@@ -103,7 +103,6 @@ def _time_backend(state: ScheduleState, tm: np.ndarray, backend: str,
 
 def bench_dispatch() -> dict:
     rng = np.random.default_rng(0)
-    jax_available = resolve_closed_form_backend("jax") == "jax"
     grid = []
     crossovers = []
     auto_picks_jax = False
@@ -145,30 +144,27 @@ def bench_dispatch() -> dict:
                     "numpy_us": round(t_np * 1e6, 1),
                     "auto_backend": auto,
                 }
-                if jax_available:
-                    t_jax = _time_backend(state, tm, "jax", n_inst)
-                    row["jax_us"] = round(t_jax * 1e6, 1)
-                    row["jax_speedup"] = round(t_np / max(t_jax, 1e-12), 2)
+                t_jax = _time_backend(state, tm, "jax", n_inst)
+                row["jax_us"] = round(t_jax * 1e6, 1)
+                row["jax_speedup"] = round(t_np / max(t_jax, 1e-12), 2)
                 rows.append(row)
             grid.extend(rows)
-            if jax_available:
-                # Crossover = smallest sweep from which JAX wins by a real
-                # margin (10%+) at that size and every larger one — a single
-                # noisy win on a microsecond-scale batch is not a crossover.
-                for i, row in enumerate(rows):
-                    if all(r["jax_speedup"] >= 1.1 for r in rows[i:]):
-                        crossovers.append(
-                            {
-                                "scenario": label,
-                                "regime": regime,
-                                "machines": m,
-                                "tasks": T,
-                                "crossover_elements": row["elements"],
-                            }
-                        )
-                        break
+            # Crossover = smallest sweep from which JAX wins by a real
+            # margin (10%+) at that size and every larger one — a single
+            # noisy win on a microsecond-scale batch is not a crossover.
+            for i, row in enumerate(rows):
+                if all(r["jax_speedup"] >= 1.1 for r in rows[i:]):
+                    crossovers.append(
+                        {
+                            "scenario": label,
+                            "regime": regime,
+                            "machines": m,
+                            "tasks": T,
+                            "crossover_elements": row["elements"],
+                        }
+                    )
+                    break
     return {
-        "jax_available": jax_available,
         "grid": grid,
         "crossovers": crossovers,
         "auto_thresholds": dict(_CLOSED_FORM_AUTO_THRESHOLDS),
@@ -187,8 +183,6 @@ def check(json_path: str) -> int:
     failures = []
     picked_jax = 0
     for row in recorded["grid"]:
-        if "jax_us" not in row:
-            continue
         auto = resolve_closed_form_backend(
             "auto", row["elements"], regime=row["regime"],
             n_machines=row["machines"],
@@ -201,7 +195,7 @@ def check(json_path: str) -> int:
                     f"numpy_us={row['numpy_us']} at {row['scenario']}/"
                     f"{row['regime']} B={row['batch']} ({row['elements']} el)"
                 )
-    if recorded.get("jax_available") and picked_jax == 0:
+    if picked_jax == 0:
         failures.append("auto never picked jax anywhere on the recorded grid")
     for msg in failures:
         print(f"DISPATCH-CHECK FAIL: {msg}")
@@ -225,7 +219,6 @@ def main(json_path: str | None = None) -> None:
         emit(
             "dispatch_crossover",
             0.0,
-            f"jax_available={out['jax_available']};"
             f"auto_picks_jax={out['auto_picks_jax']};"
             "numpy_wins_all_measured_sizes",
         )

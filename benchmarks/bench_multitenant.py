@@ -122,8 +122,9 @@ def scale_row(n_tenants: int, counts, cap_scale: float, label: str) -> dict:
     }
 
 
-def batching_row(n_tenants: int = 20) -> dict:
-    """Tenant-batched met-fold scoring vs the per-tenant residual loop."""
+def batching_case(n_tenants: int = 20):
+    """(mt, sweeps) of the batching comparison: every tenant's single-task
+    relocation rows on a shared 12-machine cluster at 90% of its rate."""
     rng = np.random.default_rng(SEED)
     tenants = _fleet(n_tenants, rng)
     cluster = paper_cluster((4, 4, 4))
@@ -147,6 +148,12 @@ def batching_row(n_tenants: int = 20) -> dict:
                 row[col] = dest
                 rows.append(row)
         sweeps.append((t, np.stack(rows)))
+    return mt, sweeps
+
+
+def batching_row(n_tenants: int = 20) -> dict:
+    """Tenant-batched met-fold scoring vs the per-tenant residual loop."""
+    mt, sweeps = batching_case(n_tenants)
     n_rows = sum(r.shape[0] for _, r in sweeps)
 
     scorer = TenantBatchScorer(mt, backend="auto")

@@ -413,32 +413,31 @@ def export_demo_trace(prefix: str, cluster=None) -> tuple[str, str]:
     return jsonl_path, chrome_path
 
 
-def parity_smoke(topo, cluster) -> dict:
-    """JAX scan vs Python loop on a shared scenario (max |diff|)."""
+def parity_case(topo, cluster):
+    """(etg, traces, policies) of the evaluator parity scenario: the refined
+    schedule's placement under a ramp and a machine-slowdown trace."""
     full = refine(schedule(topo, cluster, r0=1.0, rate_epsilon=0.05).etg, cluster)
     traces = [
-        ramp_trace(0.3 * full.rate, 1.5 * full.rate, n_windows=120).compile(
+        ramp_trace(0.3 * full.rate, 1.5 * full.rate, n_windows=N_WINDOWS).compile(
             cluster, seed=1
         ),
-        slowdown_trace(0.9 * full.rate, machine=2, n_windows=120).compile(
+        slowdown_trace(0.9 * full.rate, machine=2, n_windows=N_WINDOWS).compile(
             cluster, seed=2
         ),
     ]
-    policies = full.etg.task_machine()[None, :]
-    a = evaluate_policies_batch(full.etg, cluster, traces, policies,
+    return full.etg, traces, full.etg.task_machine()[None, :]
+
+
+def parity_smoke(topo, cluster) -> dict:
+    """JAX scan vs Python loop on a shared scenario (max |diff|)."""
+    etg, traces, policies = parity_case(topo, cluster)
+    a = evaluate_policies_batch(etg, cluster, traces, policies,
                                 backend="numpy")
-    b = evaluate_policies_batch(full.etg, cluster, traces, policies,
+    b = evaluate_policies_batch(etg, cluster, traces, policies,
                                 backend="auto")
     diff = float(np.max(np.abs(a.throughput - b.throughput)))
     lat_diff = float(np.max(np.abs(a.latency() - b.latency())))
-    try:
-        import jax  # noqa: F401
-
-        jax_used = True
-    except ImportError:
-        jax_used = False
     return {
-        "jax_available": jax_used,
         "max_abs_throughput_diff": diff,
         "max_abs_latency_diff": lat_diff,
         "within_1e9": bool(diff <= 1e-9),
@@ -465,7 +464,7 @@ def check(json_path: str) -> int:
             if "oracle_not_below_online" in row and not row["oracle_not_below_online"]:
                 bad.append(f"{tag}: oracle lost to the online controller")
     parity = data.get("parity", {})
-    if parity.get("jax_available") and not parity.get("within_1e9", False):
+    if not parity.get("within_1e9", False):
         bad.append("parity: JAX evaluator drifted past 1e-9")
     overhead = data.get("overhead", [])
     if not overhead:
@@ -524,7 +523,7 @@ def main(json_path: str | None = None, trace_out: str | None = None) -> None:
     emit(
         "runtime_eval_parity",
         0.0,
-        f"jax={parity['jax_available']};max_diff={parity['max_abs_throughput_diff']:.2e};"
+        f"max_diff={parity['max_abs_throughput_diff']:.2e};"
         f"within_1e9={parity['within_1e9']}",
     )
     overhead = overhead_rows(cluster)

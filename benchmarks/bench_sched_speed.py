@@ -31,7 +31,6 @@ from repro.core import (
     simulate_batch,
 )
 from repro.core.refine import refine
-from repro.core.simulator import _jax_available
 
 LARGE = (20, 70, 90)
 SIM_BATCH = 2048
@@ -83,13 +82,12 @@ def bench_sim_backends() -> dict:
         "tasks": int(etg.total_tasks),
         "numpy_placements_per_s": round(SIM_BATCH / t_np, 1),
     }
-    if _jax_available():
-        simulate_batch(etg, cluster, tm, r0, backend="jax")  # compile
-        t0 = time.perf_counter()
-        simulate_batch(etg, cluster, tm, r0, backend="jax")
-        t_jax = time.perf_counter() - t0
-        out["jax_placements_per_s"] = round(SIM_BATCH / t_jax, 1)
-        out["jax_speedup"] = round(t_np / t_jax, 1)
+    simulate_batch(etg, cluster, tm, r0, backend="jax")  # compile
+    t0 = time.perf_counter()
+    simulate_batch(etg, cluster, tm, r0, backend="jax")
+    t_jax = time.perf_counter() - t0
+    out["jax_placements_per_s"] = round(SIM_BATCH / t_jax, 1)
+    out["jax_speedup"] = round(t_np / t_jax, 1)
     return out
 
 

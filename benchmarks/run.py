@@ -22,6 +22,8 @@ Prints ``name,us_per_call,derived`` CSV rows.
 
 from __future__ import annotations
 
+from repro.compile_cache import setup_compile_cache
+
 from benchmarks import (
     bench_dispatch,
     bench_instances,
@@ -40,6 +42,7 @@ from benchmarks import (
 
 
 def main() -> None:
+    setup_compile_cache()
     print("name,us_per_call,derived")
     bench_prediction.main()
     bench_throughput.main()

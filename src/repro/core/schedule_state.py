@@ -153,17 +153,21 @@ class ScheduleState:
         return self._met_load
 
     def _skew_variable_load(self, cir: np.ndarray) -> np.ndarray:
-        """(m,) variable load for a per-component input-rate vector,
-        accumulated per instance: keyed components at their realized key
-        shares, shuffle components at the exact even split. The single
+        """(m,) variable load for a per-component input-rate vector:
+        keyed components accumulated per instance at their realized key
+        shares, shuffle components as the even path's per-(component,
+        machine) term in component order, so a model without keyed
+        components reproduces the even-split floats bit for bit. The single
         skew accumulation both ``var_load`` and ``utilization`` use."""
         var = np.zeros(self.cluster.n_machines, dtype=np.float64)
         for c in range(self.utg.n_components):
             nk = int(self.n_instances[c])
             frac = self.skew.instance_fractions(c, nk)
+            if frac is None:
+                var += self.e_cm[c] * self.comp_counts[c] * (cir[c] / nk)
+                continue
             w = np.asarray(self.assignment[c], dtype=np.int64)
-            ir = np.full(nk, cir[c] / nk) if frac is None else cir[c] * frac
-            np.add.at(var, w, self.e_cm[c, w] * ir)
+            np.add.at(var, w, self.e_cm[c, w] * (cir[c] * frac))
         return var
 
     @property
@@ -521,9 +525,8 @@ class ScheduleState:
             one sweep). Per-row scores are bit-identical to scoring each
             row against its own shared-count template.
           backend: ``"numpy"`` (reference floats), ``"jax"`` (jitted
-            float64 scatter-free closed form, ~1e-15 relative agreement;
-            falls back to NumPy when JAX is unavailable), or ``"auto"``
-            (JAX above the regime's calibrated element-count crossover,
+            float64 scatter-free closed form, ~1e-15 relative agreement),
+            or ``"auto"`` (JAX above the regime's calibrated element-count crossover,
             machine-count gated on CPU — skew rows dispatch under the
             ``"skew"`` regime; the jitted kernel is skew-agnostic).
         """

@@ -13,9 +13,8 @@ Rate propagation uses the sparse structure of the UTG directly: components'
 tasks are contiguous in the flattened task order (paper eq. 3), so the
 per-component gather/scatter reduces to static slices, and the parent sum
 ``CIR_b = sum alpha_a * PR_a`` unrolls over the (few) DAG edges. Everything
-runs in float64 (via ``jax.experimental.enable_x64``) so the backends agree
-to 1e-9; the NumPy path remains the reference and the fallback when JAX is
-unavailable.
+runs in float64 (inside ``jax.enable_x64(True)``; emulated on TPU) so the
+backends agree to 1e-9; the NumPy path remains the reference.
 """
 
 from __future__ import annotations
@@ -147,7 +146,7 @@ def simulate_batch_jax(
 
     ``r0`` may be a scalar or a (B,) per-candidate rate vector.
     """
-    from jax.experimental import enable_x64
+    import jax
 
     # Imported here to avoid a cycle (simulator dispatches to this module).
     from repro.core.simulator import BatchSimResult
@@ -182,7 +181,7 @@ def simulate_batch_jax(
 
     kernel = _compiled_kernel(_static_descriptor(etg))
     n_inst = np.asarray(etg.n_instances, dtype=np.float64)
-    with enable_x64():
+    with jax.enable_x64(True):
         ir, pr, tcu, util, thpt = kernel(
             task_machine, comp, n_inst, e_cm, met_cm, cluster.capacity, r0_b
         )
@@ -298,25 +297,6 @@ def _msr_kernel(per_row: bool = False, with_resources: bool = False):
     return kernel_resources
 
 
-@functools.cache
-def _use_pallas_scoring() -> bool:
-    """Route closed-form scoring through the Pallas segmented-reduce kernel
-    (``repro.kernels.sched_scoring``). On by default on TPU backends; force
-    with ``REPRO_SCHED_SCORING_PALLAS=1`` (compiled) / ``=interpret``
-    (interpreter — CPU-testable, slow) / ``=0`` (off)."""
-    import os
-
-    env = os.environ.get("REPRO_SCHED_SCORING_PALLAS")
-    if env is not None:
-        return env not in ("0", "")
-    try:
-        import jax
-
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
-
-
 def closed_form_rates_jax(
     task_machine: np.ndarray,
     comp: np.ndarray,
@@ -332,11 +312,10 @@ def closed_form_rates_jax(
 
     ``comp`` / ``unit_ir`` may be (T,) shared maps or (B, T) per-row maps;
     each shape routes to its own cached kernel variant. ``capacity`` may be
-    (m,) shared or (B, m) per-row. On TPU backends (or under
-    ``REPRO_SCHED_SCORING_PALLAS``) the accumulation runs the Pallas
-    segmented-reduce kernel instead of the XLA contraction — except for
-    per-row capacity, which the Pallas kernel does not carry yet; those
-    batches stay on the XLA contraction on every backend.
+    (m,) shared or (B, m) per-row. The sweep runs the XLA contraction in
+    float64 on every backend (emulated on TPU): the Pallas twin in
+    ``repro.kernels.sched_scoring`` compiles for the TPU only with 32-bit
+    operands, so it is not on this path.
 
     Resource-vector extras (``net_var`` / ``mem`` / ``mem_capacity``) have
     the ``cost_model.closed_form_rates`` semantics: the cut-traffic column
@@ -345,24 +324,13 @@ def closed_form_rates_jax(
     kernels; absent resource types are filled with zeros / +inf for the
     resource variant.
     """
-    import os
-
-    from jax.experimental import enable_x64
+    import jax
 
     has_resources = (
         net_var is not None or mem is not None or mem_capacity is not None
     )
-    if _use_pallas_scoring() and capacity.ndim == 1:
-        from repro.kernels.sched_scoring.ops import closed_form_rates_sched
-
-        interpret = os.environ.get("REPRO_SCHED_SCORING_PALLAS") == "interpret"
-        return closed_form_rates_sched(
-            task_machine, comp, unit_ir, e_cm, met_cm, capacity,
-            impl="interpret" if interpret else "pallas",
-            net_var=net_var, mem=mem, mem_capacity=mem_capacity,
-        )
     if not has_resources:
-        with enable_x64():
+        with jax.enable_x64(True):
             rates, thpt = _msr_kernel(per_row=comp.ndim == 2)(
                 task_machine, comp, unit_ir, e_cm, met_cm, capacity
             )
@@ -374,7 +342,7 @@ def closed_form_rates_jax(
     if mem is None:
         mem = np.zeros(comp.shape[-1], dtype=np.float64)
         mem_capacity = np.full(m, np.inf, dtype=np.float64)
-    with enable_x64():
+    with jax.enable_x64(True):
         rates, thpt = _msr_kernel(per_row=comp.ndim == 2, with_resources=True)(
             task_machine, comp, unit_ir, e_cm, met_cm, capacity,
             net_var, mem, mem_capacity,

@@ -101,18 +101,6 @@ class BatchSimResult:
         )
 
 
-@functools.cache
-def _jax_available() -> bool:
-    # Memoized: failed imports are not cached by Python, so probing per
-    # call would re-walk sys.path on every auto dispatch on JAX-less hosts.
-    try:
-        import jax  # noqa: F401
-
-        return True
-    except Exception:
-        return False
-
-
 # Per-regime element floors (B*T per sweep) below which "auto" never
 # considers JAX for the closed-form scorers, calibrated by
 # benchmarks/bench_dispatch.py (see BENCH_dispatch.json). Since the scorer
@@ -152,15 +140,10 @@ _AUTO_MAX_WORK = 6_000_000
 
 @functools.cache
 def _jax_accelerator_available() -> bool:
-    """True iff JAX imports *and* its default backend is not the CPU."""
-    if not _jax_available():
-        return False
-    try:
-        import jax
+    """True iff JAX's default backend is not the CPU."""
+    import jax
 
-        return jax.default_backend() != "cpu"
-    except Exception:
-        return False
+    return jax.default_backend() != "cpu"
 
 
 def _closed_form_auto_threshold(regime: str = "shared") -> tuple[float, bool]:
@@ -196,9 +179,9 @@ def resolve_closed_form_backend(
 
     Shared by ``cost_model.max_stable_rate_batch`` and
     ``ScheduleState.score_task_machine_batch`` so the backend-string
-    contract, the ``"auto"`` dispatch heuristic and the graceful
-    JAX-missing fallback live in one place (``simulate_batch`` keeps its own
-    richer policy: its fixed-point loop has a different cost profile).
+    contract and the ``"auto"`` dispatch heuristic live in one place
+    (``simulate_batch`` keeps its own policy: its fixed-point loop has a
+    different cost profile).
 
     Args:
       backend: ``"numpy"``, ``"jax"``, or ``"auto"`` (JAX iff the sweep
@@ -234,11 +217,10 @@ def resolve_closed_form_backend(
                 )
             )
             backend = "jax" if gate_ok and elements >= threshold else "numpy"
-    resolved = "jax" if backend == "jax" and _jax_available() else "numpy"
     # Auditability of the auto-dispatch gates: when a TraceRecorder is
     # active, every resolution lands in its dispatch log (no-op otherwise).
-    record_dispatch(requested, resolved, regime, elements, n_machines, site)
-    return resolved
+    record_dispatch(requested, backend, regime, elements, n_machines, site)
+    return backend
 
 
 # Batches at least this large amortize JAX dispatch/compile overhead on the
@@ -264,24 +246,24 @@ def simulate_batch(
         own stable rates in a single sweep).
       backend: ``"numpy"`` (reference), ``"jax"`` (jitted
         ``lax.while_loop`` fixed point, float64 — agrees with NumPy to
-        1e-9), or ``"auto"`` (JAX for large batches when importable, NumPy
-        otherwise). The JAX path falls back to NumPy if JAX is missing.
+        1e-9), or ``"auto"`` (JAX for batches of at least
+        ``_JAX_AUTO_THRESHOLD`` elements, NumPy below). The resolution lands
+        in the active ``repro.obs`` recorder's dispatch log.
     """
+    requested = backend
     if backend not in ("auto", "numpy", "jax"):
         raise ValueError(f"unknown backend {backend!r}")
+    tm = np.asarray(task_machine)
     if backend == "auto":
-        tm = np.asarray(task_machine)
-        backend = (
-            "jax"
-            if tm.size >= _JAX_AUTO_THRESHOLD and _jax_available()
-            else "numpy"
-        )
+        backend = "jax" if tm.size >= _JAX_AUTO_THRESHOLD else "numpy"
+    record_dispatch(
+        requested, backend, "simulate", tm.size, cluster.n_machines,
+        "simulate_batch",
+    )
     if backend == "jax":
-        if _jax_available():
-            from repro.core.sim_jax import simulate_batch_jax
+        from repro.core.sim_jax import simulate_batch_jax
 
-            return simulate_batch_jax(etg, cluster, task_machine, r0)
-        backend = "numpy"  # graceful fallback: NumPy is the reference path
+        return simulate_batch_jax(etg, cluster, task_machine, r0)
 
     utg = etg.utg
     comp = etg.task_component()                       # (T,)

@@ -14,6 +14,7 @@ from __future__ import annotations
 import numpy as np
 
 from repro.kernels.sched_scoring.ref import sched_scoring_ref
+from repro.obs.trace import record_dispatch
 
 __all__ = ["closed_form_rates_sched"]
 
@@ -25,7 +26,8 @@ def closed_form_rates_sched(
     e_cm: np.ndarray,
     met_cm: np.ndarray,
     capacity: np.ndarray,
-    impl: str = "auto",
+    *,
+    impl: str,
     net_var: np.ndarray | None = None,
     mem: np.ndarray | None = None,
     mem_capacity: np.ndarray | None = None,
@@ -36,9 +38,11 @@ def closed_form_rates_sched(
       task_machine: (B, T) machine index per task.
       comp / unit_ir: (T,) shared or (B, T) per-row task maps.
       e_cm / met_cm: (n_components, n_machines) profile slices.
-      impl: ``"pallas"`` (compiled), ``"interpret"`` (Pallas interpreter —
-        CPU-testable), ``"ref"`` (NumPy oracle), or ``"auto"`` (pallas on
-        TPU, ref elsewhere).
+      impl: ``"pallas"`` (compiled, TPU only), ``"interpret"`` (Pallas
+        interpreter — CPU-testable), or ``"ref"`` (NumPy oracle). The
+        kernel runs in JAX's default float dtype: float32 unless the caller
+        enabled x64. The TPU compiler refuses 64-bit operands in a Pallas
+        kernel, so compiled calls come from outside ``jax.enable_x64``.
       net_var / mem / mem_capacity: resource-vector extras with the
         ``cost_model.closed_form_rates`` semantics — (B, m) cut-traffic
         variable load, (T,)/(B, T) per-task memory demand, (m,) memory
@@ -53,58 +57,57 @@ def closed_form_rates_sched(
     met = met_cm[cmap, task_machine]
     ev = e * (unit_ir if per_row else unit_ir[None, :])
     B, T = task_machine.shape
+    if impl not in ("pallas", "interpret", "ref"):
+        raise ValueError(f"unknown impl {impl!r}")
+    record_dispatch(
+        impl, impl, "sched_scoring", B * T, capacity.shape[0],
+        "closed_form_rates_sched",
+    )
     if B == 0:
         return np.zeros(0), np.zeros(0)
     has_resources = (
         net_var is not None or mem is not None or mem_capacity is not None
     )
-    if impl == "auto":
-        import jax
-
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
     if impl in ("pallas", "interpret"):
-        from jax.experimental import enable_x64
-
         from repro.kernels.sched_scoring.kernel import (
             sched_scoring_pallas,
             sched_scoring_pallas_resources,
         )
 
-        with enable_x64():
-            if has_resources:
-                m = capacity.shape[0]
-                net_b = (
-                    net_var
-                    if net_var is not None
-                    else np.zeros((B, m), dtype=np.float64)
+        if has_resources:
+            m = capacity.shape[0]
+            net_b = (
+                net_var
+                if net_var is not None
+                else np.zeros((B, m), dtype=np.float64)
+            )
+            mem_bt = (
+                np.broadcast_to(
+                    mem if mem.ndim == 2 else mem[None, :], (B, T)
+                ).astype(np.float64, copy=False)
+                if mem is not None
+                else np.zeros((B, T), dtype=np.float64)
+            )
+            mem_cap = (
+                mem_capacity
+                if mem_capacity is not None
+                else np.full(m, np.inf, dtype=np.float64)
+            )
+            rates = np.asarray(
+                sched_scoring_pallas_resources(
+                    task_machine, ev, met, mem_bt, capacity,
+                    net_b, mem_cap,
+                    interpret=impl == "interpret",
                 )
-                mem_bt = (
-                    np.broadcast_to(
-                        mem if mem.ndim == 2 else mem[None, :], (B, T)
-                    ).astype(np.float64, copy=False)
-                    if mem is not None
-                    else np.zeros((B, T), dtype=np.float64)
+            )
+        else:
+            rates = np.asarray(
+                sched_scoring_pallas(
+                    task_machine, ev, met, capacity,
+                    interpret=impl == "interpret",
                 )
-                mem_cap = (
-                    mem_capacity
-                    if mem_capacity is not None
-                    else np.full(m, np.inf, dtype=np.float64)
-                )
-                rates = np.asarray(
-                    sched_scoring_pallas_resources(
-                        task_machine, ev, met, mem_bt, capacity,
-                        net_b, mem_cap,
-                        interpret=impl == "interpret",
-                    )
-                )
-            else:
-                rates = np.asarray(
-                    sched_scoring_pallas(
-                        task_machine, ev, met, capacity,
-                        interpret=impl == "interpret",
-                    )
-                )
-    elif impl == "ref":
+            )
+    else:
         mem_bt = None
         if mem is not None:
             mem_bt = np.broadcast_to(
@@ -114,7 +117,5 @@ def closed_form_rates_sched(
             task_machine, ev, met, capacity,
             net_var=net_var, mem=mem_bt, mem_capacity=mem_capacity,
         )
-    else:
-        raise ValueError(f"unknown impl {impl!r}")
     thpt = rates * (unit_ir.sum(axis=1) if per_row else unit_ir.sum())
     return rates, thpt

@@ -14,9 +14,9 @@ Design constraints:
 * recording must never perturb the computation it observes — the
   recorder only appends to Python lists and bumps counters, and the
   ``NullRecorder`` default makes every hook a no-op attribute access;
-* the closed-form dispatch hook (:func:`record_dispatch`) is called
-  from ``repro.core.simulator`` on *every* backend resolution, so the
-  inactive path is a single module-global ``None`` check;
+* the dispatch hook (:func:`record_dispatch`) is called on *every*
+  backend resolution of a scoring, simulator or policy-evaluation sweep,
+  so the inactive path is a single module-global ``None`` check;
 * this module imports only the standard library (and sibling
   ``repro.obs`` modules), so it can be imported from anywhere in
   ``repro`` without cycles.
@@ -43,7 +43,11 @@ __all__ = [
 
 @dataclass(frozen=True)
 class DispatchDecision:
-    """One closed-form backend resolution (``resolve_closed_form_backend``)."""
+    """One backend resolution of a sweep: closed-form scoring
+    (``resolve_closed_form_backend``; regimes shared / per_row / skew),
+    ``simulate_batch`` (regime ``simulate``), ``evaluate_policies_batch``
+    (regime ``policy_eval``) or an explicit call of the Pallas scoring
+    kernel (regime ``sched_scoring``)."""
 
     requested: str
     backend: str
@@ -295,7 +299,8 @@ def record_dispatch(
     n_machines: int | None,
     site: str | None = None,
 ) -> None:
-    """Dispatch-decision hook called by ``resolve_closed_form_backend``.
+    """Dispatch-decision hook called by ``resolve_closed_form_backend``,
+    ``simulate_batch`` and ``evaluate_policies_batch``.
 
     A single global read when no recorder is active, so the instrumented
     resolver costs nothing in normal operation.

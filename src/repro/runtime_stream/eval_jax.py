@@ -11,12 +11,12 @@ per-key share grids — dense (W, B, N) expansions of each realization
 segment's hash→instance map — threaded through the scan as per-window
 inputs, so keyed runs stay bit-compatible with the Python loop.
 
-Everything runs in float64 (``jax.experimental.enable_x64``): the window
-step is the exact formula sequence of ``StreamExecutor.run`` (no
-controller, no migrations — this is the *static-policy* sweep evaluator),
-so the backends agree to ~1e-9 over hundreds of windows; the NumPy backend
-loops the reference executor over every (trace, policy) pair and is the
-fallback whenever JAX is unavailable.
+Everything runs in float64 (inside ``jax.enable_x64(True)``; emulated on
+TPU): the window step is the exact formula sequence of
+``StreamExecutor.run`` (no controller, no migrations — this is the
+*static-policy* sweep evaluator), so the backends agree to ~1e-9 over
+hundreds of windows; the NumPy backend loops the reference executor over
+every (trace, policy) pair and is the reference.
 """
 
 from __future__ import annotations
@@ -27,7 +27,7 @@ import numpy as np
 
 from repro.core.graph import ExecutionGraph
 from repro.core.profiles import Cluster
-from repro.core.simulator import _jax_available
+from repro.obs.trace import record_dispatch
 
 from repro.runtime_stream.executor import RuntimeConfig, StreamExecutor
 from repro.runtime_stream.traces import CompiledTrace
@@ -138,7 +138,8 @@ def evaluate_policies_batch(
         parity comparisons).
       backend: ``"numpy"`` (reference: the Python executor per pair),
         ``"jax"`` (one jitted ``lax.scan``, ~1e-9 agreement), or
-        ``"auto"`` (JAX when importable, NumPy otherwise).
+        ``"auto"`` (JAX). The resolution lands in the active ``repro.obs``
+        recorder's dispatch log.
       external_load: optional (W, m) or (m,) load held by co-tenants of
         the shared machines, subtracted (clipped at zero) from every
         trace's capacity grid before evaluation — the tenant dimension of
@@ -163,10 +164,13 @@ def evaluate_policies_batch(
             for tr in traces
         ]
     policies = _validate(etg, cluster, traces, policies)
+    requested = backend
     if backend == "auto":
-        backend = "jax" if _jax_available() else "numpy"
-    if backend == "jax" and not _jax_available():
-        backend = "numpy"
+        backend = "jax"
+    record_dispatch(
+        requested, backend, "policy_eval", len(traces) * policies.size,
+        cluster.n_machines, "evaluate_policies_batch",
+    )
     if backend == "numpy":
         return _evaluate_numpy(etg, cluster, traces, policies, config)
     return _evaluate_jax(etg, cluster, traces, policies, config)
@@ -214,7 +218,6 @@ def _evaluate_numpy(etg, cluster, traces, policies, config) -> PolicyEvalResult:
 def _evaluate_jax(etg, cluster, traces, policies, config) -> PolicyEvalResult:
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
 
     utg = etg.utg
     n = utg.n_components
@@ -337,7 +340,7 @@ def _evaluate_jax(etg, cluster, traces, policies, config) -> PolicyEvalResult:
         _, ms = jax.lax.scan(step, carry0, (rates, caps, key_shares))
         return ms
 
-    with enable_x64():
+    with jax.enable_x64(True):
         thpt, adm, drp, qtot, thr, util = sweep(rates, caps, key_shares)
 
     def wbp(x):  # (W, B, P) -> (B, P, W)

@@ -5,6 +5,7 @@ import pytest
 
 pytest.importorskip("hypothesis", reason="hypothesis not installed (see requirements-dev.txt)")
 from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from sched_strategies import PROFILE, random_cluster, random_dag
 

@@ -130,7 +130,7 @@ def _assert_parity(got, ref):
 
 def _check_backend_parity(utg, cluster, seed=0, per_row=False):
     """NumPy vs XLA vs Pallas-interpret on resource clusters."""
-    pytest.importorskip("jax")
+    jax = pytest.importorskip("jax")
     from repro.kernels.sched_scoring.ops import closed_form_rates_sched
 
     etg = schedule(utg, cluster, r0=1.0, rate_epsilon=1.0).etg
@@ -158,11 +158,14 @@ def _check_backend_parity(utg, cluster, seed=0, per_row=False):
         cluster, tm, comp, unit_ir, utg.alpha,
         cost_model.component_rates(utg, 1.0), utg.edges, utg.component_types,
     )
-    got = closed_form_rates_sched(
-        tm, comp, unit_ir, state.e_cm, state.met_cm, cluster.capacity,
-        impl="interpret",
-        net_var=net_var, mem=mem, mem_capacity=mem_cap,
-    )
+    # Interpret mode computes in JAX's default float dtype: float64 here,
+    # like the NumPy reference.
+    with jax.enable_x64(True):
+        got = closed_form_rates_sched(
+            tm, comp, unit_ir, state.e_cm, state.met_cm, cluster.capacity,
+            impl="interpret",
+            net_var=net_var, mem=mem, mem_capacity=mem_cap,
+        )
     _assert_parity(got, ref)
 
 

@@ -14,6 +14,8 @@ shapes/values; the deterministic seed sweep below keeps kernel coverage in
 environments without it.
 """
 
+from fractions import Fraction
+
 import numpy as np
 import pytest
 
@@ -64,15 +66,50 @@ def _random_problem(seed, B, T, m, n, infeasible_rows=0):
     return tm, comp, unit_ir, e_cm, met_cm, cap, ref
 
 
-def _assert_parity(got, ref):
+def _sched_interpret(*args, **kwargs):
+    """Pallas kernel in interpret mode, in float64 like the NumPy oracle
+    (the kernel computes in JAX's default float dtype)."""
+    with jax.enable_x64(True):
+        return closed_form_rates_sched(*args, impl="interpret", **kwargs)
+
+
+def _exact_throughput(tm, e, met, unit_ir, cap):
+    """Row throughputs of ``closed_form_rates`` in exact rational arithmetic
+    (every float operand is converted exactly, nothing rounds)."""
+    out = []
+    for b in range(tm.shape[0]):
+        u = [Fraction(x) for x in (unit_ir[b] if unit_ir.ndim == 2 else unit_ir)]
+        var, load = {}, {}
+        for t, w in enumerate(tm[b]):
+            var[w] = var.get(w, 0) + Fraction(e[b, t]) * u[t]
+            load[w] = load.get(w, 0) + Fraction(met[b, t])
+        head = {w: Fraction(cap[w]) - load[w] for w in load}
+        if any(h < 0 for h in head.values()):
+            out.append(Fraction(0))
+            continue
+        rate = min(head[w] / var[w] for w in var if var[w] > 0)
+        out.append(max(rate, Fraction(0)) * sum(u))
+    return out
+
+
+def _assert_parity(got, ref, exact=None):
+    """``got`` matches ``ref`` to 1e-12 with the same feasibility mask and
+    the same best row. A different best row is accepted only when it ties
+    the reference's pick exactly: bit for bit in ``ref``, or, when the
+    exact row throughputs are given, in exact arithmetic (rows that are the
+    same value in real numbers but round differently, such as every row of
+    a one-machine, one-component problem with per-row instance rates)."""
     r_ref, t_ref = ref
     r_got, t_got = got
     np.testing.assert_allclose(r_got, r_ref, rtol=1e-12, atol=1e-12)
     np.testing.assert_allclose(t_got, t_ref, rtol=1e-12, atol=1e-12)
-    # Identical feasibility mask and identical best-candidate pick.
     assert np.array_equal(r_got == 0.0, r_ref == 0.0)
     if r_ref.size:
-        assert int(np.argmax(t_got)) == int(np.argmax(t_ref))
+        pick, want = int(np.argmax(t_got)), int(np.argmax(t_ref))
+        if exact is None:
+            assert t_ref[pick] == t_ref[want]
+        else:
+            assert exact[pick] == exact[want]
 
 
 SHAPES = [
@@ -101,9 +138,7 @@ def test_pallas_interpret_parity_shared(B, T, m, n, seed):
     tm, comp, unit_ir, e_cm, met_cm, cap, ref = _random_problem(
         seed, B, T, m, n, infeasible_rows=min(B, 3)
     )
-    got = closed_form_rates_sched(
-        tm, comp, unit_ir, e_cm, met_cm, cap, impl="interpret"
-    )
+    got = _sched_interpret(tm, comp, unit_ir, e_cm, met_cm, cap)
     _assert_parity(got, ref)
 
 
@@ -120,9 +155,7 @@ def test_per_row_parity(B, T, m, n):
         closed_form_rates_jax(tm, comp, unit_ir, e_cm, met_cm, cap), ref
     )
     _assert_parity(
-        closed_form_rates_sched(
-            tm, comp, unit_ir, e_cm, met_cm, cap, impl="interpret"
-        ),
+        _sched_interpret(tm, comp, unit_ir, e_cm, met_cm, cap),
         ref,
     )
 
@@ -198,9 +231,8 @@ def test_skew_pallas_interpret_matches_numpy(skew_state):
     comp = np.repeat(np.arange(n), state.n_instances)
     unit_ir = skew.per_task_unit_ir(state.n_instances)
     ref = state.score_task_machine_batch(tm, backend="numpy")
-    got = closed_form_rates_sched(
-        tm, comp, unit_ir, state.e_cm, state.met_cm, cluster.capacity,
-        impl="interpret",
+    got = _sched_interpret(
+        tm, comp, unit_ir, state.e_cm, state.met_cm, cluster.capacity
     )
     _assert_parity(got, ref)
 
@@ -304,10 +336,11 @@ if HAS_HYPOTHESIS:
         if impl == "contraction":
             got = closed_form_rates_jax(tm, comp, unit_ir, e_cm, met_cm, cap)
         else:
-            got = closed_form_rates_sched(
-                tm, comp, unit_ir, e_cm, met_cm, cap, impl="interpret"
-            )
-        _assert_parity(got, ref)
+            got = _sched_interpret(tm, comp, unit_ir, e_cm, met_cm, cap)
+        exact = _exact_throughput(
+            tm, e_cm[comp[None, :], tm], met_cm[comp[None, :], tm], unit_ir, cap
+        )
+        _assert_parity(got, ref, exact)
 
     @settings(max_examples=20, deadline=None)
     @given(
@@ -322,15 +355,16 @@ if HAS_HYPOTHESIS:
         tm, comp1, _, e_cm, met_cm, cap, _ = _random_problem(seed, B, T, m, n)
         comp = np.broadcast_to(comp1, (B, T)).copy()
         unit_ir = rng.uniform(0.05, 1.5, size=(B, T))
-        ref = closed_form_rates(
-            tm, e_cm[comp, tm], met_cm[comp, tm], unit_ir, cap
-        )
+        e, met = e_cm[comp, tm], met_cm[comp, tm]
+        ref = closed_form_rates(tm, e, met, unit_ir, cap)
+        exact = _exact_throughput(tm, e, met, unit_ir, cap)
         _assert_parity(
-            closed_form_rates_jax(tm, comp, unit_ir, e_cm, met_cm, cap), ref
-        )
-        _assert_parity(
-            closed_form_rates_sched(
-                tm, comp, unit_ir, e_cm, met_cm, cap, impl="interpret"
-            ),
+            closed_form_rates_jax(tm, comp, unit_ir, e_cm, met_cm, cap),
             ref,
+            exact,
+        )
+        _assert_parity(
+            _sched_interpret(tm, comp, unit_ir, e_cm, met_cm, cap),
+            ref,
+            exact,
         )
