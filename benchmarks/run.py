@@ -52,11 +52,7 @@ def main() -> None:
     bench_sched_speed.main(json_path="BENCH_sched.json")
     bench_refine.main(json_path="BENCH_refine.json")
     bench_dispatch.main(json_path="BENCH_dispatch.json")
-    # trace_out exports one instrumented run (JSONL + Chrome trace-event)
-    # alongside the JSON — the repro.obs demo artifacts CI validates.
-    bench_runtime.main(
-        json_path="BENCH_runtime.json", trace_out="BENCH_runtime_trace"
-    )
+    bench_runtime.main(json_path="BENCH_runtime.json")
     bench_multitenant.main(json_path="BENCH_multitenant.json")
     bench_netaware.main(json_path="BENCH_netaware.json")
     bench_planner.main()

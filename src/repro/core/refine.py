@@ -48,6 +48,7 @@ docs/architecture.md for the engine design and docs/api.md for usage.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 
 import numpy as np
@@ -57,6 +58,7 @@ from repro.core.cost_model import max_stable_rate
 from repro.core.graph import ExecutionGraph
 from repro.core.profiles import Cluster
 from repro.core.schedule_state import ScheduleState
+from repro.obs import trace
 
 __all__ = ["RefineResult", "refine"]
 
@@ -87,10 +89,16 @@ _ADAPTIVE_GROW_CAP = 64
 
 @dataclasses.dataclass(frozen=True)
 class RefineResult:
+    """``candidates``: candidate placements the climb scored, each counted
+    once per round (the reference engine re-scores greedy growth prefixes
+    and the candidate a chain ends on; those repeats are not counted), so
+    both engines report the same number for the same climb."""
+
     etg: ExecutionGraph
     rate: float
     throughput: float
     moves: list[str]
+    candidates: int = 0
 
 
 def _score(etg: ExecutionGraph, cluster: Cluster) -> float:
@@ -150,8 +158,12 @@ def refine(
         climb runs under a ``refine`` span with one ``refine.round`` span
         per applied move (state engine), and the recorder is *activated*
         for the duration so every closed-form backend resolution during
-        scoring lands in its dispatch log. ``None`` (or a
-        ``NullRecorder``) adds no work to the climb.
+        scoring lands in its dispatch log, each candidate batch's
+        construction and scoring land in ``refine.build`` /
+        ``refine.sweep`` spans (``sweep.put`` / ``sweep.run`` /
+        ``sweep.fetch`` on the device) and the ``refine.rows`` /
+        ``sweep.h2d_bytes`` counters. ``None`` (or a ``NullRecorder``)
+        adds no work to the climb.
     """
     rec = recorder if recorder is not None and recorder.enabled else None
     if engine == "state":
@@ -204,12 +216,16 @@ def _refine_reference(
     moves: list[str] = []
     m = cluster.n_machines
     n = current.utg.n_components
+    candidates = 0
 
     for _ in range(max_rounds):
         best_move: tuple[float, str, ExecutionGraph] | None = None
+        # Greedy growth prefixes whose m trials were already counted.
+        stepped: set[tuple[int, ...]] = set()
 
-        def consider(cand: ExecutionGraph, desc: str) -> None:
-            nonlocal best_move
+        def consider(cand: ExecutionGraph, desc: str, fresh: bool = True) -> None:
+            nonlocal best_move, candidates
+            candidates += fresh
             s = _score(cand, cluster)
             if s > best + tol and (best_move is None or s > best_move[0]):
                 best_move = (s, desc, cand)
@@ -250,13 +266,18 @@ def _refine_reference(
             for c in range(n):
                 for w in range(m):
                     consider(current.with_new_instance(c, w), f"add c{c}->m{w}")
+                stepped.add((c,))
             # GROW: k instances of one component at once, placed greedily —
             # the eq. 6 re-split means gains often appear only at specific
             # counts, invisible to single adds (e.g. 2 extra instances so a
             # fast machine carries 2 of N chunks).
             def greedy_grow(base, adds):
+                nonlocal candidates
                 cand = base
-                for c in adds:
+                for i, c in enumerate(adds):
+                    if tuple(adds[: i + 1]) not in stepped:
+                        stepped.add(tuple(adds[: i + 1]))
+                        candidates += m
                     step_best = None
                     for w in range(m):
                         trial = cand.with_new_instance(c, w)
@@ -268,7 +289,9 @@ def _refine_reference(
 
             for c in range(n):
                 for k in (2, 3, 4):
-                    consider(greedy_grow(current, [c] * k), f"grow c{c}x{k}")
+                    consider(
+                        greedy_grow(current, [c] * k), f"grow c{c}x{k}", fresh=False
+                    )
             # PAIRGROW: components often need to grow *together* — the eq. 6
             # re-split creates valleys between (x, y) and (x+a, y+b) that
             # per-component moves cannot cross.
@@ -277,7 +300,7 @@ def _refine_reference(
                     for a, b in ((1, 1), (2, 1), (1, 2), (2, 2)):
                         adds = [ci] * a + [cj] * b
                         consider(greedy_grow(current, adds),
-                                 f"pairgrow c{ci}x{a}+c{cj}x{b}")
+                                 f"pairgrow c{ci}x{a}+c{cj}x{b}", fresh=False)
             # DROP: remove an instance (keeps >= 1 per component).
             for c in range(n):
                 if int(current.n_instances[c]) < 2:
@@ -295,7 +318,9 @@ def _refine_reference(
         moves.append(desc)
 
     rate, thpt = max_stable_rate(current, cluster)
-    return RefineResult(etg=current, rate=rate, throughput=thpt, moves=moves)
+    return RefineResult(
+        etg=current, rate=rate, throughput=thpt, moves=moves, candidates=candidates
+    )
 
 
 # ------------------------------------------------------------ state engine
@@ -358,12 +383,13 @@ def _grow_step(
     row, offsets = cur.row, cur.offsets
     pos = int(offsets[c + 1])  # append at end of c's block
     T = row.shape[0]
-    tm = np.empty((m, T + 1), dtype=np.int64)
-    tm[:, :pos] = row[:pos]
-    tm[:, pos] = np.arange(m)
-    tm[:, pos + 1 :] = row[pos:]
-    n_new = state.n_instances.copy()
-    n_new[c] += 1
+    with trace.span("refine.build", "refine"):
+        tm = np.empty((m, T + 1), dtype=np.int64)
+        tm[:, :pos] = row[:pos]
+        tm[:, pos] = np.arange(m)
+        tm[:, pos + 1 :] = row[pos:]
+        n_new = state.n_instances.copy()
+        n_new[c] += 1
     _, scores = state.score_task_machine_batch(tm, n_new, backend=backend)
     w = int(np.argmax(scores))
     state.add_instance(c, w)
@@ -396,22 +422,25 @@ def _lockstep_extend(
     m = state.cluster.n_machines
     T = int(chains[0].row.shape[0])
     k = len(chains)
-    comps_arr = np.asarray(comps, dtype=np.int64)
-    base = np.stack([ch.row for ch in chains])           # (k, T)
-    pos = np.array(
-        [int(ch.offsets[c + 1]) for ch, c in zip(chains, comps)],
-        dtype=np.int64,
-    )  # append at end of each chain's grown block
-    counts = np.stack([ch.n_inst for ch in chains])      # (k, n)
-    counts[np.arange(k), comps_arr] += 1
-    # Insert one column at pos[i]: source column j-1 right of the insert, j
-    # left of it; the insert column itself is overwritten with the machine
-    # index, so its clipped source value is irrelevant.
-    cols = np.arange(T + 1)
-    src = np.clip(cols[None, :] - (cols[None, :] > pos[:, None]), 0, max(T - 1, 0))
-    tm = np.repeat(np.take_along_axis(base, src, axis=1), m, axis=0)
-    tm[np.arange(k * m), np.repeat(pos, m)] = np.tile(np.arange(m), k)
-    n_rows = np.repeat(counts, m, axis=0)
+    with trace.span("refine.build", "refine"):
+        comps_arr = np.asarray(comps, dtype=np.int64)
+        base = np.stack([ch.row for ch in chains])           # (k, T)
+        pos = np.array(
+            [int(ch.offsets[c + 1]) for ch, c in zip(chains, comps)],
+            dtype=np.int64,
+        )  # append at end of each chain's grown block
+        counts = np.stack([ch.n_inst for ch in chains])      # (k, n)
+        counts[np.arange(k), comps_arr] += 1
+        # Insert one column at pos[i]: source column j-1 right of the
+        # insert, j left of it; the insert column itself is overwritten with
+        # the machine index, so its clipped source value is irrelevant.
+        cols = np.arange(T + 1)
+        src = np.clip(
+            cols[None, :] - (cols[None, :] > pos[:, None]), 0, max(T - 1, 0)
+        )
+        tm = np.repeat(np.take_along_axis(base, src, axis=1), m, axis=0)
+        tm[np.arange(k * m), np.repeat(pos, m)] = np.tile(np.arange(m), k)
+        n_rows = np.repeat(counts, m, axis=0)
     _, scores = state.score_task_machine_batch(tm, n_rows, backend=backend)
     winners = scores.reshape(k, m).argmax(axis=1)
     for i, (ch, c) in enumerate(zip(chains, comps)):
@@ -633,210 +662,216 @@ def _refine_state(
         best = float(
             state.score_task_machine_batch(state.task_machine()[None, :])[1][0]
         )
+    rows0 = state.rows_scored  # the incumbent is no candidate
     moves: list[str] = []
     m = cluster.n_machines
     n = state.utg.n_components
 
     for round_idx in range(max_rounds):
-        # Per-round profiling span (opened/closed manually so the
-        # convergence `break` below can close it without reindenting the
-        # whole round body under a `with`).
-        round_span = sp = None
-        if recorder is not None:
-            round_span = recorder.span("refine.round", cat="refine", round=round_idx)
-            sp = round_span.__enter__()
-        best_move: tuple[float, str, "function"] | None = None
-
-        def offer(score: float, desc: str, apply_fn) -> None:
-            nonlocal best_move
-            if score > best + tol and (best_move is None or score > best_move[0]):
-                best_move = (score, desc, apply_fn)
-
-        base_tm = state.task_machine()
-        offsets = state.component_offsets()
-        T = int(base_tm.shape[0])
-        # Copy: growth exploration below mutates state.n_instances in place
-        # before snapshot/restore swaps in a fresh array.
-        n_inst = state.n_instances.copy()
-        comp_of = np.repeat(np.arange(n), n_inst)
-
-        # RELOCATE + SWAP share the template (counts unchanged): candidates
-        # are 1-2 column edits on the base row, scored in one sweep. Within
-        # the concatenated [relocate..., swap...] order, np.argmax is the
-        # reference's first strictly-greater winner.
-        W = np.tile(np.arange(m), (T, 1))
-        keep = (W != base_tm[:, None]).ravel()
-        reloc_pos = np.repeat(np.arange(T), m)[keep]
-        reloc_w = W.ravel()[keep]
-        a_idx, b_idx = np.triu_indices(T, 1)
-        pair_ok = (comp_of[a_idx] != comp_of[b_idx]) & (
-            base_tm[a_idx] != base_tm[b_idx]
+        # Per-round profiling span; ``sp`` is its record (None unrecorded).
+        round_span = (
+            contextlib.nullcontext()
+            if recorder is None
+            else recorder.span("refine.round", cat="refine", round=round_idx)
         )
-        swap_a, swap_b = a_idx[pair_ok], b_idx[pair_ok]
-        b1, b2 = reloc_pos.size, swap_a.size
-        # Each candidate = two column writes (a relocate writes one column
-        # twice), so construction chunks alongside scoring.
-        pos_a = np.concatenate([reloc_pos, swap_a])
-        val_a = np.concatenate([reloc_w, base_tm[swap_b]])
-        pos_b = np.concatenate([reloc_pos, swap_b])
-        val_b = np.concatenate([reloc_w, base_tm[swap_a]])
-        scores = np.empty(b1 + b2, dtype=np.float64)
-        chunk = _effective_chunk(cluster, n)
-        for start in range(0, b1 + b2, chunk):
-            stop = min(start + chunk, b1 + b2)
-            tm = np.tile(base_tm, (stop - start, 1))
-            rows = np.arange(stop - start)
-            tm[rows, pos_a[start:stop]] = val_a[start:stop]
-            tm[rows, pos_b[start:stop]] = val_b[start:stop]
-            scores[start:stop] = state.score_task_machine_batch(
-                tm, n_inst, backend=backend
-            )[1]
-        if b1 + b2:
-            i = int(np.argmax(scores))
-            s = float(scores[i])
-            if i < b1:
-                p, w = int(reloc_pos[i]), int(reloc_w[i])
-                c = int(comp_of[p])
-                k, src = p - int(offsets[c]), int(base_tm[p])
-                offer(
-                    s,
-                    f"relocate c{c}#{k} m{src}->m{w}",
-                    lambda c=c, k=k, w=w: state.relocate_instance(c, k, w),
-                )
-            else:
-                pa, pb = int(swap_a[i - b1]), int(swap_b[i - b1])
-                ca, cb = int(comp_of[pa]), int(comp_of[pb])
-                ka, kb = pa - int(offsets[ca]), pb - int(offsets[cb])
-                offer(
-                    s,
-                    f"swap c{ca}#{ka}<->c{cb}#{kb}",
-                    lambda ca=ca, ka=ka, cb=cb, kb=kb: state.swap_instances(
-                        ca, ka, cb, kb
-                    ),
-                )
+        with round_span as sp:
+            best_move: tuple[float, str, "function"] | None = None
 
-        if allow_add:
-            def apply_adds(placements):
-                for c, w in placements:
-                    state.add_instance(c, w)
+            def offer(score: float, desc: str, apply_fn) -> None:
+                nonlocal best_move
+                if score > best + tol and (best_move is None or score > best_move[0]):
+                    best_move = (score, desc, apply_fn)
 
-            # Greedy growth is deterministic, so the reference's independent
-            # greedy_grow re-runs traverse shared prefixes: one 4-step chain
-            # per component yields the ADD candidate (step 1) and the
-            # GROW k=2/3/4 candidates (steps 2-4); PAIRGROW forks off the
-            # first one or two steps of the first component's chain. The
-            # lockstep explorer advances every chain together — 4
-            # per-row-count sweeps per round regardless of component count;
-            # the sequential explorer steps chains one m-row sweep at a
-            # time. Both produce bit-identical chain scores. Offers follow
-            # the reference enumeration order (ADD..., GROW..., PAIRGROW...,
-            # DROP...), which matters for exact-tie breaking under the
-            # strict-> first-max rule.
-            explore = (
-                _growth_chains_lockstep if lockstep else _growth_chains_sequential
+            base_tm = state.task_machine()
+            offsets = state.component_offsets()
+            T = int(base_tm.shape[0])
+            # Copy: growth exploration below mutates state.n_instances in place
+            # before snapshot/restore swaps in a fresh array.
+            n_inst = state.n_instances.copy()
+            comp_of = np.repeat(np.arange(n), n_inst)
+
+            # RELOCATE + SWAP share the template (counts unchanged): candidates
+            # are 1-2 column edits on the base row, scored in one sweep. Within
+            # the concatenated [relocate..., swap...] order, np.argmax is the
+            # reference's first strictly-greater winner.
+            W = np.tile(np.arange(m), (T, 1))
+            keep = (W != base_tm[:, None]).ravel()
+            reloc_pos = np.repeat(np.arange(T), m)[keep]
+            reloc_w = W.ravel()[keep]
+            a_idx, b_idx = np.triu_indices(T, 1)
+            pair_ok = (comp_of[a_idx] != comp_of[b_idx]) & (
+                base_tm[a_idx] != base_tm[b_idx]
             )
-            singles, pair_a, pair_b, pairs = explore(
-                state, base_tm, offsets, n_inst, backend, adaptive_growth
-            )
-            # ADD: the reference's first-max over machines is exactly the
-            # chain's first greedy step (same scores, same argmax).
-            for c in range(n):
-                ch = singles[c]
-                offer(
-                    ch.scores[0],
-                    f"add c{c}->m{ch.placements[0][1]}",
-                    lambda p=ch.placements[:1]: apply_adds(p),
-                )
-            # GROW: k instances of one component at once — the eq. 6
-            # re-split means gains often appear only at specific counts,
-            # invisible to single adds. Adaptive chains extend the menu
-            # past k=4 for as deep as their scores kept improving.
-            for c in range(n):
-                ch = singles[c]
-                for k in range(2, len(ch.scores) + 1):
+            swap_a, swap_b = a_idx[pair_ok], b_idx[pair_ok]
+            b1, b2 = reloc_pos.size, swap_a.size
+            # Each candidate = two column writes (a relocate writes one column
+            # twice), so construction chunks alongside scoring.
+            pos_a = np.concatenate([reloc_pos, swap_a])
+            val_a = np.concatenate([reloc_w, base_tm[swap_b]])
+            pos_b = np.concatenate([reloc_pos, swap_b])
+            val_b = np.concatenate([reloc_w, base_tm[swap_a]])
+            scores = np.empty(b1 + b2, dtype=np.float64)
+            chunk = _effective_chunk(cluster, n)
+            for start in range(0, b1 + b2, chunk):
+                stop = min(start + chunk, b1 + b2)
+                with trace.span("refine.build", "refine"):
+                    tm = np.tile(base_tm, (stop - start, 1))
+                    rows = np.arange(stop - start)
+                    tm[rows, pos_a[start:stop]] = val_a[start:stop]
+                    tm[rows, pos_b[start:stop]] = val_b[start:stop]
+                scores[start:stop] = state.score_task_machine_batch(
+                    tm, n_inst, backend=backend
+                )[1]
+            if b1 + b2:
+                i = int(np.argmax(scores))
+                s = float(scores[i])
+                if i < b1:
+                    p, w = int(reloc_pos[i]), int(reloc_w[i])
+                    c = int(comp_of[p])
+                    k, src = p - int(offsets[c]), int(base_tm[p])
                     offer(
-                        ch.scores[k - 1],
-                        f"grow c{c}x{k}",
-                        lambda p=ch.placements[:k]: apply_adds(p),
+                        s,
+                        f"relocate c{c}#{k} m{src}->m{w}",
+                        lambda c=c, k=k, w=w: state.relocate_instance(c, k, w),
                     )
-            # PAIRGROW: components often need to grow *together* — the
-            # eq. 6 re-split creates valleys between (x, y) and
-            # (x+a, y+b) that per-component moves cannot cross. The (a, b)
-            # combo is the (a + b)-step prefix of the (a, ·) pair chain.
-            for ci, cj in pairs:
-                pa, pb = pair_a[(ci, cj)], pair_b[(ci, cj)]
-                for (a, b), ch in (
-                    ((1, 1), pa),
-                    ((2, 1), pb),
-                    ((1, 2), pa),
-                    ((2, 2), pb),
-                ):
+                else:
+                    pa, pb = int(swap_a[i - b1]), int(swap_b[i - b1])
+                    ca, cb = int(comp_of[pa]), int(comp_of[pb])
+                    ka, kb = pa - int(offsets[ca]), pb - int(offsets[cb])
                     offer(
-                        ch.scores[a + b - 1],
-                        f"pairgrow c{ci}x{a}+c{cj}x{b}",
-                        lambda p=ch.placements[: a + b]: apply_adds(p),
-                    )
-                # Adaptive extension of the pair menu: (a, b > 2) combos
-                # for as deep as each pair chain kept improving.
-                max_b = max(len(pa.scores) - 1, len(pb.scores) - 2)
-                for b in range(3, max_b + 1):
-                    for a, ch in ((1, pa), (2, pb)):
-                        if len(ch.scores) - a >= b:
-                            offer(
-                                ch.scores[a + b - 1],
-                                f"pairgrow c{ci}x{a}+c{cj}x{b}",
-                                lambda p=ch.placements[: a + b]: apply_adds(p),
-                            )
-            # DROP: which instance to delete, over every component with
-            # >= 2 instances — column removals on the base row, all scored
-            # in one per-row-count sweep (winner still picked per component
-            # to preserve the reference offer order).
-            drop_rows: list[np.ndarray] = []
-            drop_counts: list[np.ndarray] = []
-            drop_span: list[tuple[int, int]] = []
-            for c in range(n):
-                nk = int(n_inst[c])
-                if nk < 2:
-                    continue
-                cols = np.arange(T - 1)
-                idx = cols[None, :] + (
-                    cols[None, :] >= (int(offsets[c]) + np.arange(nk))[:, None]
-                )
-                n_new = n_inst.copy()
-                n_new[c] -= 1
-                drop_rows.append(base_tm[idx])
-                drop_counts.append(np.tile(n_new, (nk, 1)))
-                drop_span.append((c, nk))
-            if drop_rows:
-                _, sd_all = state.score_task_machine_batch(
-                    np.concatenate(drop_rows, axis=0),
-                    np.concatenate(drop_counts, axis=0),
-                    backend=backend,
-                )
-                start = 0
-                for c, nk in drop_span:
-                    sd = sd_all[start : start + nk]
-                    start += nk
-                    k = int(np.argmax(sd))
-                    offer(
-                        float(sd[k]),
-                        f"drop c{c}#{k}",
-                        lambda c=c, k=k: state.drop_instance(c, k),
+                        s,
+                        f"swap c{ca}#{ka}<->c{cb}#{kb}",
+                        lambda ca=ca, ka=ka, cb=cb, kb=kb: state.swap_instances(
+                            ca, ka, cb, kb
+                        ),
                     )
 
-        if best_move is None:
-            if round_span is not None:
-                sp["args"]["move"] = None
-                round_span.__exit__(None, None, None)
-            break
-        best, desc, apply_fn = best_move
-        apply_fn()
-        moves.append(desc)
-        if round_span is not None:
-            sp["args"]["move"] = desc
-            sp["args"]["score"] = float(best)
-            round_span.__exit__(None, None, None)
+            if allow_add:
+                def apply_adds(placements):
+                    for c, w in placements:
+                        state.add_instance(c, w)
+
+                # Greedy growth is deterministic, so the reference's independent
+                # greedy_grow re-runs traverse shared prefixes: one 4-step chain
+                # per component yields the ADD candidate (step 1) and the
+                # GROW k=2/3/4 candidates (steps 2-4); PAIRGROW forks off the
+                # first one or two steps of the first component's chain. The
+                # lockstep explorer advances every chain together — 4
+                # per-row-count sweeps per round regardless of component count;
+                # the sequential explorer steps chains one m-row sweep at a
+                # time. Both produce bit-identical chain scores. Offers follow
+                # the reference enumeration order (ADD..., GROW..., PAIRGROW...,
+                # DROP...), which matters for exact-tie breaking under the
+                # strict-> first-max rule.
+                explore = (
+                    _growth_chains_lockstep if lockstep else _growth_chains_sequential
+                )
+                singles, pair_a, pair_b, pairs = explore(
+                    state, base_tm, offsets, n_inst, backend, adaptive_growth
+                )
+                # ADD: the reference's first-max over machines is exactly the
+                # chain's first greedy step (same scores, same argmax).
+                for c in range(n):
+                    ch = singles[c]
+                    offer(
+                        ch.scores[0],
+                        f"add c{c}->m{ch.placements[0][1]}",
+                        lambda p=ch.placements[:1]: apply_adds(p),
+                    )
+                # GROW: k instances of one component at once — the eq. 6
+                # re-split means gains often appear only at specific counts,
+                # invisible to single adds. Adaptive chains extend the menu
+                # past k=4 for as deep as their scores kept improving.
+                for c in range(n):
+                    ch = singles[c]
+                    for k in range(2, len(ch.scores) + 1):
+                        offer(
+                            ch.scores[k - 1],
+                            f"grow c{c}x{k}",
+                            lambda p=ch.placements[:k]: apply_adds(p),
+                        )
+                # PAIRGROW: components often need to grow *together* — the
+                # eq. 6 re-split creates valleys between (x, y) and
+                # (x+a, y+b) that per-component moves cannot cross. The (a, b)
+                # combo is the (a + b)-step prefix of the (a, ·) pair chain.
+                for ci, cj in pairs:
+                    pa, pb = pair_a[(ci, cj)], pair_b[(ci, cj)]
+                    for (a, b), ch in (
+                        ((1, 1), pa),
+                        ((2, 1), pb),
+                        ((1, 2), pa),
+                        ((2, 2), pb),
+                    ):
+                        offer(
+                            ch.scores[a + b - 1],
+                            f"pairgrow c{ci}x{a}+c{cj}x{b}",
+                            lambda p=ch.placements[: a + b]: apply_adds(p),
+                        )
+                    # Adaptive extension of the pair menu: (a, b > 2) combos
+                    # for as deep as each pair chain kept improving.
+                    max_b = max(len(pa.scores) - 1, len(pb.scores) - 2)
+                    for b in range(3, max_b + 1):
+                        for a, ch in ((1, pa), (2, pb)):
+                            if len(ch.scores) - a >= b:
+                                offer(
+                                    ch.scores[a + b - 1],
+                                    f"pairgrow c{ci}x{a}+c{cj}x{b}",
+                                    lambda p=ch.placements[: a + b]: apply_adds(p),
+                                )
+                # DROP: which instance to delete, over every component with
+                # >= 2 instances — column removals on the base row, all scored
+                # in one per-row-count sweep (winner still picked per component
+                # to preserve the reference offer order).
+                drop_rows: list[np.ndarray] = []
+                drop_counts: list[np.ndarray] = []
+                drop_span: list[tuple[int, int]] = []
+                with trace.span("refine.build", "refine"):
+                    for c in range(n):
+                        nk = int(n_inst[c])
+                        if nk < 2:
+                            continue
+                        cols = np.arange(T - 1)
+                        idx = cols[None, :] + (
+                            cols[None, :]
+                            >= (int(offsets[c]) + np.arange(nk))[:, None]
+                        )
+                        n_new = n_inst.copy()
+                        n_new[c] -= 1
+                        drop_rows.append(base_tm[idx])
+                        drop_counts.append(np.tile(n_new, (nk, 1)))
+                        drop_span.append((c, nk))
+                    if drop_span:
+                        drop_tm = np.concatenate(drop_rows, axis=0)
+                        drop_n = np.concatenate(drop_counts, axis=0)
+                if drop_span:
+                    _, sd_all = state.score_task_machine_batch(
+                        drop_tm, drop_n, backend=backend
+                    )
+                    start = 0
+                    for c, nk in drop_span:
+                        sd = sd_all[start : start + nk]
+                        start += nk
+                        k = int(np.argmax(sd))
+                        offer(
+                            float(sd[k]),
+                            f"drop c{c}#{k}",
+                            lambda c=c, k=k: state.drop_instance(c, k),
+                        )
+
+            if best_move is None:
+                if sp is not None:
+                    sp["args"]["move"] = None
+                break
+            best, desc, apply_fn = best_move
+            apply_fn()
+            moves.append(desc)
+            if sp is not None:
+                sp["args"]["move"] = desc
+                sp["args"]["score"] = float(best)
 
     final = state.to_etg()
     rate, thpt = max_stable_rate(final, cluster, skew=skew)
-    return RefineResult(etg=final, rate=rate, throughput=thpt, moves=moves)
+    return RefineResult(
+        etg=final, rate=rate, throughput=thpt, moves=moves,
+        candidates=state.rows_scored - rows0,
+    )

@@ -58,6 +58,7 @@ import numpy as np
 from repro.core import cost_model
 from repro.core.graph import ExecutionGraph, UserGraph
 from repro.core.profiles import Cluster
+from repro.obs import trace
 
 __all__ = ["ScheduleState", "maximize_throughput_incremental"]
 
@@ -99,6 +100,7 @@ class ScheduleState:
         "cir_unit",
         "mem_c",
         "skew",
+        "rows_scored",
         "_met_load",
         "_var_load",
         "_mem_load",
@@ -133,6 +135,8 @@ class ScheduleState:
         self._var_load: np.ndarray | None = None
         self._mem_load: np.ndarray | None = None
         self._net_load: np.ndarray | None = None
+        # Candidate rows scored by ``score_task_machine_batch`` so far.
+        self.rows_scored = 0
 
     @classmethod
     def from_etg(
@@ -529,7 +533,23 @@ class ScheduleState:
             or ``"auto"`` (JAX above the regime's calibrated element-count crossover,
             machine-count gated on CPU — skew rows dispatch under the
             ``"skew"`` regime; the jitted kernel is skew-agnostic).
+
+        Each call is one ``refine.sweep`` span on the active recorder and
+        adds its B rows to ``rows_scored`` and the ``refine.rows`` counter.
         """
+        with trace.span("refine.sweep", "refine"):
+            out = self._score_batch(task_machine, n_instances, backend)
+        rows = int(out[1].shape[0])
+        self.rows_scored += rows
+        trace.count("refine.rows", rows)
+        return out
+
+    def _score_batch(
+        self,
+        task_machine: np.ndarray,
+        n_instances: np.ndarray | None,
+        backend: str,
+    ) -> tuple[np.ndarray, np.ndarray]:
         n_inst = self.n_instances if n_instances is None else np.asarray(
             n_instances, dtype=np.int64
         )

@@ -25,6 +25,7 @@ import numpy as np
 
 from repro.core.graph import ExecutionGraph
 from repro.core.profiles import Cluster
+from repro.obs import trace
 
 __all__ = ["simulate_batch_jax", "max_stable_rate_batch_jax", "closed_form_rates_jax"]
 
@@ -47,7 +48,7 @@ def _compiled_kernel(static: tuple):
     src = frozenset(sources)
 
     @jax.jit
-    def kernel(task_machine, comp, n_inst, e_cm, met_cm, capacity, r0):
+    def simulate_fixed_point(task_machine, comp, n_inst, e_cm, met_cm, capacity, r0):
         """Fixed point over machine scale factors s (B, m).
 
         ``r0`` is a (B,) per-candidate offered-rate vector (a scalar sweep
@@ -60,6 +61,10 @@ def _compiled_kernel(static: tuple):
         loop body is two einsum contractions plus the O(n) topo recurrence —
         no per-task gathers/scatters until the final readout.
         """
+        with jax.named_scope("simulate_fixed_point"):
+            return _fixed_point(task_machine, comp, n_inst, e_cm, met_cm, capacity, r0)
+
+    def _fixed_point(task_machine, comp, n_inst, e_cm, met_cm, capacity, r0):
         B, T = task_machine.shape
         m = capacity.shape[0]
         rows = jnp.arange(B)[:, None]
@@ -118,7 +123,7 @@ def _compiled_kernel(static: tuple):
         util = jnp.zeros((B, m), dtype=e.dtype).at[rows, task_machine].add(tcu)
         return ir, pr, tcu, util, pr.sum(axis=1)
 
-    return kernel
+    return simulate_fixed_point
 
 
 def _static_descriptor(etg: ExecutionGraph) -> tuple:
@@ -234,6 +239,11 @@ def _msr_kernel(per_row: bool = False, with_resources: bool = False):
     feasibility mask (absent resource types are passed as zeros /
     +inf). Kept as separate cached kernels so scalar-CPU scoring never
     re-traces and executes byte-for-byte the legacy contraction.
+
+    Each variant is named by what it computes — ``msr_shared``,
+    ``msr_per_row``, ``msr_resources_shared``, ``msr_resources_per_row`` —
+    as its jitted function (the module ``jit_<name>`` in a profiler trace)
+    and as a ``jax.named_scope`` around its body.
     """
     import jax
     import jax.numpy as jnp
@@ -266,35 +276,39 @@ def _msr_kernel(per_row: bool = False, with_resources: bool = False):
         thpt = rates * (unit_ir.sum(axis=1) if per_row else unit_ir.sum())
         return rates, thpt
 
-    if not with_resources:
+    name = ("msr_resources_" if with_resources else "msr_") + (
+        "per_row" if per_row else "shared"
+    )
 
-        @jax.jit
-        def kernel(task_machine, comp, unit_ir, e_cm, met_cm, capacity):
+    def kernel(task_machine, comp, unit_ir, e_cm, met_cm, capacity):
+        with jax.named_scope(name):
             _, var_w, met_w = _accumulate(
                 task_machine, comp, unit_ir, e_cm, met_cm, capacity
             )
             return _finish(var_w, met_w, capacity, unit_ir)
 
-        return kernel
-
-    @jax.jit
     def kernel_resources(
         task_machine, comp, unit_ir, e_cm, met_cm, capacity,
         net_var, mem, mem_capacity,
     ):
-        onehot, var_w, met_w = _accumulate(
-            task_machine, comp, unit_ir, e_cm, met_cm, capacity
-        )
-        var_w = var_w + net_var
-        mem_bt = mem if mem.ndim == 2 else mem[None, :]
-        mem_w = jnp.sum(jnp.where(onehot, mem_bt[:, None, :], 0.0), axis=-1)
-        mem_cap_b = (
-            mem_capacity if mem_capacity.ndim == 2 else mem_capacity[None, :]
-        )
-        over_mem = jnp.any(mem_w > mem_cap_b, axis=1)
-        return _finish(var_w, met_w, capacity, unit_ir, infeasible_extra=over_mem)
+        with jax.named_scope(name):
+            onehot, var_w, met_w = _accumulate(
+                task_machine, comp, unit_ir, e_cm, met_cm, capacity
+            )
+            var_w = var_w + net_var
+            mem_bt = mem if mem.ndim == 2 else mem[None, :]
+            mem_w = jnp.sum(jnp.where(onehot, mem_bt[:, None, :], 0.0), axis=-1)
+            mem_cap_b = (
+                mem_capacity if mem_capacity.ndim == 2 else mem_capacity[None, :]
+            )
+            over_mem = jnp.any(mem_w > mem_cap_b, axis=1)
+            return _finish(
+                var_w, met_w, capacity, unit_ir, infeasible_extra=over_mem
+            )
 
-    return kernel_resources
+    fn = kernel_resources if with_resources else kernel
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn)
 
 
 def closed_form_rates_jax(
@@ -323,31 +337,36 @@ def closed_form_rates_jax(
     mask. All-``None`` (the scalar-CPU default) runs the exact legacy
     kernels; absent resource types are filled with zeros / +inf for the
     resource variant.
+
+    On the active recorder the sweep is three spans — ``sweep.put`` (the
+    operands' ``jax.device_put``), ``sweep.run`` (the jitted call) and
+    ``sweep.fetch`` (the ``np.asarray`` of rates and throughput) — and the
+    operands' ``nbytes`` add to the ``sweep.h2d_bytes`` counter.
     """
     import jax
 
-    has_resources = (
+    operands = [task_machine, comp, unit_ir, e_cm, met_cm, capacity]
+    with_resources = (
         net_var is not None or mem is not None or mem_capacity is not None
     )
-    if not has_resources:
-        with jax.enable_x64(True):
-            rates, thpt = _msr_kernel(per_row=comp.ndim == 2)(
-                task_machine, comp, unit_ir, e_cm, met_cm, capacity
-            )
-        return np.asarray(rates), np.asarray(thpt)
-    B = task_machine.shape[0]
-    m = capacity.shape[-1]
-    if net_var is None:
-        net_var = np.zeros((B, m), dtype=np.float64)
-    if mem is None:
-        mem = np.zeros(comp.shape[-1], dtype=np.float64)
-        mem_capacity = np.full(m, np.inf, dtype=np.float64)
+    if with_resources:
+        B = task_machine.shape[0]
+        m = capacity.shape[-1]
+        if net_var is None:
+            net_var = np.zeros((B, m), dtype=np.float64)
+        if mem is None:
+            mem = np.zeros(comp.shape[-1], dtype=np.float64)
+            mem_capacity = np.full(m, np.inf, dtype=np.float64)
+        operands += [net_var, mem, mem_capacity]
+    kernel = _msr_kernel(per_row=comp.ndim == 2, with_resources=with_resources)
     with jax.enable_x64(True):
-        rates, thpt = _msr_kernel(per_row=comp.ndim == 2, with_resources=True)(
-            task_machine, comp, unit_ir, e_cm, met_cm, capacity,
-            net_var, mem, mem_capacity,
-        )
-    return np.asarray(rates), np.asarray(thpt)
+        with trace.span("sweep.put", "sweep"):
+            on_device = jax.device_put(operands)
+        trace.count("sweep.h2d_bytes", sum(int(x.nbytes) for x in operands))
+        with trace.span("sweep.run", "sweep"):
+            rates, thpt = kernel(*on_device)
+    with trace.span("sweep.fetch", "sweep"):
+        return np.asarray(rates), np.asarray(thpt)
 
 
 def max_stable_rate_batch_jax(

@@ -19,12 +19,23 @@ Design constraints:
   so the inactive path is a single module-global ``None`` check;
 * this module imports only the standard library (and sibling
   ``repro.obs`` modules), so it can be imported from anywhere in
-  ``repro`` without cycles.
+  ``repro`` without cycles. Once the program has imported ``jax``, every
+  span is mirrored onto the profiler's clock as a
+  ``jax.profiler.TraceAnnotation`` of the bare span name, so a profiler
+  trace names what the host was doing while the device waited.
+
+Per-decision summaries: when an enabled recorder's outermost span closes,
+the recorder appends its wall time, the self time of every span name
+inside it and the counter deltas made while it was open to a bounded
+process-wide buffer, :func:`recent` — a flight recorder of the last
+replans' time breakdown.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
+import sys
 import time
 from dataclasses import dataclass
 from typing import Any, Iterator
@@ -37,8 +48,15 @@ __all__ = [
     "NULL_RECORDER",
     "TraceRecorder",
     "active_recorder",
+    "count",
+    "recent",
     "record_dispatch",
+    "span",
 ]
+
+# Summaries of the newest outermost spans that :func:`recent` returns.
+RECENT_CAPACITY = 16_384
+_RECENT: collections.deque[dict[str, Any]] = collections.deque(maxlen=RECENT_CAPACITY)
 
 
 @dataclass(frozen=True)
@@ -68,6 +86,16 @@ class DispatchDecision:
         }
 
 
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` of ``name``, or ``None`` while
+    the program has not imported ``jax`` (this module never imports it)."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    if profiler is None:
+        return None
+    return profiler.TraceAnnotation(name)
+
+
 class _Span:
     """Lightweight span context manager (cheaper than a generator CM).
 
@@ -75,10 +103,13 @@ class _Span:
     program order even for nested spans) and its ``dur`` — in virtual
     ticks — is filled in at ``__exit__``.  The object returned by
     ``__enter__`` is the record dict, which the caller may mutate to
-    attach result arguments discovered during the span.
+    attach result arguments discovered during the span.  The span is
+    also timed on ``time.perf_counter_ns`` for the recorder's
+    per-decision summaries and mirrored onto the profiler's clock; neither
+    touches the record.
     """
 
-    __slots__ = ("_recorder", "_name", "_cat", "_args", "_rec", "_w0")
+    __slots__ = ("_recorder", "_name", "_cat", "_args", "_rec", "_w0", "_ann")
 
     def __init__(self, recorder: "TraceRecorder", name: str, cat: str,
                  args: dict[str, Any]) -> None:
@@ -88,16 +119,27 @@ class _Span:
         self._args = args
         self._rec: dict[str, Any] | None = None
         self._w0 = 0.0
+        self._ann = None
 
     def __enter__(self) -> dict[str, Any]:
-        rec = self._recorder._record("span", self._name, self._cat, self._args)
+        recorder = self._recorder
+        rec = recorder._record("span", self._name, self._cat, self._args)
         self._rec = rec
-        if self._recorder.wall_clock:
+        if recorder.wall_clock:
             self._w0 = time.perf_counter()
+        recorder._open(self._name)
+        ann = _annotation(self._name)
+        if ann is not None:
+            ann.__enter__()
+            self._ann = ann
         return rec
 
     def __exit__(self, *exc: Any) -> None:
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
         recorder = self._recorder
+        recorder._close()
         rec = self._rec
         recorder._tick += 1
         rec["dur"] = recorder._tick - rec["ts"]
@@ -134,6 +176,11 @@ class TraceRecorder:
         self._dispatch_counters: dict[tuple[str, str], Any] = {}
         self._dispatch_rows: list[tuple] = []
         self._dispatch_cache: list[DispatchDecision] = []
+        # Open spans' [name, start_ns, children_ns], outermost first, and
+        # the outermost span's self time per name and counters at entry.
+        self._open_spans: list[list] = []
+        self._self_ns: dict[str, int] = {}
+        self._counters0: dict[str, float] = {}
 
     # ---------------------------------------------------------------- clock
 
@@ -258,6 +305,36 @@ class TraceRecorder:
         """Record a structured replan decision (``repro.obs.ledger.ReplanDecision``)."""
         self._record("decision", f"replan:{dec.outcome}", "decision", dec.to_record())
 
+    # ------------------------------------------------------ wall summaries
+
+    def _counter_values(self) -> dict[str, float]:
+        return {m.name: m.value for m in self.metrics if m.kind == "counter"}
+
+    def _open(self, name: str) -> None:
+        if not self._open_spans:
+            self._self_ns = {}
+            self._counters0 = self._counter_values()
+        self._open_spans.append([name, time.perf_counter_ns(), 0])
+
+    def _close(self) -> None:
+        name, t0, children = self._open_spans.pop()
+        wall = time.perf_counter_ns() - t0
+        self._self_ns[name] = self._self_ns.get(name, 0) + wall - children
+        if self._open_spans:
+            self._open_spans[-1][2] += wall
+            return
+        c0 = self._counters0
+        _RECENT.append({
+            "name": name,
+            "wall_s": wall * 1e-9,
+            "self_s": {k: v * 1e-9 for k, v in self._self_ns.items()},
+            "counters": {
+                k: v - c0.get(k, 0.0)
+                for k, v in self._counter_values().items()
+                if v != c0.get(k, 0.0)
+            },
+        })
+
     # ------------------------------------------------------------ activation
 
     def activate(self) -> contextlib.AbstractContextManager["TraceRecorder"]:
@@ -291,6 +368,46 @@ def active_recorder() -> TraceRecorder | None:
     return _ACTIVE
 
 
+class _NullContext:
+    def __enter__(self) -> None:
+        return None
+
+    def __exit__(self, *exc: Any) -> None:
+        return None
+
+
+_NULL_CTX = _NullContext()
+
+
+def span(name: str, cat: str = "span") -> _Span | _NullContext:
+    """A span named ``name`` on the active recorder — for code too far
+    from the caller to thread a recorder through (candidate building,
+    scoring sweeps, transfers). With no active recorder: one global read
+    and the shared null context."""
+    rec = _ACTIVE
+    if rec is None:
+        return _NULL_CTX
+    return rec.span(name, cat)
+
+
+def count(name: str, amount: float) -> None:
+    """Add ``amount`` to the active recorder's counter ``name``; nothing
+    when no recorder is active."""
+    rec = _ACTIVE
+    if rec is None:
+        return
+    rec.metrics.counter(name).add(amount)
+
+
+def recent() -> list[dict[str, Any]]:
+    """Summaries of the newest outermost spans of enabled recorders, oldest
+    first, at most ``RECENT_CAPACITY``: ``{"name", "wall_s", "self_s":
+    {span name: seconds inside it and in none of its children},
+    "counters": {counter: nonzero delta while the span was open}}``. Self
+    times sum to ``wall_s`` (timed in integer nanoseconds)."""
+    return list(_RECENT)
+
+
 def record_dispatch(
     requested: str,
     backend: str,
@@ -309,17 +426,6 @@ def record_dispatch(
     if rec is None:
         return
     rec.dispatch(requested, backend, regime, elements, n_machines, site)
-
-
-class _NullContext:
-    def __enter__(self) -> None:
-        return None
-
-    def __exit__(self, *exc: Any) -> None:
-        return None
-
-
-_NULL_CTX = _NullContext()
 
 
 class NullRecorder:

@@ -265,6 +265,10 @@ def _evaluate_jax(etg, cluster, traces, policies, config) -> PolicyEvalResult:
     cfg = config
 
     def step(carry, xs):
+        with jax.named_scope("policy_eval"):
+            return _window(carry, xs)
+
+    def _window(carry, xs):
         backlog, prev_out, throttle = carry       # (B,P,T) (B,P,n) (B,P)
         r_t, cap, shares_t = xs                   # (B,) (B,m) tuple of (B,N)
         r_adm = r_t[:, None] * throttle           # (B,P)
@@ -330,8 +334,10 @@ def _evaluate_jax(etg, cluster, traces, policies, config) -> PolicyEvalResult:
         )
         return (backlog, prev_out, throttle_next), metrics
 
+    # Named by what it computes: the module ``jit_policy_eval`` in a profiler
+    # trace, its scan body under the ``policy_eval`` scope.
     @jax.jit
-    def sweep(rates, caps, key_shares):
+    def policy_eval(rates, caps, key_shares):
         carry0 = (
             jnp.zeros((B, P, T)),
             jnp.zeros((B, P, n)),
@@ -341,7 +347,7 @@ def _evaluate_jax(etg, cluster, traces, policies, config) -> PolicyEvalResult:
         return ms
 
     with jax.enable_x64(True):
-        thpt, adm, drp, qtot, thr, util = sweep(rates, caps, key_shares)
+        thpt, adm, drp, qtot, thr, util = policy_eval(rates, caps, key_shares)
 
     def wbp(x):  # (W, B, P) -> (B, P, W)
         return np.asarray(x).transpose(1, 2, 0)
