@@ -62,8 +62,10 @@ from repro.obs import trace
 
 __all__ = ["RefineResult", "refine"]
 
-# Candidate rows scored per vectorized sweep; bounds the (chunk, T) batch
-# memory on large clusters without changing results (rows are independent).
+# Candidate rows built per materialised RELOCATE+SWAP sweep (NumPy scoring,
+# or clusters with resources); bounds the (chunk, T) batch memory on large
+# clusters without changing results (rows are independent). Device sweeps
+# of the edits are not chunked by it (``ScheduleState.score_relocate_swap``).
 # Network-aware clusters tighten this further (see ``_effective_chunk``):
 # the cut-traffic term expands every row into (n_components, m) scatter
 # tensors plus distance matvecs, so the naive cap would materialize the
@@ -643,11 +645,12 @@ def _refine_state(
     Per round, every move family is expressed as edits on the flattened
     (T,) task->machine row exported from ``ScheduleState`` and scored in
     vectorized ``max_stable_rate_batch`` sweeps — one sweep covers all
-    RELOCATE+SWAP candidates, four depth-lockstep per-row-count sweeps
-    cover every growth chain (ADD/GROW/PAIRGROW), and one more covers all
-    DROP candidates: ~6 sweeps per round. Candidate scores are
-    bit-identical to the reference engine's scalar scoring (same
-    ``max_stable_rate_batch`` row computation), and winners are selected
+    RELOCATE+SWAP candidates (``ScheduleState.score_relocate_swap``), four
+    depth-lockstep per-row-count sweeps cover every growth chain
+    (ADD/GROW/PAIRGROW), and one more covers all DROP candidates: ~6 sweeps
+    per round. NumPy-scored candidates are bit-identical to the reference
+    engine's scalar scoring (same ``max_stable_rate_batch`` row
+    computation); device sweeps agree to ~1e-15. Winners are selected
     with the same strict-``>`` first-max semantics in the same enumeration
     order, so both engines apply the same move sequence. Applying a move is
     an O(m) ``ScheduleState`` delta; growth exploration carries candidate
@@ -664,7 +667,6 @@ def _refine_state(
         )
     rows0 = state.rows_scored  # the incumbent is no candidate
     moves: list[str] = []
-    m = cluster.n_machines
     n = state.utg.n_components
 
     for round_idx in range(max_rounds):
@@ -691,51 +693,26 @@ def _refine_state(
             comp_of = np.repeat(np.arange(n), n_inst)
 
             # RELOCATE + SWAP share the template (counts unchanged): candidates
-            # are 1-2 column edits on the base row, scored in one sweep. Within
-            # the concatenated [relocate..., swap...] order, np.argmax is the
-            # reference's first strictly-greater winner.
-            W = np.tile(np.arange(m), (T, 1))
-            keep = (W != base_tm[:, None]).ravel()
-            reloc_pos = np.repeat(np.arange(T), m)[keep]
-            reloc_w = W.ravel()[keep]
-            a_idx, b_idx = np.triu_indices(T, 1)
-            pair_ok = (comp_of[a_idx] != comp_of[b_idx]) & (
-                base_tm[a_idx] != base_tm[b_idx]
+            # are 1-2 column edits on the base row, scored in one sweep (the
+            # rows are built only where NumPy or a resource-aware cluster
+            # scores them). Within the [relocate..., swap...] order, np.argmax
+            # is the reference's first strictly-greater winner.
+            edits, scores = state.score_relocate_swap(
+                base_tm, backend=backend, row_chunk=_effective_chunk(cluster, n)
             )
-            swap_a, swap_b = a_idx[pair_ok], b_idx[pair_ok]
-            b1, b2 = reloc_pos.size, swap_a.size
-            # Each candidate = two column writes (a relocate writes one column
-            # twice), so construction chunks alongside scoring.
-            pos_a = np.concatenate([reloc_pos, swap_a])
-            val_a = np.concatenate([reloc_w, base_tm[swap_b]])
-            pos_b = np.concatenate([reloc_pos, swap_b])
-            val_b = np.concatenate([reloc_w, base_tm[swap_a]])
-            scores = np.empty(b1 + b2, dtype=np.float64)
-            chunk = _effective_chunk(cluster, n)
-            for start in range(0, b1 + b2, chunk):
-                stop = min(start + chunk, b1 + b2)
-                with trace.span("refine.build", "refine"):
-                    tm = np.tile(base_tm, (stop - start, 1))
-                    rows = np.arange(stop - start)
-                    tm[rows, pos_a[start:stop]] = val_a[start:stop]
-                    tm[rows, pos_b[start:stop]] = val_b[start:stop]
-                scores[start:stop] = state.score_task_machine_batch(
-                    tm, n_inst, backend=backend
-                )[1]
-            if b1 + b2:
+            if scores.size:
                 i = int(np.argmax(scores))
                 s = float(scores[i])
-                if i < b1:
-                    p, w = int(reloc_pos[i]), int(reloc_w[i])
-                    c = int(comp_of[p])
-                    k, src = p - int(offsets[c]), int(base_tm[p])
+                pa, wa, pb, wb = (int(x) for x in edits[:, i])
+                if pa == pb:
+                    c = int(comp_of[pb])
+                    k, src = pb - int(offsets[c]), int(base_tm[pb])
                     offer(
                         s,
-                        f"relocate c{c}#{k} m{src}->m{w}",
-                        lambda c=c, k=k, w=w: state.relocate_instance(c, k, w),
+                        f"relocate c{c}#{k} m{src}->m{wb}",
+                        lambda c=c, k=k, w=wb: state.relocate_instance(c, k, w),
                     )
                 else:
-                    pa, pb = int(swap_a[i - b1]), int(swap_b[i - b1])
                     ca, cb = int(comp_of[pa]), int(comp_of[pb])
                     ka, kb = pa - int(offsets[ca]), pb - int(offsets[cb])
                     offer(

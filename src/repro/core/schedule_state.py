@@ -70,6 +70,23 @@ __all__ = ["ScheduleState", "maximize_throughput_incremental"]
 _RSTAR_GUARD = 1e-9
 
 
+# Grid cells of one RELOCATE+SWAP sweep on the device
+# (``ScheduleState.score_relocate_swap``): a sweep of A moving tasks scores
+# an (A, m) relocate grid and an (A, T) swap grid. At 2**22 the paper's
+# large scenario (478 tasks, 180 machines) is one sweep per round.
+_EDIT_SWEEP_CELLS = 1 << 22
+
+
+def _edited_rows(base: np.ndarray, edits: np.ndarray) -> np.ndarray:
+    """(B, T) rows of ``base`` with ``row[pos_a] = val_a`` then ``row[pos_b]
+    = val_b`` for ``(pos_a, val_a, pos_b, val_b) = edits[:, b]``."""
+    tm = np.tile(base, (edits.shape[1], 1))
+    rows = np.arange(edits.shape[1])
+    tm[rows, edits[0]] = edits[1]
+    tm[rows, edits[2]] = edits[3]
+    return tm
+
+
 class ScheduleState:
     """Flat, incrementally-updatable schedule state (structure of arrays).
 
@@ -544,6 +561,126 @@ class ScheduleState:
         trace.count("refine.rows", rows)
         return out
 
+    def score_relocate_swap(
+        self, base: np.ndarray, backend: str, row_chunk: int
+    ) -> tuple[np.ndarray, np.ndarray]:
+        """Closed-form throughput of every RELOCATE and SWAP candidate of
+        the (T,) ``base`` row under the state's instance counts.
+
+        Returns ``(edits, throughput)``: candidate b is ``base`` with
+        ``row[pos_a] = val_a`` then ``row[pos_b] = val_b`` for ``(pos_a,
+        val_a, pos_b, val_b) = edits[:, b]`` — every task to every other
+        machine (a relocate writes one column twice), then every two tasks
+        of different components on different machines trading machines,
+        each family in task order (refine's menu and its tie order).
+
+        The menu is scored in sweeps of ``_EDIT_SWEEP_CELLS // (m + T)``
+        moving tasks (one sweep on the paper's clusters), each resolved like
+        a ``score_task_machine_batch`` call of the rows it stands for (same
+        elements, regime and machine count). A device sweep ships only the
+        base row to ``sim_jax``'s ``msr_edits`` kernel, which scores the
+        sweep's relocate and swap grids, and bumps the ``sweep.edit_rows``
+        counter by its candidates; a NumPy sweep builds the rows
+        ``row_chunk`` at a time and scores them with the reference core, so
+        its scores are bit-identical to ``score_task_machine_batch``'s.
+        Clusters with network or memory resources score the rows
+        ``row_chunk`` at a time through ``score_task_machine_batch`` on
+        either backend: there a move changes cut traffic on machines it
+        does not touch.
+
+        Each sweep is one ``refine.sweep`` span and adds its candidates to
+        ``rows_scored`` and the ``refine.rows`` counter.
+        """
+        n_tasks, m = int(base.shape[0]), self.cluster.n_machines
+        comp = np.repeat(np.arange(self.utg.n_components), self.n_instances)
+        moves = np.arange(m)[None, :] != base[:, None]              # (T, m)
+        pairs = np.triu(
+            (comp[:, None] != comp[None, :]) & (base[:, None] != base[None, :]),
+            k=1,
+        )                                                            # (T, T)
+        reloc_pos, reloc_w = np.nonzero(moves)
+        swap_a, swap_b = np.nonzero(pairs)
+        edits = np.stack([
+            np.concatenate([reloc_pos, swap_a]),
+            np.concatenate([reloc_w, base[swap_b]]),
+            np.concatenate([reloc_pos, swap_b]),
+            np.concatenate([reloc_w, base[swap_a]]),
+        ])
+        thpt = np.empty(edits.shape[1], dtype=np.float64)
+        if self.cluster.has_resources:
+            for start in range(0, thpt.size, row_chunk):
+                part = slice(start, min(start + row_chunk, thpt.size))
+                with trace.span("refine.build", "refine"):
+                    tm = _edited_rows(base, edits[:, part])
+                thpt[part] = self.score_task_machine_batch(tm, backend=backend)[1]
+            return edits, thpt
+        # Candidates of tasks [a0, a1) are one slice of each family.
+        reloc_end = np.cumsum(moves.sum(axis=1))
+        swap_end = reloc_pos.size + np.cumsum(pairs.sum(axis=1))
+        block = max(1, _EDIT_SWEEP_CELLS // (m + n_tasks))
+        for a0 in range(0, n_tasks, block):
+            a1 = min(a0 + block, n_tasks)
+            parts = [
+                slice(reloc_end[a0 - 1] if a0 else 0, reloc_end[a1 - 1]),
+                slice(swap_end[a0 - 1] if a0 else reloc_pos.size, swap_end[a1 - 1]),
+            ]
+            rows = sum(p.stop - p.start for p in parts)
+            if rows == 0:
+                continue
+            with trace.span("refine.sweep", "refine"):
+                self._score_moves(
+                    base, edits, parts, (a0, a1), moves, pairs, thpt,
+                    backend, row_chunk,
+                )
+            self.rows_scored += rows
+            trace.count("refine.rows", rows)
+        return edits, thpt
+
+    def _score_moves(
+        self,
+        base: np.ndarray,
+        edits: np.ndarray,
+        parts: list[slice],
+        tasks: tuple[int, int],
+        moves: np.ndarray,
+        pairs: np.ndarray,
+        thpt: np.ndarray,
+        backend: str,
+        row_chunk: int,
+    ) -> None:
+        """One sweep of ``score_relocate_swap`` on a cluster without
+        resources: the candidates of moving tasks ``tasks``, which fill
+        ``thpt[parts]``."""
+        rows = sum(p.stop - p.start for p in parts)
+        comp, unit_ir, regime = self._task_maps(self.n_instances, rows, base.size)
+        from repro.core.simulator import resolve_closed_form_backend
+
+        resolved = resolve_closed_form_backend(
+            backend,
+            rows * base.size,
+            regime=regime,
+            n_machines=self.cluster.n_machines,
+            site="score_relocate_swap",
+        )
+        if resolved == "jax":
+            from repro.core.sim_jax import relocate_swap_scores_jax
+
+            trace.count("sweep.edit_rows", rows)
+            a = slice(*tasks)
+            relocate, swap = relocate_swap_scores_jax(
+                base, np.arange(*tasks), comp, unit_ir,
+                self.e_cm, self.met_cm, self.cluster.capacity,
+            )
+            thpt[parts[0]] = relocate[moves[a]]
+            thpt[parts[1]] = swap[pairs[a]]
+            return
+        for part in parts:
+            for start in range(part.start, part.stop, row_chunk):
+                chunk = slice(start, min(start + row_chunk, part.stop))
+                with trace.span("refine.build", "refine"):
+                    tm = _edited_rows(base, edits[:, chunk])
+                thpt[chunk] = self._score_rows(tm, comp, unit_ir, "numpy")[1]
+
     def _score_batch(
         self,
         task_machine: np.ndarray,
@@ -553,91 +690,67 @@ class ScheduleState:
         n_inst = self.n_instances if n_instances is None else np.asarray(
             n_instances, dtype=np.int64
         )
-        n = self.utg.n_components
         task_machine = np.asarray(task_machine, dtype=np.int64)
         if task_machine.ndim != 2:
             raise ValueError("task_machine must be (B, sum(n_instances))")
+        comp, unit_ir, regime = self._task_maps(n_inst, *task_machine.shape)
         from repro.core.simulator import resolve_closed_form_backend
 
-        n_machines = self.cluster.capacity.shape[0]
-        if self.skew is not None:
-            # Skew-aware scoring: keyed components' unit IR comes from the
-            # realized per-instance fractions; the gathers below feed the
-            # same closed-form core either backend runs.
-            if n_inst.ndim == 2:
-                if n_inst.shape != (task_machine.shape[0], n):
-                    raise ValueError("per-row n_instances must be (B, n)")
-                comp, _ = cost_model.per_row_task_maps(
-                    self.cir_unit, n_inst, task_machine.shape[1]
-                )
-                unit_ir = self.skew.per_row_unit_ir(n_inst)
-                gather_comp = comp
-            else:
-                comp = np.repeat(np.arange(n), n_inst)
-                if task_machine.shape[1] != comp.shape[0]:
-                    raise ValueError("task_machine must be (B, sum(n_instances))")
-                unit_ir = self.skew.per_task_unit_ir(n_inst)
-                gather_comp = comp[None, :]
-            net_var, mem, mem_cap = self._resource_operands(
-                task_machine, comp, unit_ir
-            )
-            if (
-                resolve_closed_form_backend(
-                    backend,
-                    task_machine.size,
-                    regime="skew",
-                    n_machines=n_machines,
-                    site="score_task_machine_batch",
-                )
-                == "jax"
-            ):
-                from repro.core.sim_jax import closed_form_rates_jax
+        resolved = resolve_closed_form_backend(
+            backend,
+            task_machine.size,
+            regime=regime,
+            n_machines=self.cluster.n_machines,
+            site="score_task_machine_batch",
+        )
+        return self._score_rows(task_machine, comp, unit_ir, resolved)
 
-                return closed_form_rates_jax(
-                    task_machine,
-                    comp,
-                    unit_ir,
-                    self.e_cm,
-                    self.met_cm,
-                    self.cluster.capacity,
-                    net_var=net_var,
-                    mem=mem,
-                    mem_capacity=mem_cap,
-                )
-            e = self.e_cm[gather_comp, task_machine]
-            met = self.met_cm[gather_comp, task_machine]
-            return cost_model.closed_form_rates(
-                task_machine, e, met, unit_ir, self.cluster.capacity,
-                net_var=net_var, mem=mem, mem_capacity=mem_cap,
-            )
+    def _task_maps(
+        self, n_inst: np.ndarray, rows: int, n_tasks: int
+    ) -> tuple[np.ndarray, np.ndarray, str]:
+        """Per-task (component, unit IR) maps of B candidate rows and the
+        dispatch regime they score under: (T,) maps for shared counts,
+        (B, T) for per-row counts. Keyed components' unit IR comes from the
+        realized per-instance fractions under a skew model; either backend
+        feeds the maps to the same closed-form core."""
+        n = self.utg.n_components
         if n_inst.ndim == 2:
-            if n_inst.shape != (task_machine.shape[0], n):
+            if n_inst.shape != (rows, n):
                 raise ValueError("per-row n_instances must be (B, n)")
             comp, unit_ir = cost_model.per_row_task_maps(
-                self.cir_unit, n_inst, task_machine.shape[1]
+                self.cir_unit, n_inst, n_tasks
             )                                             # each (B, T)
-            gather_comp = comp
+            if self.skew is not None:
+                unit_ir = self.skew.per_row_unit_ir(n_inst)
         else:
             comp = np.repeat(np.arange(n), n_inst)
-            if task_machine.shape[1] != comp.shape[0]:
+            if n_tasks != comp.shape[0]:
                 raise ValueError("task_machine must be (B, sum(n_instances))")
-            # Per-component division then gather: per-element operands match
-            # instance_rates()' per-task division exactly, so floats agree.
-            unit_ir = (self.cir_unit / n_inst)[comp]
-            gather_comp = comp[None, :]
+            if self.skew is not None:
+                unit_ir = self.skew.per_task_unit_ir(n_inst)
+            else:
+                # Per-component division then gather: per-element operands
+                # match instance_rates()' per-task division exactly, so
+                # floats agree.
+                unit_ir = (self.cir_unit / n_inst)[comp]
+        if self.skew is not None:
+            regime = "skew"
+        else:
+            regime = "per_row" if n_inst.ndim == 2 else "shared"
+        return comp, unit_ir, regime
+
+    def _score_rows(
+        self,
+        task_machine: np.ndarray,
+        comp: np.ndarray,
+        unit_ir: np.ndarray,
+        backend: str,
+    ) -> tuple[np.ndarray, np.ndarray]:
+        """Closed form of materialised (B, T) rows on a resolved backend."""
         net_var, mem, mem_cap = self._resource_operands(
             task_machine, comp, unit_ir
         )
-        if (
-            resolve_closed_form_backend(
-                backend,
-                task_machine.size,
-                regime="per_row" if n_inst.ndim == 2 else "shared",
-                n_machines=n_machines,
-                site="score_task_machine_batch",
-            )
-            == "jax"
-        ):
+        if backend == "jax":
             from repro.core.sim_jax import closed_form_rates_jax
 
             return closed_form_rates_jax(
@@ -651,6 +764,7 @@ class ScheduleState:
                 mem=mem,
                 mem_capacity=mem_cap,
             )
+        gather_comp = comp if comp.ndim == 2 else comp[None, :]
         e = self.e_cm[gather_comp, task_machine]          # (B, T)
         met = self.met_cm[gather_comp, task_machine]
         return cost_model.closed_form_rates(

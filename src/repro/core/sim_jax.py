@@ -27,10 +27,19 @@ from repro.core.graph import ExecutionGraph
 from repro.core.profiles import Cluster
 from repro.obs import trace
 
-__all__ = ["simulate_batch_jax", "max_stable_rate_batch_jax", "closed_form_rates_jax"]
+__all__ = [
+    "simulate_batch_jax",
+    "max_stable_rate_batch_jax",
+    "closed_form_rates_jax",
+    "relocate_swap_scores_jax",
+]
 
 _MAX_ITERS = 200
 _TOL = 1e-10
+
+# Machines the edit kernel ranks from the base row: a move touches two, so
+# the third best is always untouched.
+_EDIT_SPARE = 3
 
 
 @functools.lru_cache(maxsize=None)
@@ -202,8 +211,10 @@ def simulate_batch_jax(
 # ----------------------------------------------------- closed-form scoring
 
 
-@functools.lru_cache(maxsize=4)
-def _msr_kernel(per_row: bool = False, with_resources: bool = False):
+@functools.lru_cache(maxsize=8)
+def _msr_kernel(
+    per_row: bool = False, with_resources: bool = False, edits: bool = False
+):
     """Jitted closed-form max-stable-rate scorer (paper eq. 5 linearity).
 
     Mirrors ``cost_model.max_stable_rate_batch``'s NumPy math: per-machine
@@ -240,10 +251,27 @@ def _msr_kernel(per_row: bool = False, with_resources: bool = False):
     +inf). Kept as separate cached kernels so scalar-CPU scoring never
     re-traces and executes byte-for-byte the legacy contraction.
 
+    ``edits=True`` selects ``msr_edits``, the edit path: refine's
+    RELOCATE and SWAP candidates of one base row, which each change the
+    totals of two machines only. It takes the (T,) base row, the (A,)
+    tasks that move and the shared maps, computes the base's ``var_w`` /
+    ``met_w`` once with the same contraction, ranks the base's machines
+    once, and returns the throughput of an (A, m) relocate grid (task a to
+    machine w) and an (A, T) swap grid (tasks a and b trading machines):
+    each cell patches the two touched machines' sums and runs ``_finish``
+    on them and on the best machine the move leaves alone. That is O(1)
+    per candidate against the row kernel's O(T·m), with nothing per
+    candidate shipped: on a TPU a gather indexed per candidate costs about
+    its table's size per index, so the candidates are dense grids rather
+    than per-row lookups. Refine uses it for every RELOCATE+SWAP sweep that
+    resolves to JAX on a cluster without network or memory resources
+    (``ScheduleState.score_relocate_swap``); there a move changes no
+    machine it does not touch.
+
     Each variant is named by what it computes — ``msr_shared``,
-    ``msr_per_row``, ``msr_resources_shared``, ``msr_resources_per_row`` —
-    as its jitted function (the module ``jit_<name>`` in a profiler trace)
-    and as a ``jax.named_scope`` around its body.
+    ``msr_per_row``, ``msr_resources_shared``, ``msr_resources_per_row``,
+    ``msr_edits`` — as its jitted function (the module ``jit_<name>`` in a
+    profiler trace) and as a ``jax.named_scope`` around its body.
     """
     import jax
     import jax.numpy as jnp
@@ -276,9 +304,14 @@ def _msr_kernel(per_row: bool = False, with_resources: bool = False):
         thpt = rates * (unit_ir.sum(axis=1) if per_row else unit_ir.sum())
         return rates, thpt
 
-    name = ("msr_resources_" if with_resources else "msr_") + (
-        "per_row" if per_row else "shared"
-    )
+    if edits:
+        if per_row or with_resources:
+            raise ValueError("the edit kernel takes shared maps and no resources")
+        name = "msr_edits"
+    else:
+        name = ("msr_resources_" if with_resources else "msr_") + (
+            "per_row" if per_row else "shared"
+        )
 
     def kernel(task_machine, comp, unit_ir, e_cm, met_cm, capacity):
         with jax.named_scope(name):
@@ -306,7 +339,93 @@ def _msr_kernel(per_row: bool = False, with_resources: bool = False):
                 var_w, met_w, capacity, unit_ir, infeasible_extra=over_mem
             )
 
-    fn = kernel_resources if with_resources else kernel
+    def kernel_edits(base, rows, comp, unit_ir, e_cm, met_cm, capacity):
+        with jax.named_scope(name):
+            _, var_w, met_w = _accumulate(
+                base[None, :], comp, unit_ir, e_cm, met_cm, capacity
+            )
+            m = capacity.shape[0]
+            # Neutral machines (no load, unbounded capacity) pad the base, so
+            # that three distinct machines rank first whatever m is.
+            pad = jnp.zeros(_EDIT_SPARE, dtype=var_w.dtype)
+            var_m = jnp.concatenate([var_w[0], pad])
+            met_m = jnp.concatenate([met_w[0], pad])
+            cap_m = jnp.concatenate([capacity, pad + jnp.inf])
+            # Rank the base's machines once: infeasible first, then by
+            # limit, ties by index. A move touches two machines, so the
+            # least limit among the others is that of the first of the three
+            # best that it leaves alone.
+            head = cap_m - met_m
+            key = jnp.where(
+                head < 0.0,
+                -jnp.inf,
+                jnp.where(var_m > 0.0, head / jnp.maximum(var_m, 1e-300), jnp.inf),
+            )
+            idx = jnp.arange(key.shape[0], dtype=base.dtype)
+            before = (key[:, None] < key[None, :]) | (
+                (key[:, None] == key[None, :]) & (idx[:, None] < idx[None, :])
+            )
+            rank = jnp.sum(before, axis=0, dtype=base.dtype)
+            best = [jnp.argmax(rank == k) for k in range(_EDIT_SPARE)]
+
+            def score(src, var_src, met_src, cap_src, dst, var_dst, met_dst, cap_dst):
+                """Throughput of a grid of candidates touching two machines,
+                ``src`` and ``dst``, with their new totals given."""
+                rest_var, rest_met, rest_cap = (
+                    t[best[-1]] for t in (var_m, met_m, cap_m)
+                )
+                for k in reversed(best[:-1]):
+                    free = (src != k) & (dst != k)
+                    rest_var = jnp.where(free, var_m[k], rest_var)
+                    rest_met = jnp.where(free, met_m[k], rest_met)
+                    rest_cap = jnp.where(free, cap_m[k], rest_cap)
+                shape = jnp.broadcast_shapes(src.shape, dst.shape)
+                cols = [
+                    jnp.stack([jnp.broadcast_to(x, shape) for x in xs], axis=-1)
+                    .reshape(-1, 3)
+                    for xs in (
+                        (var_src, var_dst, rest_var),
+                        (met_src, met_dst, rest_met),
+                        (cap_src, cap_dst, rest_cap),
+                    )
+                ]
+                return _finish(*cols, unit_ir)[1].reshape(shape)
+
+            # ev / mt: what task t adds to machine w's var_w / met_w there.
+            ev = e_cm[comp] * unit_ir[:, None]                 # (T, m)
+            mt = met_cm[comp]
+            ev_home = jnp.take_along_axis(ev, base[:, None], axis=1)[:, 0]
+            mt_home = jnp.take_along_axis(mt, base[:, None], axis=1)[:, 0]
+            # Rows of the block: task a leaves its machine s_a.
+            s_a = base[rows][:, None]                          # (A, 1)
+            var_sa = var_m[s_a] + -ev_home[rows][:, None]
+            met_sa = met_m[s_a] + -mt_home[rows][:, None]
+            cap_sa = cap_m[s_a]
+            # RELOCATE a -> w, every machine w (w == s_a is no candidate).
+            w = jnp.arange(m, dtype=base.dtype)[None, :]       # (1, m)
+            relocate = score(
+                s_a, var_sa, met_sa, cap_sa,
+                w, var_w + ev[rows], met_w + mt[rows], capacity[None, :],
+            )
+            # SWAP a <-> b, every task b: a joins s_b, b leaves s_b for s_a.
+            # Each machine's change is taken first, so that two tasks of
+            # equal load trading places leave its sum exactly as it was.
+            s_b = base[None, :]                                # (1, T)
+            swap = score(
+                s_a,
+                var_m[s_a] + (ev.T[s_a[:, 0]] - ev_home[rows][:, None]),
+                met_m[s_a] + (mt.T[s_a[:, 0]] - mt_home[rows][:, None]),
+                cap_sa,
+                s_b,
+                var_m[s_b] + (ev[rows].T[base].T - ev_home[None, :]),
+                met_m[s_b] + (mt[rows].T[base].T - mt_home[None, :]),
+                cap_m[s_b],
+            )
+            return relocate, swap
+
+    fn = kernel_edits if edits else (
+        kernel_resources if with_resources else kernel
+    )
     fn.__name__ = fn.__qualname__ = name
     return jax.jit(fn)
 
@@ -342,9 +461,12 @@ def closed_form_rates_jax(
     operands' ``jax.device_put``), ``sweep.run`` (the jitted call) and
     ``sweep.fetch`` (the ``np.asarray`` of rates and throughput) — and the
     operands' ``nbytes`` add to the ``sweep.h2d_bytes`` counter.
-    """
-    import jax
 
+    Refine's RELOCATE+SWAP rows are all edits of one base row; where they
+    would resolve to this function on a cluster without resources they go
+    to ``relocate_swap_scores_jax`` instead, which ships the base row
+    alone and scores the candidates as edits (``msr_edits``).
+    """
     operands = [task_machine, comp, unit_ir, e_cm, met_cm, capacity]
     with_resources = (
         net_var is not None or mem is not None or mem_capacity is not None
@@ -359,14 +481,63 @@ def closed_form_rates_jax(
             mem_capacity = np.full(m, np.inf, dtype=np.float64)
         operands += [net_var, mem, mem_capacity]
     kernel = _msr_kernel(per_row=comp.ndim == 2, with_resources=with_resources)
+    return _device_sweep(kernel, operands)
+
+
+def _device_sweep(kernel, operands: list) -> tuple[np.ndarray, ...]:
+    """Run a jitted scorer on host operands in float64: ``sweep.put`` (the
+    operands' ``jax.device_put``, whose ``nbytes`` add to the
+    ``sweep.h2d_bytes`` counter), ``sweep.run`` (the call, which only
+    enqueues) and ``sweep.fetch`` (the ``np.asarray`` of its results, where
+    the host waits for the device)."""
+    import jax
+
     with jax.enable_x64(True):
         with trace.span("sweep.put", "sweep"):
             on_device = jax.device_put(operands)
         trace.count("sweep.h2d_bytes", sum(int(x.nbytes) for x in operands))
         with trace.span("sweep.run", "sweep"):
-            rates, thpt = kernel(*on_device)
+            out = kernel(*on_device)
     with trace.span("sweep.fetch", "sweep"):
-        return np.asarray(rates), np.asarray(thpt)
+        return tuple(np.asarray(x) for x in out)
+
+
+def relocate_swap_scores_jax(
+    base: np.ndarray,
+    rows: np.ndarray,
+    comp: np.ndarray,
+    unit_ir: np.ndarray,
+    e_cm: np.ndarray,
+    met_cm: np.ndarray,
+    capacity: np.ndarray,
+) -> tuple[np.ndarray, np.ndarray]:
+    """Closed-form throughput of the RELOCATE and SWAP edits of one base
+    row, on ``msr_edits``.
+
+    ``base`` is the (T,) task->machine row and ``rows`` the (A,) tasks a
+    that move. Returns the (A, m) grid whose [a, w] entry scores ``base``
+    with task a on machine w, and the (A, T) grid whose [a, b] entry scores
+    it with tasks a and b trading machines. Entries that are no candidate
+    (w already a's machine, a and b on one machine) hold no meaning.
+    ``comp`` / ``unit_ir`` are the (T,) shared maps and ``capacity`` is
+    (m,): the edit kernel serves clusters without network or memory
+    resources. Each entry is what ``closed_form_rates_jax`` gives the
+    materialised row, up to the rounding of the two patched machine sums.
+
+    Only the base row and the tables ship (indices as int32), and only the
+    grids come back. Spans and the ``sweep.h2d_bytes`` counter are those of
+    ``closed_form_rates_jax``.
+    """
+    operands = [
+        np.asarray(base, dtype=np.int32),
+        np.asarray(rows, dtype=np.int32),
+        np.asarray(comp, dtype=np.int32),
+        unit_ir,
+        e_cm,
+        met_cm,
+        capacity,
+    ]
+    return _device_sweep(_msr_kernel(edits=True), operands)
 
 
 def max_stable_rate_batch_jax(

@@ -81,6 +81,24 @@ def test_msr_kernel_compiles_f64(one_chip, per_row, with_resources):
     assert "f64" in compiled.as_text()
 
 
+def test_edit_kernel_compiles_f64(one_chip):
+    """``msr_edits``: refine's relocate and swap grids of one base row,
+    scored in (emulated) float64."""
+    from repro.core.sim_jax import _msr_kernel
+
+    with jax.enable_x64(True):
+        compiled = _msr_kernel(edits=True).lower(
+            _spec(one_chip, (T,), jnp.int32),
+            _spec(one_chip, (T,), jnp.int32),
+            _spec(one_chip, (T,), jnp.int32),
+            _spec(one_chip, (T,), jnp.float64),
+            _spec(one_chip, (N_COMP, M), jnp.float64),
+            _spec(one_chip, (N_COMP, M), jnp.float64),
+            _spec(one_chip, (M,), jnp.float64),
+        ).compile()
+    assert "f64" in compiled.as_text()
+
+
 @pytest.mark.parametrize("with_resources", [False, True],
                          ids=["scalar", "resources"])
 def test_pallas_scoring_compiles_f32(one_chip, with_resources):

@@ -1,0 +1,251 @@
+"""RELOCATE+SWAP candidates scored as edits of one base row.
+
+``sim_jax``'s ``msr_edits`` kernel against ``msr_shared`` on the same
+candidates materialised as rows, and refine's routing of its relocate+swap
+sweep through ``ScheduleState.score_relocate_swap``: device sweeps of a
+cluster without resources take the edit path and count it in
+``sweep.edit_rows``; NumPy sweeps and clusters with resources keep rows.
+"""
+
+import numpy as np
+import pytest
+
+from repro.core import linear_topology, paper_cluster, schedule
+from repro.core.refine import refine
+from repro.core.schedule_state import ScheduleState
+from repro.core.sim_jax import closed_form_rates_jax, relocate_swap_scores_jax
+from repro.obs import TraceRecorder
+
+# Machine types of the kernel scenario: machines 1-3 share a type.
+MTYPE = np.array([0, 1, 1, 1, 2, 2])
+
+
+def _scenario(seed, tight=True):
+    """A small cluster and base row with the cases the kernel must meet:
+    machine 0 holds task 0 alone (moving it away empties it), and machines
+    1 and 2, of one type, hold one instance of each component at the same
+    places of their blocks (moves onto either tie). ``tight`` leaves
+    machine 0 no spare fixed capacity (moving any task onto it turns the
+    row infeasible); otherwise every machine has room and the binding
+    machine varies from row to row."""
+    rng = np.random.default_rng(seed)
+    counts = rng.integers(3, 5, size=3)
+    comp = np.repeat(np.arange(3), counts)
+    offsets = np.concatenate([[0], np.cumsum(counts)])
+    e_cm = rng.uniform(0.5, 2.0, size=(3, 3))[:, MTYPE]
+    met_cm = rng.uniform(0.05, 0.3, size=(3, 3))[:, MTYPE]
+    # Per-task unit rates (as a skew model gives), equal on the twins.
+    unit_ir = rng.uniform(0.2, 1.0, size=comp.size)
+    unit_ir[offsets[:3] + 2] = unit_ir[offsets[:3] + 1]
+    base = rng.integers(3, 6, size=comp.size)
+    base[0] = 0
+    base[offsets[:3] + 1] = 1
+    base[offsets[:3] + 2] = 2
+    met_base = np.bincount(base, met_cm[comp, base], minlength=MTYPE.size)
+    cap = met_base + rng.uniform(0.5, 3.0, size=MTYPE.size)
+    if tight:
+        cap[0] = met_base[0] + 0.01
+    cap[2] = cap[1]
+    return base, comp, unit_ir, e_cm, met_cm, cap
+
+
+def _menu(base, m):
+    """Edits of every relocation, then of every swap of tasks on different
+    machines, each family in task order, and the grid cells they score."""
+    moves = np.arange(m)[None, :] != base[:, None]
+    pairs = np.triu(base[:, None] != base[None, :], k=1)
+    p, w = np.nonzero(moves)
+    a, b = np.nonzero(pairs)
+    relocate = np.stack([p, w, p, w])
+    swap = np.stack([a, base[b], b, base[a]])
+    return relocate, swap, moves, pairs
+
+
+def _rows(base, edits):
+    tm = np.tile(base, (edits.shape[1], 1))
+    r = np.arange(edits.shape[1])
+    tm[r, edits[0]] = edits[1]
+    tm[r, edits[2]] = edits[3]
+    return tm
+
+
+@pytest.mark.parametrize("tight", [True, False], ids=["tight", "roomy"])
+@pytest.mark.parametrize("seed", [0, 1, 2, 3])
+@pytest.mark.parametrize("family", ["relocate", "swap"])
+def test_edit_kernel_agrees_with_the_row_kernel(seed, family, tight):
+    base, comp, unit_ir, e_cm, met_cm, cap = _scenario(seed, tight)
+    relocate, swap, moves, pairs = _menu(base, cap.size)
+    grids = relocate_swap_scores_jax(
+        base, np.arange(base.size), comp, unit_ir, e_cm, met_cm, cap
+    )
+    assert grids[0].shape == moves.shape and grids[1].shape == pairs.shape
+    edits, got = (relocate, grids[0][moves]) if family == "relocate" else (
+        swap, grids[1][pairs]
+    )
+    rows = _rows(base, edits)
+    want = closed_form_rates_jax(rows, comp, unit_ir, e_cm, met_cm, cap)[1]
+    np.testing.assert_allclose(got, want, rtol=1e-12, atol=0.0)
+    # Exact ties of the row kernel stay exact ties.
+    assert np.all((got[:, None] == got[None, :])[want[:, None] == want[None, :]])
+    # Task 0 leaving machine 0 empties it.
+    assert np.any((edits[0] == 0) & (got > 0.0))
+    if family == "relocate":
+        src, dst = base[edits[0]], edits[1]
+        # A task joining machine 0 with no spare fixed capacity makes the
+        # row infeasible.
+        onto_0 = dst == 0
+        assert onto_0.any() and np.all((want[onto_0] == 0.0) == tight)
+        assert np.all((got[onto_0] == 0.0) == tight)
+        # A task from machines 3-5 onto machine 1 or onto its twin 2.
+        to_1, to_2 = (dst == 1) & (src > 2), (dst == 2) & (src > 2)
+        assert to_1.any() and np.array_equal(edits[0][to_1], edits[0][to_2])
+        np.testing.assert_array_equal(want[to_1], want[to_2])
+        np.testing.assert_array_equal(got[to_1], got[to_2])
+
+
+def test_edit_kernel_scores_a_block_of_moving_tasks():
+    base, comp, unit_ir, e_cm, met_cm, cap = _scenario(1)
+    full = relocate_swap_scores_jax(
+        base, np.arange(base.size), comp, unit_ir, e_cm, met_cm, cap
+    )
+    rows = np.arange(2, 5)
+    block = relocate_swap_scores_jax(base, rows, comp, unit_ir, e_cm, met_cm, cap)
+    for f, b in zip(full, block):
+        np.testing.assert_array_equal(b, f[rows])
+
+
+def test_edit_kernel_ships_the_base_row_and_tables():
+    base, comp, unit_ir, e_cm, met_cm, cap = _scenario(0)
+    rec = TraceRecorder()
+    with rec.activate():
+        relocate_swap_scores_jax(
+            base, np.arange(base.size), comp, unit_ir, e_cm, met_cm, cap
+        )
+    counters = {m["name"]: m["value"] for m in rec.metrics.snapshot()}
+    int32_bytes = 4 * 3 * base.size
+    f64_bytes = unit_ir.nbytes + e_cm.nbytes + met_cm.nbytes + cap.nbytes
+    assert counters["sweep.h2d_bytes"] == int32_bytes + f64_bytes
+    assert [r["name"] for r in rec.records] == ["sweep.put", "sweep.run", "sweep.fetch"]
+
+
+def test_edit_kernel_carries_a_stable_name():
+    import jax
+
+    from repro.core.sim_jax import _msr_kernel
+
+    T, n, m = 3, 2, 4
+    args = [
+        np.zeros(T, np.int32), np.arange(T, dtype=np.int32), np.zeros(T, np.int32),
+        np.ones(T), np.ones((n, m)), np.ones((n, m)), np.ones(m),
+    ]
+    with jax.enable_x64(True):
+        text = _msr_kernel(edits=True).lower(*args).as_text(debug_info=True)
+    assert "jit_msr_edits" in text
+    assert '"msr_edits/' in text or "jit(msr_edits)/msr_edits/" in text
+
+
+@pytest.fixture(scope="module")
+def browned_out():
+    cluster = paper_cluster((2, 3, 4))
+    etg = schedule(linear_topology(), cluster, r0=1.0, rate_epsilon=1.0).etg
+    cap = cluster.capacity.copy()
+    cap[[0, 3]] *= 0.5
+    return etg, cluster.with_capacity(cap)
+
+
+def _relocate_swap_rows(monkeypatch):
+    """Candidates of every ``score_relocate_swap`` call, each checked
+    against the relocate+swap menu of the base row it was handed."""
+    seen = []
+    score = ScheduleState.score_relocate_swap
+
+    def logged(self, base, *args, **kw):
+        edits, thpt = score(self, base, *args, **kw)
+        comp = np.repeat(np.arange(self.utg.n_components), self.n_instances)
+        a, b = np.triu_indices(base.size, 1)
+        swaps = np.count_nonzero((comp[a] != comp[b]) & (base[a] != base[b]))
+        assert thpt.size == base.size * (self.cluster.n_machines - 1) + swaps
+        assert edits.shape == (4, thpt.size)
+        seen.append(thpt.size)
+        return edits, thpt
+
+    monkeypatch.setattr(ScheduleState, "score_relocate_swap", logged)
+    return seen
+
+
+def _counters(rec):
+    return {m["name"]: m["value"] for m in rec.metrics.snapshot()}
+
+
+def test_device_sweeps_score_relocate_swap_as_edits(monkeypatch, browned_out):
+    etg, cluster = browned_out
+    seen = _relocate_swap_rows(monkeypatch)
+    rec = TraceRecorder()
+    res = refine(etg, cluster, max_rounds=4, backend="jax", recorder=rec)
+    counters = _counters(rec)
+    assert counters["sweep.edit_rows"] == sum(seen) > 0
+    assert counters["refine.rows"] == res.candidates > sum(seen)
+    edit_sweeps = [d for d in rec.dispatch_log if d.site == "score_relocate_swap"]
+    assert len(edit_sweeps) == len(seen)
+    assert all(d.backend == "jax" and d.regime == "shared" for d in edit_sweeps)
+    same = refine(etg, cluster, max_rounds=4, backend="numpy")
+    assert res.moves == same.moves
+    assert res.throughput == pytest.approx(same.throughput, rel=1e-12)
+
+
+@pytest.mark.parametrize("cells", [1 << 22, 64])
+def test_device_and_numpy_sweeps_score_one_menu(monkeypatch, browned_out, cells):
+    """Both backends give the same candidates in the same order, with the
+    same scores to 1e-12, in one sweep or in several."""
+    from repro.core import schedule_state
+
+    monkeypatch.setattr(schedule_state, "_EDIT_SWEEP_CELLS", cells)
+    etg, cluster = browned_out
+    state = ScheduleState.from_etg(etg, cluster)
+    base = state.task_machine()
+    rec = TraceRecorder()
+    with rec.activate():
+        edits_np, numpy_ = state.score_relocate_swap(base, "numpy", 16_384)
+        edits_jx, jax_ = state.score_relocate_swap(base, "jax", 16_384)
+    np.testing.assert_array_equal(edits_np, edits_jx)
+    np.testing.assert_allclose(jax_, numpy_, rtol=1e-12, atol=0.0)
+    sweeps = [d for d in rec.dispatch_log if d.site == "score_relocate_swap"]
+    block = cells // (cluster.n_machines + base.size)
+    assert len(sweeps) == 2 * -(-base.size // block) == (2 if cells > 64 else 18)
+    np.testing.assert_array_equal(
+        numpy_,
+        state._score_rows(
+            np.concatenate([base[None, :], _rows(base, edits_np)]),
+            np.repeat(np.arange(state.utg.n_components), state.n_instances),
+            (state.cir_unit / state.n_instances)[
+                np.repeat(np.arange(state.utg.n_components), state.n_instances)
+            ],
+            "numpy",
+        )[1][1:],
+    )
+
+
+def test_numpy_sweeps_keep_rows(monkeypatch, browned_out):
+    etg, cluster = browned_out
+    seen = _relocate_swap_rows(monkeypatch)
+    rec = TraceRecorder()
+    res = refine(etg, cluster, max_rounds=4, backend="numpy", recorder=rec)
+    assert "sweep.edit_rows" not in _counters(rec)
+    assert seen and res.moves == refine(
+        etg, cluster, max_rounds=4, engine="reference"
+    ).moves
+
+
+def test_network_clusters_keep_rows_on_the_device(monkeypatch, browned_out):
+    from repro.core import rack_distance_matrix
+
+    etg, cluster = browned_out
+    racks = np.arange(cluster.n_machines) % 2
+    net = cluster.with_resources(distance=rack_distance_matrix(racks), net_penalty=0.05)
+    seen = _relocate_swap_rows(monkeypatch)
+    rec = TraceRecorder()
+    res = refine(etg, net, max_rounds=2, backend="jax", recorder=rec)
+    counters = _counters(rec)
+    assert seen and "sweep.edit_rows" not in counters
+    assert counters["refine.rows"] == res.candidates
+    assert not [d for d in rec.dispatch_log if d.site == "score_relocate_swap"]

@@ -207,7 +207,9 @@ def test_dispatch_log_covers_keyed_refine(cluster):
     for d in rec.dispatch_log:
         assert d.backend in ("numpy", "jax")
         assert d.requested in ("numpy", "jax", "auto")
-        assert d.site in ("max_stable_rate_batch", "score_task_machine_batch")
+        assert d.site in (
+            "max_stable_rate_batch", "score_task_machine_batch", "score_relocate_swap"
+        )
         assert d.elements is None or d.elements > 0
     # The dispatch stream also lands in the record list for exporters.
     assert sum(r["type"] == "dispatch" for r in rec.records) == len(rec.dispatch_log)

@@ -77,18 +77,25 @@ def test_trace_module_imports_no_jax():
 def test_candidates_equal_the_rows_of_every_sweep(monkeypatch, browned_out, backend):
     etg, cluster = browned_out
     shapes = []
-    score = ScheduleState._score_batch
+    score_rows = ScheduleState._score_batch
+    score_moves = ScheduleState._score_moves
 
-    def logged(self, task_machine, n_instances, backend):
+    def logged_rows(self, task_machine, n_instances, backend):
         shapes.append(np.shape(task_machine))
-        return score(self, task_machine, n_instances, backend)
+        return score_rows(self, task_machine, n_instances, backend)
 
-    monkeypatch.setattr(ScheduleState, "_score_batch", logged)
+    def logged_moves(self, base, edits, parts, *rest):
+        shapes.append((sum(p.stop - p.start for p in parts), base.shape[0]))
+        return score_moves(self, base, edits, parts, *rest)
+
+    monkeypatch.setattr(ScheduleState, "_score_batch", logged_rows)
+    monkeypatch.setattr(ScheduleState, "_score_moves", logged_moves)
     rec = TraceRecorder()
     res = refine(etg, cluster, max_rounds=4, backend=backend, recorder=rec)
     assert res.candidates == sum(b for b, _ in shapes) > 0
     # Σ over the dispatch log of elements ÷ row width, sweep by sweep.
-    sweeps = [d for d in rec.dispatch_log if d.site == "score_task_machine_batch"]
+    sites = {"score_task_machine_batch", "score_relocate_swap"}
+    sweeps = [d for d in rec.dispatch_log if d.site in sites]
     assert [d.elements for d in sweeps] == [b * t for b, t in shapes]
     assert res.candidates == sum(d.elements // t for d, (_, t) in zip(sweeps, shapes))
     counters = {m["name"]: m["value"] for m in rec.metrics.snapshot()}
