@@ -30,6 +30,7 @@ import numpy as np
 
 from repro.core.graph import ExecutionGraph, UserGraph
 from repro.core.profiles import Cluster
+from repro.obs import trace
 
 __all__ = [
     "component_rates",
@@ -432,45 +433,50 @@ def network_unit_load(
     ``comp`` / ``unit_ir`` are (T,) shared or (B, T) per-row task maps —
     exactly the operands ``closed_form_rates`` receives, so every scoring
     regime (shared / per-row / skew) prices the same network term.
-    """
-    task_machine = np.asarray(task_machine, dtype=np.int64)
-    B, T = task_machine.shape
-    n = cir_unit.shape[0]
-    m = distance.shape[0]
-    comp_bt = comp if comp.ndim == 2 else np.broadcast_to(comp[None, :], (B, T))
-    unit_bt = unit_ir if unit_ir.ndim == 2 else np.broadcast_to(
-        unit_ir[None, :], (B, T)
-    )
-    alpha = np.asarray(alpha, dtype=np.float64)
-    # Per-task sender output and receiver share (see docstring). A
-    # zero-input component carries no flow; its receive fraction is moot.
-    out_t = alpha[comp_bt] * unit_bt                         # (B, T)
-    cir_of_t = cir_unit[comp_bt]
-    with np.errstate(divide="ignore", invalid="ignore"):
-        rfrac_t = np.where(cir_of_t > 0.0, unit_bt / np.maximum(cir_of_t, 1e-300), 0.0)
 
-    net = np.empty((B, m), dtype=np.float64)
-    chunk = max(1, int(chunk_elems) // max(1, n * m))
-    for start in range(0, B, chunk):
-        stop = min(start + chunk, B)
-        bc = stop - start
-        rows = np.repeat(np.arange(bc), T)
-        cols_c = comp_bt[start:stop].reshape(-1)
-        cols_w = task_machine[start:stop].reshape(-1)
-        send = np.zeros((bc, n, m), dtype=np.float64)
-        recv = np.zeros((bc, n, m), dtype=np.float64)
-        np.add.at(send, (rows, cols_c, cols_w), out_t[start:stop].reshape(-1))
-        np.add.at(recv, (rows, cols_c, cols_w), rfrac_t[start:stop].reshape(-1))
-        # D-matvec per (row, component): charge on machine w is
-        # Σ_v distance[w, v] × (other endpoint's mass on v).
-        send_d = send @ distance.T                            # (bc, n, m)
-        recv_d = recv @ distance.T
-        acc = np.zeros((bc, m), dtype=np.float64)
-        for a, b in edges:
-            acc += send[:, a, :] * recv_d[:, b, :]            # sender side
-            acc += recv[:, b, :] * send_d[:, a, :]            # receiver side
-        net[start:stop] = acc
-    return net * float(net_penalty)
+    This is the NumPy reference of the term; device sweeps compute it from
+    their rows on the device (``sim_jax``'s ``_net_masses``). Each call is
+    one ``net.host`` span on the active recorder.
+    """
+    with trace.span("net.host", "sweep"):
+        task_machine = np.asarray(task_machine, dtype=np.int64)
+        B, T = task_machine.shape
+        n = cir_unit.shape[0]
+        m = distance.shape[0]
+        comp_bt = comp if comp.ndim == 2 else np.broadcast_to(comp[None, :], (B, T))
+        unit_bt = unit_ir if unit_ir.ndim == 2 else np.broadcast_to(
+            unit_ir[None, :], (B, T)
+        )
+        alpha = np.asarray(alpha, dtype=np.float64)
+        # Per-task sender output and receiver share (see docstring). A
+        # zero-input component carries no flow; its receive fraction is moot.
+        out_t = alpha[comp_bt] * unit_bt                         # (B, T)
+        cir_of_t = cir_unit[comp_bt]
+        with np.errstate(divide="ignore", invalid="ignore"):
+            rfrac_t = np.where(cir_of_t > 0.0, unit_bt / np.maximum(cir_of_t, 1e-300), 0.0)
+
+        net = np.empty((B, m), dtype=np.float64)
+        chunk = max(1, int(chunk_elems) // max(1, n * m))
+        for start in range(0, B, chunk):
+            stop = min(start + chunk, B)
+            bc = stop - start
+            rows = np.repeat(np.arange(bc), T)
+            cols_c = comp_bt[start:stop].reshape(-1)
+            cols_w = task_machine[start:stop].reshape(-1)
+            send = np.zeros((bc, n, m), dtype=np.float64)
+            recv = np.zeros((bc, n, m), dtype=np.float64)
+            np.add.at(send, (rows, cols_c, cols_w), out_t[start:stop].reshape(-1))
+            np.add.at(recv, (rows, cols_c, cols_w), rfrac_t[start:stop].reshape(-1))
+            # D-matvec per (row, component): charge on machine w is
+            # Σ_v distance[w, v] × (other endpoint's mass on v).
+            send_d = send @ distance.T                            # (bc, n, m)
+            recv_d = recv @ distance.T
+            acc = np.zeros((bc, m), dtype=np.float64)
+            for a, b in edges:
+                acc += send[:, a, :] * recv_d[:, b, :]            # sender side
+                acc += recv[:, b, :] * send_d[:, a, :]            # receiver side
+            net[start:stop] = acc
+        return net * float(net_penalty)
 
 
 def resource_operands(

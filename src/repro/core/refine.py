@@ -62,22 +62,24 @@ from repro.obs import trace
 
 __all__ = ["RefineResult", "refine"]
 
-# Candidate rows built per materialised RELOCATE+SWAP sweep (NumPy scoring,
-# or clusters with resources); bounds the (chunk, T) batch memory on large
-# clusters without changing results (rows are independent). Device sweeps
-# of the edits are not chunked by it (``ScheduleState.score_relocate_swap``).
-# Network-aware clusters tighten this further (see ``_effective_chunk``):
-# the cut-traffic term expands every row into (n_components, m) scatter
-# tensors plus distance matvecs, so the naive cap would materialize the
-# full edge×machine product on wide topologies (regression-tested at m=90).
+# Candidate rows built at a time when a RELOCATE+SWAP sweep scores on NumPy;
+# bounds the (chunk, T) batch memory on large clusters without changing
+# results (rows are independent). Device sweeps score the edits without
+# building rows, with or without resources, so this does not chunk them
+# (``ScheduleState.score_relocate_swap``). Network-aware clusters tighten
+# this further (see ``_effective_chunk``): the host cut-traffic term expands
+# every row into (n_components, m) scatter tensors plus distance matvecs,
+# so the naive cap would materialize the full edge×machine product on wide
+# topologies (regression-tested at m=90).
 _SCORE_CHUNK = 16_384
 
 
 def _effective_chunk(cluster: Cluster, n_components: int) -> int:
-    """Rows per scoring sweep: ``_SCORE_CHUNK``, tightened on network-aware
-    clusters so one sweep's distance-expanded accumulation stays within the
-    ``cost_model._NET_CHUNK_ELEMS`` (chunk · n · m) element budget instead
-    of relying on the inner chunking to re-split an oversized batch."""
+    """Rows per NumPy RELOCATE+SWAP chunk: ``_SCORE_CHUNK``, tightened on
+    network-aware clusters so one chunk's host cut-traffic accumulation
+    stays within the ``cost_model._NET_CHUNK_ELEMS`` (chunk · n · m)
+    element budget instead of relying on its inner chunking to re-split an
+    oversized batch."""
     if not cluster.has_network:
         return _SCORE_CHUNK
     per_row = max(1, n_components * cluster.n_machines)
@@ -694,8 +696,7 @@ def _refine_state(
 
             # RELOCATE + SWAP share the template (counts unchanged): candidates
             # are 1-2 column edits on the base row, scored in one sweep (the
-            # rows are built only where NumPy or a resource-aware cluster
-            # scores them). Within the [relocate..., swap...] order, np.argmax
+            # rows are built only where NumPy scores them). Within the [relocate..., swap...] order, np.argmax
             # is the reference's first strictly-greater winner.
             edits, scores = state.score_relocate_swap(
                 base_tm, backend=backend, row_chunk=_effective_chunk(cluster, n)

@@ -76,6 +76,12 @@ _RSTAR_GUARD = 1e-9
 # large scenario (478 tasks, 180 machines) is one sweep per round.
 _EDIT_SWEEP_CELLS = 1 << 22
 
+# The same budget on a cluster with network or memory resources, counted in
+# (candidate x machine) cells: every candidate is scored on all m machines,
+# an (A, m, m) relocate grid and an (A, T, m) swap grid. At 2**26 the
+# paper's large scenario is still one sweep per round (56.6M cells).
+_NET_EDIT_SWEEP_CELLS = 1 << 26
+
 
 def _edited_rows(base: np.ndarray, edits: np.ndarray) -> np.ndarray:
     """(B, T) rows of ``base`` with ``row[pos_a] = val_a`` then ``row[pos_b]
@@ -583,10 +589,12 @@ class ScheduleState:
         counter by its candidates; a NumPy sweep builds the rows
         ``row_chunk`` at a time and scores them with the reference core, so
         its scores are bit-identical to ``score_task_machine_batch``'s.
-        Clusters with network or memory resources score the rows
-        ``row_chunk`` at a time through ``score_task_machine_batch`` on
-        either backend: there a move changes cut traffic on machines it
-        does not touch.
+        On a cluster with network or memory resources a move changes the
+        cut traffic of machines it does not touch, so a device sweep scores
+        every candidate on every machine (``msr_edits_resources``, with the
+        cut traffic and the memory mask computed on the device), in sweeps
+        of ``_NET_EDIT_SWEEP_CELLS // ((m + T) * m)`` moving tasks, and also
+        bumps the ``sweep.net_rows`` counter.
 
         Each sweep is one ``refine.sweep`` span and adds its candidates to
         ``rows_scored`` and the ``refine.rows`` counter.
@@ -607,17 +615,14 @@ class ScheduleState:
             np.concatenate([reloc_w, base[swap_a]]),
         ])
         thpt = np.empty(edits.shape[1], dtype=np.float64)
-        if self.cluster.has_resources:
-            for start in range(0, thpt.size, row_chunk):
-                part = slice(start, min(start + row_chunk, thpt.size))
-                with trace.span("refine.build", "refine"):
-                    tm = _edited_rows(base, edits[:, part])
-                thpt[part] = self.score_task_machine_batch(tm, backend=backend)[1]
-            return edits, thpt
         # Candidates of tasks [a0, a1) are one slice of each family.
         reloc_end = np.cumsum(moves.sum(axis=1))
         swap_end = reloc_pos.size + np.cumsum(pairs.sum(axis=1))
-        block = max(1, _EDIT_SWEEP_CELLS // (m + n_tasks))
+        if self.cluster.has_resources:
+            block = _NET_EDIT_SWEEP_CELLS // ((m + n_tasks) * m)
+        else:
+            block = _EDIT_SWEEP_CELLS // (m + n_tasks)
+        block = max(1, block)
         for a0 in range(0, n_tasks, block):
             a1 = min(a0 + block, n_tasks)
             parts = [
@@ -648,9 +653,8 @@ class ScheduleState:
         backend: str,
         row_chunk: int,
     ) -> None:
-        """One sweep of ``score_relocate_swap`` on a cluster without
-        resources: the candidates of moving tasks ``tasks``, which fill
-        ``thpt[parts]``."""
+        """One sweep of ``score_relocate_swap``: the candidates of moving
+        tasks ``tasks``, which fill ``thpt[parts]``."""
         rows = sum(p.stop - p.start for p in parts)
         comp, unit_ir, regime = self._task_maps(self.n_instances, rows, base.size)
         from repro.core.simulator import resolve_closed_form_backend
@@ -665,11 +669,14 @@ class ScheduleState:
         if resolved == "jax":
             from repro.core.sim_jax import relocate_swap_scores_jax
 
+            resources = self._device_resources()
             trace.count("sweep.edit_rows", rows)
+            if resources is not None:
+                trace.count("sweep.net_rows", rows)
             a = slice(*tasks)
             relocate, swap = relocate_swap_scores_jax(
                 base, np.arange(*tasks), comp, unit_ir,
-                self.e_cm, self.met_cm, self.cluster.capacity,
+                self.e_cm, self.met_cm, self.cluster.capacity, resources,
             )
             thpt[parts[0]] = relocate[moves[a]]
             thpt[parts[1]] = swap[pairs[a]]
@@ -746,13 +753,16 @@ class ScheduleState:
         unit_ir: np.ndarray,
         backend: str,
     ) -> tuple[np.ndarray, np.ndarray]:
-        """Closed form of materialised (B, T) rows on a resolved backend."""
-        net_var, mem, mem_cap = self._resource_operands(
-            task_machine, comp, unit_ir
-        )
+        """Closed form of materialised (B, T) rows on a resolved backend.
+        A device sweep on a cluster with resources computes the cut traffic
+        and the memory mask on the device and bumps ``sweep.net_rows``; a
+        NumPy sweep prices them on the host (``net.host``)."""
         if backend == "jax":
             from repro.core.sim_jax import closed_form_rates_jax
 
+            resources = self._device_resources()
+            if resources is not None:
+                trace.count("sweep.net_rows", task_machine.shape[0])
             return closed_form_rates_jax(
                 task_machine,
                 comp,
@@ -760,16 +770,26 @@ class ScheduleState:
                 self.e_cm,
                 self.met_cm,
                 self.cluster.capacity,
-                net_var=net_var,
-                mem=mem,
-                mem_capacity=mem_cap,
+                resources,
             )
+        net_var, mem, mem_cap = self._resource_operands(
+            task_machine, comp, unit_ir
+        )
         gather_comp = comp if comp.ndim == 2 else comp[None, :]
         e = self.e_cm[gather_comp, task_machine]          # (B, T)
         met = self.met_cm[gather_comp, task_machine]
         return cost_model.closed_form_rates(
             task_machine, e, met, unit_ir, self.cluster.capacity,
             net_var=net_var, mem=mem, mem_capacity=mem_cap,
+        )
+
+    def _device_resources(self) -> list | None:
+        """``sim_jax.device_resources`` of the state's topology and cluster."""
+        from repro.core.sim_jax import device_resources
+
+        return device_resources(
+            self.cluster, self.utg.component_types, self.utg.edges,
+            self.utg.alpha, self.cir_unit,
         )
 
     def _resource_operands(

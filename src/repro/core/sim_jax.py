@@ -32,6 +32,8 @@ __all__ = [
     "max_stable_rate_batch_jax",
     "closed_form_rates_jax",
     "relocate_swap_scores_jax",
+    "device_resources",
+    "network_tables",
 ]
 
 _MAX_ITERS = 200
@@ -243,12 +245,20 @@ def _msr_kernel(
     row against its tenant's residual capacity); the rank difference is a
     trace-time constant, so both shapes share one cached variant.
 
-    ``with_resources=True`` selects the resource-vector variant: three
-    extra operands — ``net_var`` (B, m) cut-traffic load added to the
-    variable coefficient, ``mem`` per-task memory demand and
-    ``mem_capacity`` per-machine memory ceiling driving the hard
-    feasibility mask (absent resource types are passed as zeros /
-    +inf). Kept as separate cached kernels so scalar-CPU scoring never
+    ``with_resources=True`` selects the resource-vector variant. Its extra
+    operands are the tail ``device_resources`` builds: ``mem`` memory
+    demand per component (indexed by ``comp``) and ``mem_capacity``
+    per-machine memory ceiling driving the hard feasibility mask, then the
+    network tables — the (m, m)
+    ``distance``, the (n, n) edge-count ``adjacency`` (``adjacency[a, b]``
+    edges a -> b), ``alpha``, ``cir_unit`` and the scalar ``net_penalty``
+    (per-row (B, n, n) / (B, n) tables where rows carry different
+    topologies, as tenants do). The kernel builds each component's send
+    and receive mass per machine with the same one-hot contraction
+    (``_net_masses``) and prices the cut traffic with the distance matrix
+    on the device — ``cost_model.network_unit_load``'s term, added to the
+    variable coefficient. Absent resource types are zeros / +inf / no
+    components. Kept as separate cached kernels so scalar-CPU scoring never
     re-traces and executes byte-for-byte the legacy contraction.
 
     ``edits=True`` selects ``msr_edits``, the edit path: refine's
@@ -264,14 +274,27 @@ def _msr_kernel(
     candidate shipped: on a TPU a gather indexed per candidate costs about
     its table's size per index, so the candidates are dense grids rather
     than per-row lookups. Refine uses it for every RELOCATE+SWAP sweep that
-    resolves to JAX on a cluster without network or memory resources
-    (``ScheduleState.score_relocate_swap``); there a move changes no
-    machine it does not touch.
+    resolves to JAX (``ScheduleState.score_relocate_swap``).
+
+    ``edits=True, with_resources=True`` selects ``msr_edits_resources``,
+    the edit path of a cluster with network or memory resources. There a
+    move changes the cut traffic of every machine that hosts a
+    neighbouring component, so each candidate is scored on all m machines:
+    an (A, m, m) relocate grid and an (A, T, m) swap grid of machine
+    totals, each patched from the base's. Moving task t of component c
+    from u to v moves machine w's cut traffic by ``(D[w, v] - D[w, u]) *
+    K_t[w] + ([w == v] - [w == u]) * L_t[w]``, where K_t is the base's
+    neighbour mass of c weighted by t's rates and L_t its distance
+    product (``_net_masses``); a swap adds both tasks' terms and, for
+    adjacent components, the flow between the two tasks, which the two
+    terms each count as colocated. Memory changes on the two touched
+    machines only.
 
     Each variant is named by what it computes — ``msr_shared``,
     ``msr_per_row``, ``msr_resources_shared``, ``msr_resources_per_row``,
-    ``msr_edits`` — as its jitted function (the module ``jit_<name>`` in a
-    profiler trace) and as a ``jax.named_scope`` around its body.
+    ``msr_edits``, ``msr_edits_resources`` — as its jitted function (the
+    module ``jit_<name>`` in a profiler trace) and as a
+    ``jax.named_scope`` around its body.
     """
     import jax
     import jax.numpy as jnp
@@ -304,10 +327,55 @@ def _msr_kernel(
         thpt = rates * (unit_ir.sum(axis=1) if per_row else unit_ir.sum())
         return rates, thpt
 
+    def _net_masses(onehot, comp, unit_ir, distance, adjacency, alpha, cir_unit):
+        """Cut-traffic masses at unit rate, each (B, n, m): what the
+        instances of component c on machine w send (``send``) and what
+        share of c's input they receive (``recv``), the neighbour masses
+        ``r_out`` (c's children's ``recv``) and ``s_in`` (c's parents'
+        ``send``), and their distance products ``(D x)[w] = sum_v D[w, v]
+        x[v]``. Components count from the row's least ``comp``, so rows of
+        different topologies index their own (B, n, n) / (B, n) tables."""
+        cmap = comp if comp.ndim == 2 else comp[None, :]
+        u = unit_ir if unit_ir.ndim == 2 else unit_ir[None, :]
+        local = cmap - jnp.min(cmap, axis=-1, keepdims=True)
+        n = adjacency.shape[-1]
+        mass = jnp.stack(
+            [
+                jnp.sum(
+                    jnp.where(onehot & (local == c)[:, None, :], u[:, None, :], 0.0),
+                    axis=-1,
+                )
+                for c in range(n)
+            ],
+            axis=1,
+        )
+        adj = adjacency if adjacency.ndim == 3 else adjacency[None]
+        al = (alpha if alpha.ndim == 2 else alpha[None])[:, :, None]
+        cir = (cir_unit if cir_unit.ndim == 2 else cir_unit[None])[:, :, None]
+        send = al * mass
+        recv = jnp.where(cir > 0.0, mass / jnp.where(cir > 0.0, cir, 1.0), 0.0)
+        r_out = jnp.sum(adj[:, :, :, None] * recv[:, None, :, :], axis=2)
+        s_in = jnp.sum(adj[:, :, :, None] * send[:, :, None, :], axis=1)
+
+        def dist(x):
+            return jnp.sum(x[:, :, None, :] * distance[None, None, :, :], axis=-1)
+
+        return send, recv, r_out, s_in, dist(r_out), dist(s_in)
+
+    def _net_load(onehot, comp, unit_ir, distance, adjacency, alpha, cir_unit, penalty):
+        """(B, m) cut-traffic CPU load at unit rate, ``network_unit_load``'s
+        term: each machine pays for its flows to every other machine."""
+        if adjacency.shape[-1] == 0:
+            return jnp.zeros(onehot.shape[:2], dtype=unit_ir.dtype)
+        send, recv, _, _, d_r_out, d_s_in = _net_masses(
+            onehot, comp, unit_ir, distance, adjacency, alpha, cir_unit
+        )
+        return penalty * jnp.sum(send * d_r_out + recv * d_s_in, axis=1)
+
     if edits:
-        if per_row or with_resources:
-            raise ValueError("the edit kernel takes shared maps and no resources")
-        name = "msr_edits"
+        if per_row:
+            raise ValueError("the edit kernel takes shared maps")
+        name = "msr_edits_resources" if with_resources else "msr_edits"
     else:
         name = ("msr_resources_" if with_resources else "msr_") + (
             "per_row" if per_row else "shared"
@@ -322,14 +390,17 @@ def _msr_kernel(
 
     def kernel_resources(
         task_machine, comp, unit_ir, e_cm, met_cm, capacity,
-        net_var, mem, mem_capacity,
+        mem, mem_capacity, distance, adjacency, alpha, cir_unit, net_penalty,
     ):
         with jax.named_scope(name):
             onehot, var_w, met_w = _accumulate(
                 task_machine, comp, unit_ir, e_cm, met_cm, capacity
             )
-            var_w = var_w + net_var
-            mem_bt = mem if mem.ndim == 2 else mem[None, :]
+            var_w = var_w + _net_load(
+                onehot, comp, unit_ir, distance, adjacency, alpha, cir_unit,
+                net_penalty,
+            )
+            mem_bt = mem[comp if per_row else comp[None, :]]
             mem_w = jnp.sum(jnp.where(onehot, mem_bt[:, None, :], 0.0), axis=-1)
             mem_cap_b = (
                 mem_capacity if mem_capacity.ndim == 2 else mem_capacity[None, :]
@@ -423,9 +494,113 @@ def _msr_kernel(
             )
             return relocate, swap
 
-    fn = kernel_edits if edits else (
-        kernel_resources if with_resources else kernel
-    )
+    def kernel_edits_resources(
+        base, rows, comp, unit_ir, e_cm, met_cm, capacity,
+        mem, mem_capacity, distance, adjacency, alpha, cir_unit, net_penalty,
+    ):
+        with jax.named_scope(name):
+            onehot, var_w, met_w = _accumulate(
+                base[None, :], comp, unit_ir, e_cm, met_cm, capacity
+            )
+            var_w, met_w = var_w[0], met_w[0]
+            mem = mem[comp]                                                  # (T,)
+            mem_w = jnp.sum(jnp.where(onehot[0], mem[None, :], 0.0), axis=-1)
+            m = capacity.shape[0]
+            zero = jnp.zeros((), dtype=unit_ir.dtype)
+            if adjacency.shape[-1]:
+                send, recv, r_out, s_in, d_r_out, d_s_in = (
+                    x[0]
+                    for x in _net_masses(
+                        onehot, comp, unit_ir, distance, adjacency, alpha, cir_unit
+                    )
+                )
+                net_w = net_penalty * jnp.sum(send * d_r_out + recv * d_s_in, axis=0)
+                # Task t's send rate and receive share, and what its move
+                # changes: K (distance-weighted) and L (on its two machines).
+                o = alpha[comp] * unit_ir
+                cir_t = cir_unit[comp]
+                r = jnp.where(cir_t > 0.0, unit_ir / jnp.where(cir_t > 0.0, cir_t, 1.0), 0.0)
+                K = r[:, None] * s_in[comp] + o[:, None] * r_out[comp]      # (T, m)
+                L = o[:, None] * d_r_out[comp] + r[:, None] * d_s_in[comp]
+                Dt = distance.T                                              # [v, w] = D[w, v]
+            else:
+                net_w = jnp.zeros(m, dtype=unit_ir.dtype)
+
+            def score(dvar, dmet, dmem, dnet):
+                """Throughput of a grid of candidates, given every machine's
+                change (last axis): each total is the base's plus its change,
+                so a machine the move leaves alone keeps its sums exactly."""
+                head = capacity - (met_w + dmet)
+                den = (var_w + dvar) + (net_w + dnet)
+                infeasible = jnp.any(head < 0.0, axis=-1) | jnp.any(
+                    mem_w + dmem > mem_capacity, axis=-1
+                )
+                limits = jnp.where(den > 0.0, head / jnp.maximum(den, 1e-300), jnp.inf)
+                rates = jnp.clip(jnp.min(limits, axis=-1), 0.0, None)
+                return jnp.where(infeasible, 0.0, rates) * unit_ir.sum()
+
+            ev = e_cm[comp] * unit_ir[:, None]                               # (T, m)
+            mt = met_cm[comp]
+            ev_home = jnp.take_along_axis(ev, base[:, None], axis=1)[:, 0]
+            mt_home = jnp.take_along_axis(mt, base[:, None], axis=1)[:, 0]
+            machines = jnp.arange(m, dtype=base.dtype)
+            x = base[rows]                                                   # (A,)
+            on_x = (machines[None, :] == x[:, None])[:, None, :]             # (A, 1, m)
+            # RELOCATE a -> v, every machine v: the (A, v, w) grid.
+            on_v = jnp.eye(m, dtype=bool)[None, :, :]
+            flip = on_v.astype(ev.dtype) - on_x.astype(ev.dtype)
+            dnet = zero
+            if adjacency.shape[-1]:
+                dnet = net_penalty * (
+                    (Dt[None, :, :] - Dt[x][:, None, :]) * K[rows][:, None, :]
+                    + flip * L[rows][:, None, :]
+                )
+            relocate = score(
+                jnp.where(on_v, ev[rows][:, :, None], 0.0)
+                - jnp.where(on_x, ev_home[rows][:, None, None], 0.0),
+                jnp.where(on_v, mt[rows][:, :, None], 0.0)
+                - jnp.where(on_x, mt_home[rows][:, None, None], 0.0),
+                flip * mem[rows][:, None, None],
+                dnet,
+            )
+            # SWAP a <-> b, every task b: the (A, b, w) grid; a joins y = s_b,
+            # b joins x = s_a.
+            on_y = (machines[None, :] == base[:, None])[None, :, :]          # (1, T, m)
+            flip = on_y.astype(ev.dtype) - on_x.astype(ev.dtype)
+            dnet = zero
+            if adjacency.shape[-1]:
+                ca = comp[rows]
+                # The flow between a and b, when their components are
+                # adjacent: each task's own term counts it as colocated.
+                link = 2.0 * (
+                    o[rows][:, None] * r[None, :] * adjacency[ca][:, comp]
+                    + o[None, :] * r[rows][:, None] * adjacency.T[ca][:, comp]
+                )
+                cross = link[:, :, None] * (
+                    jnp.where(on_x, distance[x][:, base][:, :, None], 0.0)
+                    + jnp.where(on_y, Dt[x][:, base][:, :, None], 0.0)
+                )
+                dnet = net_penalty * (
+                    (Dt[base][None, :, :] - Dt[x][:, None, :])
+                    * (K[rows][:, None, :] - K[None, :, :])
+                    + flip * (L[rows][:, None, :] - L[None, :, :])
+                    + cross
+                )
+            mem_ab = (mem[rows][:, None] - mem[None, :])[:, :, None]
+            swap = score(
+                jnp.where(on_x, (ev.T[x] - ev_home[rows][:, None])[:, :, None], 0.0)
+                + jnp.where(on_y, (ev[rows][:, base] - ev_home[None, :])[:, :, None], 0.0),
+                jnp.where(on_x, (mt.T[x] - mt_home[rows][:, None])[:, :, None], 0.0)
+                + jnp.where(on_y, (mt[rows][:, base] - mt_home[None, :])[:, :, None], 0.0),
+                jnp.where(on_y, mem_ab, 0.0) - jnp.where(on_x, mem_ab, 0.0),
+                dnet,
+            )
+            return relocate, swap
+
+    if edits:
+        fn = kernel_edits_resources if with_resources else kernel_edits
+    else:
+        fn = kernel_resources if with_resources else kernel
     fn.__name__ = fn.__qualname__ = name
     return jax.jit(fn)
 
@@ -437,9 +612,7 @@ def closed_form_rates_jax(
     e_cm: np.ndarray,
     met_cm: np.ndarray,
     capacity: np.ndarray,
-    net_var: np.ndarray | None = None,
-    mem: np.ndarray | None = None,
-    mem_capacity: np.ndarray | None = None,
+    resources: list | None = None,
 ) -> tuple[np.ndarray, np.ndarray]:
     """JAX twin of ``cost_model.closed_form_rates`` (scatter-free).
 
@@ -450,12 +623,13 @@ def closed_form_rates_jax(
     ``repro.kernels.sched_scoring`` compiles for the TPU only with 32-bit
     operands, so it is not on this path.
 
-    Resource-vector extras (``net_var`` / ``mem`` / ``mem_capacity``) have
-    the ``cost_model.closed_form_rates`` semantics: the cut-traffic column
-    is added to the variable coefficient and memory is a hard feasibility
-    mask. All-``None`` (the scalar-CPU default) runs the exact legacy
-    kernels; absent resource types are filled with zeros / +inf for the
-    resource variant.
+    ``resources`` is the operand tail ``device_resources`` builds for a
+    cluster with network or memory resources (``None`` without them, which
+    runs the exact legacy kernels). It has the ``cost_model.closed_form_rates``
+    semantics: the cut-traffic load, computed on the device from the rows
+    and the distance matrix, is added to the variable coefficient, and
+    memory is a hard feasibility mask. Nothing per row is computed on the
+    host.
 
     On the active recorder the sweep is three spans — ``sweep.put`` (the
     operands' ``jax.device_put``), ``sweep.run`` (the jitted call) and
@@ -463,25 +637,68 @@ def closed_form_rates_jax(
     operands' ``nbytes`` add to the ``sweep.h2d_bytes`` counter.
 
     Refine's RELOCATE+SWAP rows are all edits of one base row; where they
-    would resolve to this function on a cluster without resources they go
-    to ``relocate_swap_scores_jax`` instead, which ships the base row
-    alone and scores the candidates as edits (``msr_edits``).
+    would resolve to this function they go to ``relocate_swap_scores_jax``
+    instead, which ships the base row alone and scores the candidates as
+    edits (``msr_edits``, ``msr_edits_resources``).
     """
     operands = [task_machine, comp, unit_ir, e_cm, met_cm, capacity]
-    with_resources = (
-        net_var is not None or mem is not None or mem_capacity is not None
+    if resources is not None:
+        operands += resources
+    kernel = _msr_kernel(
+        per_row=comp.ndim == 2, with_resources=resources is not None
     )
-    if with_resources:
-        B = task_machine.shape[0]
-        m = capacity.shape[-1]
-        if net_var is None:
-            net_var = np.zeros((B, m), dtype=np.float64)
-        if mem is None:
-            mem = np.zeros(comp.shape[-1], dtype=np.float64)
-            mem_capacity = np.full(m, np.inf, dtype=np.float64)
-        operands += [net_var, mem, mem_capacity]
-    kernel = _msr_kernel(per_row=comp.ndim == 2, with_resources=with_resources)
     return _device_sweep(kernel, operands)
+
+
+def edge_counts(n_components: int, edges) -> np.ndarray:
+    """(n, n) float matrix of edge multiplicities: ``[a, b]`` counts the
+    topology's edges a -> b (``network_unit_load`` prices each once)."""
+    adjacency = np.zeros((n_components, n_components), dtype=np.float64)
+    for a, b in edges:
+        adjacency[a, b] += 1.0
+    return adjacency
+
+
+def device_resources(
+    cluster: Cluster,
+    component_types: np.ndarray,
+    edges,
+    alpha: np.ndarray,
+    cir_unit: np.ndarray,
+) -> list | None:
+    """The resource operands of a device sweep of one topology on
+    ``cluster``, or ``None`` on a cluster without network or memory
+    resources: ``[mem, mem_capacity, distance, adjacency, alpha, cir_unit,
+    net_penalty]``, with ``mem`` the (n,) memory demand per component.
+    Without a memory model it is zeros against an unbounded capacity, and
+    without a distance matrix the network tables hold no component, so the
+    kernel prices no cut traffic."""
+    if not cluster.has_resources:
+        return None
+    if cluster.has_memory:
+        mem = cluster.profile.mem[component_types]
+        mem_capacity = cluster.mem_capacity
+    else:
+        mem = np.zeros(len(component_types), dtype=np.float64)
+        mem_capacity = np.full(cluster.n_machines, np.inf)
+    tables = network_tables(
+        cluster,
+        edge_counts(len(cir_unit), edges),
+        np.asarray(alpha, dtype=np.float64),
+        np.asarray(cir_unit, dtype=np.float64),
+    )
+    return [mem, mem_capacity, *tables]
+
+
+def network_tables(cluster: Cluster, adjacency, alpha, cir_unit) -> list:
+    """The network tail of a resource sweep: ``[distance, adjacency, alpha,
+    cir_unit, net_penalty]`` (shared (n, n) / (n,) tables, or per-row
+    (B, n, n) / (B, n) ones), or tables of no component when ``cluster``
+    has no distance matrix."""
+    if not cluster.has_network:
+        empty = np.zeros(0, dtype=np.float64)
+        return [np.zeros((0, 0)), np.zeros((0, 0)), empty, empty, np.float64(0.0)]
+    return [cluster.distance, adjacency, alpha, cir_unit, np.float64(cluster.net_penalty)]
 
 
 def _device_sweep(kernel, operands: list) -> tuple[np.ndarray, ...]:
@@ -510,9 +727,10 @@ def relocate_swap_scores_jax(
     e_cm: np.ndarray,
     met_cm: np.ndarray,
     capacity: np.ndarray,
+    resources: list | None = None,
 ) -> tuple[np.ndarray, np.ndarray]:
     """Closed-form throughput of the RELOCATE and SWAP edits of one base
-    row, on ``msr_edits``.
+    row, on ``msr_edits`` (``msr_edits_resources`` with ``resources``).
 
     ``base`` is the (T,) task->machine row and ``rows`` the (A,) tasks a
     that move. Returns the (A, m) grid whose [a, w] entry scores ``base``
@@ -520,9 +738,10 @@ def relocate_swap_scores_jax(
     it with tasks a and b trading machines. Entries that are no candidate
     (w already a's machine, a and b on one machine) hold no meaning.
     ``comp`` / ``unit_ir`` are the (T,) shared maps and ``capacity`` is
-    (m,): the edit kernel serves clusters without network or memory
-    resources. Each entry is what ``closed_form_rates_jax`` gives the
-    materialised row, up to the rounding of the two patched machine sums.
+    (m,); ``resources`` is ``device_resources``' tail, or ``None`` on a
+    cluster without resources. Each entry is what
+    ``closed_form_rates_jax`` gives the materialised row, up to the
+    rounding of the patched machine sums.
 
     Only the base row and the tables ship (indices as int32), and only the
     grids come back. Spans and the ``sweep.h2d_bytes`` counter are those of
@@ -537,7 +756,11 @@ def relocate_swap_scores_jax(
         met_cm,
         capacity,
     ]
-    return _device_sweep(_msr_kernel(edits=True), operands)
+    if resources is not None:
+        operands += resources
+    return _device_sweep(
+        _msr_kernel(edits=True, with_resources=resources is not None), operands
+    )
 
 
 def max_stable_rate_batch_jax(
@@ -581,16 +804,10 @@ def max_stable_rate_batch_jax(
     ttypes = utg.component_types
     e_cm = cluster.profile.e[ttypes][:, cluster.machine_types]
     met_cm = cluster.profile.met[ttypes][:, cluster.machine_types]
-    net_var = mem = mem_cap = None
-    if cluster.has_resources:
-        cir_unit = skew.cir_unit if skew is not None else (
-            cost_model.component_rates(utg, 1.0)
-        )
-        net_var, mem, mem_cap = cost_model.resource_operands(
-            cluster, task_machine, comp, unit_ir, utg.alpha,
-            cir_unit, utg.edges, ttypes,
-        )
+    cir_unit = skew.cir_unit if skew is not None else (
+        cost_model.component_rates(utg, 1.0)
+    )
+    resources = device_resources(cluster, ttypes, utg.edges, utg.alpha, cir_unit)
     return closed_form_rates_jax(
-        task_machine, comp, unit_ir, e_cm, met_cm, cluster.capacity,
-        net_var=net_var, mem=mem, mem_capacity=mem_cap,
+        task_machine, comp, unit_ir, e_cm, met_cm, cluster.capacity, resources
     )

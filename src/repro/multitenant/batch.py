@@ -206,11 +206,6 @@ class TenantBatchScorer:
         # *own* topology's cut traffic (cross-tenant traffic does not exist
         # — tenants are separate topologies) against the shared distance
         # matrix, and their memory against the tenant's residual memory.
-        net = (
-            np.empty((b_total, m), dtype=np.float64)
-            if self._has_network
-            else None
-        )
         mem = (
             np.zeros((b_total, self.t_max), dtype=np.float64)
             if self._has_memory
@@ -233,25 +228,13 @@ class TenantBatchScorer:
             comp[sl, :w] = self.active_comp[lo:hi]
             unit[sl, :w] = self.active_unit[lo:hi]
             cap[sl] = self._resid_cap[t]
-            if self._has_network:
-                st = self.mt.states[t]
-                net[sl] = cost_model.network_unit_load(
-                    rows_arr,
-                    self._local_comp[t],
-                    self.active_unit[lo:hi],
-                    st.utg.alpha,
-                    st.cir_unit,
-                    st.utg.edges,
-                    cluster.distance,
-                    cluster.net_penalty,
-                )
             if self._has_memory:
                 mem[sl, :w] = self._active_mem[lo:hi]
                 memcap[sl] = self._resid_mem[t]
             row0 += b_t
 
         rates, thpt = self._dispatch(
-            tm, comp, unit, cap, net_var=net, mem=mem, mem_capacity=memcap
+            sweeps, sizes, tm, comp, unit, cap, mem=mem, mem_capacity=memcap
         )
         self.candidates_evaluated += b_total
         out: list[tuple[np.ndarray, np.ndarray]] = []
@@ -273,14 +256,19 @@ class TenantBatchScorer:
 
     def _dispatch(
         self,
+        sweeps: "list[tuple[int, np.ndarray]]",
+        sizes: list[int],
         tm: np.ndarray,
         comp: np.ndarray,
         unit: np.ndarray,
         capacity: np.ndarray,
-        net_var: np.ndarray | None = None,
         mem: np.ndarray | None = None,
         mem_capacity: np.ndarray | None = None,
     ) -> tuple[np.ndarray, np.ndarray]:
+        """Score the stacked rows of ``sweeps`` (``sizes`` rows each). A
+        device sweep prices each row's cut traffic on the device from its
+        tenant's topology tables; a NumPy sweep prices it per tenant block
+        on the host."""
         from repro.core.simulator import resolve_closed_form_backend
 
         resolved = resolve_closed_form_backend(
@@ -290,19 +278,70 @@ class TenantBatchScorer:
             n_machines=capacity.shape[-1],
             site="tenant_batch",
         )
+        cluster = self.mt.cluster
         if resolved == "jax":
             from repro.core.sim_jax import closed_form_rates_jax
 
+            resources = None
+            if cluster.has_resources:
+                resources = self._device_resources(sweeps, sizes, mem_capacity)
             return closed_form_rates_jax(
-                tm, comp, unit, self.e_table, self.met_table, capacity,
-                net_var=net_var, mem=mem, mem_capacity=mem_capacity,
+                tm, comp, unit, self.e_table, self.met_table, capacity, resources
             )
+        net_var = None
+        if self._has_network:
+            net_var = np.empty((tm.shape[0], cluster.n_machines), dtype=np.float64)
+            row0 = 0
+            for (t, rows), b_t in zip(sweeps, sizes):
+                if b_t == 0:
+                    continue
+                st = self.mt.states[t]
+                lo, hi = self._task_span[t]
+                net_var[row0 : row0 + b_t] = cost_model.network_unit_load(
+                    np.asarray(rows, dtype=np.int64),
+                    self._local_comp[t],
+                    self.active_unit[lo:hi],
+                    st.utg.alpha,
+                    st.cir_unit,
+                    st.utg.edges,
+                    cluster.distance,
+                    cluster.net_penalty,
+                )
+                row0 += b_t
         e = self.e_table[comp, tm]
         met = self.met_table[comp, tm]
         return cost_model.closed_form_rates(
             tm, e, met, unit, capacity,
             net_var=net_var, mem=mem, mem_capacity=mem_capacity,
         )
+
+    def _device_resources(self, sweeps, sizes, mem_capacity) -> list:
+        """``sim_jax.device_resources``' tail for stacked tenant rows: memory
+        per global component (the padding row's 0) against the rows'
+        residual memory, and per-row tables of each row's own topology, its
+        components counted from the row's least global component."""
+        from repro.core.sim_jax import edge_counts, network_tables
+
+        states = self.mt.states
+        mem_c = np.zeros(self.e_table.shape[0], dtype=np.float64)
+        if self._has_memory:
+            for t, st in enumerate(states):
+                lo, hi = self._comp_span[t]
+                mem_c[lo:hi] = st.mem_c
+        else:
+            mem_capacity = np.full(self.mt.cluster.n_machines, np.inf)
+        n = max(st.utg.n_components for st in states)
+        tables = np.zeros((len(states), n, n + 2), dtype=np.float64)
+        for t, st in enumerate(states):
+            k = st.utg.n_components
+            tables[t, :k, :k] = edge_counts(k, st.utg.edges)
+            tables[t, :k, n] = st.utg.alpha
+            tables[t, :k, n + 1] = st.cir_unit
+        rows = tables[np.repeat([t for t, _ in sweeps], sizes)]
+        network = network_tables(
+            self.mt.cluster, rows[:, :, :n], rows[:, :, n], rows[:, :, n + 1]
+        )
+        return [mem_c, mem_capacity, *network]
 
     # ------------------------------------------------- reference (tests)
 

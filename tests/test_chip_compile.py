@@ -53,8 +53,8 @@ def _spec(sharding, shape, dtype):
 
 
 @pytest.mark.parametrize(
-    "per_row,with_resources", [(False, False), (True, True)],
-    ids=["shared", "per_row_resources"],
+    "per_row,with_resources", [(False, False), (True, True), (False, True)],
+    ids=["shared", "per_row_resources", "shared_resources"],
 )
 def test_msr_kernel_compiles_f64(one_chip, per_row, with_resources):
     """The XLA contraction that TPU sweeps run, in (emulated) float64."""
@@ -71,14 +71,23 @@ def test_msr_kernel_compiles_f64(one_chip, per_row, with_resources):
             _spec(one_chip, (M,), jnp.float64),
         ]
         if with_resources:
-            specs += [
-                _spec(one_chip, (B, M), jnp.float64),
-                _spec(one_chip, task_map, jnp.float64),
-                _spec(one_chip, (M,), jnp.float64),
-            ]
+            specs += _resource_specs(one_chip)
         kernel = _msr_kernel(per_row=per_row, with_resources=with_resources)
         compiled = kernel.lower(*specs).compile()
     assert "f64" in compiled.as_text()
+
+
+def _resource_specs(sharding):
+    """Memory per component and per machine, then the network tables."""
+    return [
+        _spec(sharding, (N_COMP,), jnp.float64),
+        _spec(sharding, (M,), jnp.float64),
+        _spec(sharding, (M, M), jnp.float64),
+        _spec(sharding, (N_COMP, N_COMP), jnp.float64),
+        _spec(sharding, (N_COMP,), jnp.float64),
+        _spec(sharding, (N_COMP,), jnp.float64),
+        _spec(sharding, (), jnp.float64),
+    ]
 
 
 def test_edit_kernel_compiles_f64(one_chip):
@@ -87,16 +96,31 @@ def test_edit_kernel_compiles_f64(one_chip):
     from repro.core.sim_jax import _msr_kernel
 
     with jax.enable_x64(True):
-        compiled = _msr_kernel(edits=True).lower(
-            _spec(one_chip, (T,), jnp.int32),
-            _spec(one_chip, (T,), jnp.int32),
-            _spec(one_chip, (T,), jnp.int32),
-            _spec(one_chip, (T,), jnp.float64),
-            _spec(one_chip, (N_COMP, M), jnp.float64),
-            _spec(one_chip, (N_COMP, M), jnp.float64),
-            _spec(one_chip, (M,), jnp.float64),
-        ).compile()
+        compiled = _msr_kernel(edits=True).lower(*_edit_specs(one_chip)).compile()
     assert "f64" in compiled.as_text()
+
+
+def test_resource_edit_kernel_compiles_f64(one_chip):
+    """``msr_edits_resources``: the same grids on a cluster with network and
+    memory resources, every candidate scored on every machine."""
+    from repro.core.sim_jax import _msr_kernel
+
+    with jax.enable_x64(True):
+        specs = _edit_specs(one_chip) + _resource_specs(one_chip)
+        compiled = _msr_kernel(edits=True, with_resources=True).lower(*specs).compile()
+    assert "f64" in compiled.as_text()
+
+
+def _edit_specs(sharding):
+    return [
+        _spec(sharding, (T,), jnp.int32),
+        _spec(sharding, (T,), jnp.int32),
+        _spec(sharding, (T,), jnp.int32),
+        _spec(sharding, (T,), jnp.float64),
+        _spec(sharding, (N_COMP, M), jnp.float64),
+        _spec(sharding, (N_COMP, M), jnp.float64),
+        _spec(sharding, (M,), jnp.float64),
+    ]
 
 
 @pytest.mark.parametrize("with_resources", [False, True],

@@ -2,9 +2,9 @@
 
 ``sim_jax``'s ``msr_edits`` kernel against ``msr_shared`` on the same
 candidates materialised as rows, and refine's routing of its relocate+swap
-sweep through ``ScheduleState.score_relocate_swap``: device sweeps of a
-cluster without resources take the edit path and count it in
-``sweep.edit_rows``; NumPy sweeps and clusters with resources keep rows.
+sweep through ``ScheduleState.score_relocate_swap``: device sweeps take the
+edit path and count it in ``sweep.edit_rows``; NumPy sweeps keep rows.
+Clusters with resources: ``tests/test_net_edit_scoring.py``.
 """
 
 import numpy as np
@@ -237,15 +237,22 @@ def test_numpy_sweeps_keep_rows(monkeypatch, browned_out):
 
 
 def test_network_clusters_keep_rows_on_the_device(monkeypatch, browned_out):
-    from repro.core import rack_distance_matrix
+    """Device sweeps of a network cluster score relocate+swap as edits too,
+    on ``msr_edits_resources``: no row is built, and every candidate's cut
+    traffic is computed on the device."""
+    from repro.core import rack_distance_matrix, schedule_state
 
     etg, cluster = browned_out
     racks = np.arange(cluster.n_machines) % 2
     net = cluster.with_resources(distance=rack_distance_matrix(racks), net_penalty=0.05)
     seen = _relocate_swap_rows(monkeypatch)
+    monkeypatch.setattr(
+        schedule_state, "_edited_rows", lambda *a: pytest.fail("rows built")
+    )
     rec = TraceRecorder()
     res = refine(etg, net, max_rounds=2, backend="jax", recorder=rec)
     counters = _counters(rec)
-    assert seen and "sweep.edit_rows" not in counters
-    assert counters["refine.rows"] == res.candidates
-    assert not [d for d in rec.dispatch_log if d.site == "score_relocate_swap"]
+    assert seen and counters["sweep.edit_rows"] == sum(seen)
+    assert counters["refine.rows"] == counters["sweep.net_rows"] == res.candidates
+    edit_sweeps = [d for d in rec.dispatch_log if d.site == "score_relocate_swap"]
+    assert len(edit_sweeps) == len(seen)

@@ -138,14 +138,18 @@ def test_h2d_bytes_equal_the_operands_of_a_jax_sweep(variant):
         comp, unit_ir = np.tile(comp, (B, 1)), np.tile(unit_ir, (B, 1))
     e_cm, met_cm, cap = rng.random((n, m)), rng.random((n, m)), 10 + rng.random(m)
     operands = [tm, comp, unit_ir, e_cm, met_cm, cap]
-    extras = {}
+    resources = None
     if variant == "resources":
-        extras = {"net_var": rng.random((B, m))}
-        # Absent memory is shipped as zeros per task and +inf per machine.
-        operands += [extras["net_var"], np.zeros(T), np.full(m, np.inf)]
+        # Absent memory is shipped as zeros per component and +inf per
+        # machine; the network as its distance matrix and topology tables.
+        resources = [
+            np.zeros(n), np.full(m, np.inf), rng.random((m, m)),
+            np.eye(n, k=1), rng.random(n), 1 + rng.random(n), np.float64(0.5),
+        ]
+        operands += resources
     rec = TraceRecorder()
     with rec.activate():
-        closed_form_rates_jax(tm, comp, unit_ir, e_cm, met_cm, cap, **extras)
+        closed_form_rates_jax(tm, comp, unit_ir, e_cm, met_cm, cap, resources)
     counters = {m_["name"]: m_["value"] for m_ in rec.metrics.snapshot()}
     assert counters["sweep.h2d_bytes"] == sum(x.nbytes for x in operands)
     assert [r["name"] for r in rec.records] == ["sweep.put", "sweep.run", "sweep.fetch"]
@@ -227,7 +231,10 @@ def test_scoring_kernels_carry_stable_names(per_row, with_resources, name):
     unit_ir = np.ones((B, T) if per_row else T)
     args = [tm, comp, unit_ir, np.ones((n, m)), np.ones((n, m)), np.ones(m)]
     if with_resources:
-        args += [np.zeros((B, m)), np.zeros(T), np.full(m, np.inf)]
+        args += [
+            np.zeros(n), np.full(m, np.inf), np.ones((m, m)), np.eye(n, k=1),
+            np.ones(n), np.ones(n), np.float64(1.0),
+        ]
     with jax.enable_x64(True):
         text = _msr_kernel(per_row, with_resources).lower(*args).as_text(
             debug_info=True
