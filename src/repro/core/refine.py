@@ -24,9 +24,10 @@ Engines
 ``engine="state"`` (default) runs the climb on the incremental
 ``ScheduleState`` engine: moves are O(m) count-matrix deltas (no
 ``ExecutionGraph`` copies), and each round's candidate set is scored
-through vectorized ``max_stable_rate_batch`` calls — candidate placements
-are exported as (B, T) task->machine matrices, greedy growth chains across
-all components/pairs advance in depth-lockstep per-row-count sweeps (4 per
+through vectorized sweeps — candidate placements are edits of the exported
+task->machine row (materialised as (B, T) matrices where NumPy scores them;
+scored as edits of their base rows on the device), greedy growth chains
+across all components/pairs advance in depth-lockstep sweeps (4 per
 round), and every NumPy-scored candidate's score is bit-identical to the
 reference path's scalar ``max_stable_rate``, so the two engines provably
 choose the same moves. The default ``backend="auto"`` preserves that
@@ -374,6 +375,11 @@ class _GrowChain:
         return child
 
 
+def _with_task(row: np.ndarray, pos: int, machine: int) -> np.ndarray:
+    """``row`` with one more task, on ``machine``, at column ``pos``."""
+    return np.concatenate((row[:pos], [machine], row[pos:]))
+
+
 def _grow_step(
     state: ScheduleState, c: int, backend: str, cur: _GrowCursor
 ) -> tuple[float, int]:
@@ -383,24 +389,14 @@ def _grow_step(
     Matches the reference ``greedy_grow`` inner loop exactly: strict-``>``
     first-max over machines in index order is ``np.argmax`` on the batch.
     """
-    m = state.cluster.n_machines
-    row, offsets = cur.row, cur.offsets
-    pos = int(offsets[c + 1])  # append at end of c's block
-    T = row.shape[0]
-    with trace.span("refine.build", "refine"):
-        tm = np.empty((m, T + 1), dtype=np.int64)
-        tm[:, :pos] = row[:pos]
-        tm[:, pos] = np.arange(m)
-        tm[:, pos + 1 :] = row[pos:]
-        n_new = state.n_instances.copy()
-        n_new[c] += 1
-    _, scores = state.score_task_machine_batch(tm, n_new, backend=backend)
+    scores = state.score_grow_steps(cur.row, state.n_instances, c, backend)
     w = int(np.argmax(scores))
     state.add_instance(c, w)
-    cur.row = tm[w]
-    new_off = offsets.copy()
-    new_off[c + 1 :] += 1
-    cur.offsets = new_off
+    with trace.span("refine.build", "refine"):
+        cur.row = _with_task(cur.row, int(cur.offsets[c + 1]), w)
+        new_off = cur.offsets.copy()
+        new_off[c + 1 :] += 1
+        cur.offsets = new_off
     return float(scores[w]), w
 
 
@@ -411,51 +407,35 @@ def _lockstep_extend(
     backend: str,
 ) -> None:
     """One lockstep depth: score every live chain's next greedy step in a
-    single per-row-count sweep and apply each chain's winner.
+    single sweep and apply each chain's winner.
 
-    Chain i appends one instance of ``comps[i]``; its m candidate rows are
-    column inserts on its own row, and the whole depth scores as one
-    ``score_task_machine_batch`` call with a (B, n) count matrix (B =
-    len(chains) * m). Rows are scored independently and each chain's winner
-    is the strict first-max over its own contiguous m rows in machine
-    order, so scores and winners are bit-identical to stepping the chains
-    one ``_grow_step`` sweep at a time.
+    Chain i appends one instance of ``comps[i]`` at the end of its block;
+    its m candidates (one per machine) score as one
+    ``ScheduleState.score_grow_steps`` sweep of every chain, which stands
+    for len(chains) * m rows with per-row counts. Each chain's winner is
+    the strict first-max over its own m candidates in machine order, so
+    scores and winners are those of stepping the chains one ``_grow_step``
+    sweep at a time; only the winning rows are built.
     """
     if not chains:
         return
-    m = state.cluster.n_machines
-    T = int(chains[0].row.shape[0])
-    k = len(chains)
+    scores = state.score_grow_steps(
+        np.stack([ch.row for ch in chains]),
+        np.stack([ch.n_inst for ch in chains]),
+        np.asarray(comps, dtype=np.int64),
+        backend,
+    )
+    winners = scores.argmax(axis=1)
     with trace.span("refine.build", "refine"):
-        comps_arr = np.asarray(comps, dtype=np.int64)
-        base = np.stack([ch.row for ch in chains])           # (k, T)
-        pos = np.array(
-            [int(ch.offsets[c + 1]) for ch, c in zip(chains, comps)],
-            dtype=np.int64,
-        )  # append at end of each chain's grown block
-        counts = np.stack([ch.n_inst for ch in chains])      # (k, n)
-        counts[np.arange(k), comps_arr] += 1
-        # Insert one column at pos[i]: source column j-1 right of the
-        # insert, j left of it; the insert column itself is overwritten with
-        # the machine index, so its clipped source value is irrelevant.
-        cols = np.arange(T + 1)
-        src = np.clip(
-            cols[None, :] - (cols[None, :] > pos[:, None]), 0, max(T - 1, 0)
-        )
-        tm = np.repeat(np.take_along_axis(base, src, axis=1), m, axis=0)
-        tm[np.arange(k * m), np.repeat(pos, m)] = np.tile(np.arange(m), k)
-        n_rows = np.repeat(counts, m, axis=0)
-    _, scores = state.score_task_machine_batch(tm, n_rows, backend=backend)
-    winners = scores.reshape(k, m).argmax(axis=1)
-    for i, (ch, c) in enumerate(zip(chains, comps)):
-        w = int(winners[i])
-        ch.row = tm[i * m + w]
-        new_off = ch.offsets.copy()
-        new_off[c + 1 :] += 1
-        ch.offsets = new_off
-        ch.n_inst[c] += 1
-        ch.placements.append((c, w))
-        ch.scores.append(float(scores[i * m + w]))
+        for i, (ch, c) in enumerate(zip(chains, comps)):
+            w = int(winners[i])
+            ch.row = _with_task(ch.row, int(ch.offsets[c + 1]), w)
+            new_off = ch.offsets.copy()
+            new_off[c + 1 :] += 1
+            ch.offsets = new_off
+            ch.n_inst[c] += 1
+            ch.placements.append((c, w))
+            ch.scores.append(float(scores[i, w]))
 
 
 def _adaptive_live(chains: list[tuple[_GrowChain, int]]) -> list[tuple[_GrowChain, int]]:
@@ -648,11 +628,12 @@ def _refine_state(
     (T,) task->machine row exported from ``ScheduleState`` and scored in
     vectorized ``max_stable_rate_batch`` sweeps — one sweep covers all
     RELOCATE+SWAP candidates (``ScheduleState.score_relocate_swap``), four
-    depth-lockstep per-row-count sweeps cover every growth chain
-    (ADD/GROW/PAIRGROW), and one more covers all DROP candidates: ~6 sweeps
-    per round. NumPy-scored candidates are bit-identical to the reference
-    engine's scalar scoring (same ``max_stable_rate_batch`` row
-    computation); device sweeps agree to ~1e-15. Winners are selected
+    depth-lockstep sweeps cover every growth chain (ADD/GROW/PAIRGROW;
+    ``score_grow_steps``), and one more covers all DROP candidates
+    (``score_drops``): ~6 sweeps per round. NumPy-scored candidates are
+    bit-identical to the reference engine's scalar scoring (same
+    ``max_stable_rate_batch`` row computation); device sweeps agree to
+    ~1e-15. Winners are selected
     with the same strict-``>`` first-max semantics in the same enumeration
     order, so both engines apply the same move sequence. Applying a move is
     an O(m) ``ScheduleState`` delta; growth exploration carries candidate
@@ -688,7 +669,6 @@ def _refine_state(
 
             base_tm = state.task_machine()
             offsets = state.component_offsets()
-            T = int(base_tm.shape[0])
             # Copy: growth exploration below mutates state.n_instances in place
             # before snapshot/restore swaps in a fresh array.
             n_inst = state.n_instances.copy()
@@ -798,43 +778,19 @@ def _refine_state(
                                 )
                 # DROP: which instance to delete, over every component with
                 # >= 2 instances — column removals on the base row, all scored
-                # in one per-row-count sweep (winner still picked per component
-                # to preserve the reference offer order).
-                drop_rows: list[np.ndarray] = []
-                drop_counts: list[np.ndarray] = []
-                drop_span: list[tuple[int, int]] = []
-                with trace.span("refine.build", "refine"):
-                    for c in range(n):
-                        nk = int(n_inst[c])
-                        if nk < 2:
-                            continue
-                        cols = np.arange(T - 1)
-                        idx = cols[None, :] + (
-                            cols[None, :]
-                            >= (int(offsets[c]) + np.arange(nk))[:, None]
-                        )
-                        n_new = n_inst.copy()
-                        n_new[c] -= 1
-                        drop_rows.append(base_tm[idx])
-                        drop_counts.append(np.tile(n_new, (nk, 1)))
-                        drop_span.append((c, nk))
-                    if drop_span:
-                        drop_tm = np.concatenate(drop_rows, axis=0)
-                        drop_n = np.concatenate(drop_counts, axis=0)
-                if drop_span:
-                    _, sd_all = state.score_task_machine_batch(
-                        drop_tm, drop_n, backend=backend
+                # in one sweep (winner still picked per component to preserve
+                # the reference offer order).
+                drops = state.score_drops(base_tm, n_inst, backend)
+                for c in range(n):
+                    if int(n_inst[c]) < 2:
+                        continue
+                    sd = drops[offsets[c] : offsets[c + 1]]
+                    k = int(np.argmax(sd))
+                    offer(
+                        float(sd[k]),
+                        f"drop c{c}#{k}",
+                        lambda c=c, k=k: state.drop_instance(c, k),
                     )
-                    start = 0
-                    for c, nk in drop_span:
-                        sd = sd_all[start : start + nk]
-                        start += nk
-                        k = int(np.argmax(sd))
-                        offer(
-                            float(sd[k]),
-                            f"drop c{c}#{k}",
-                            lambda c=c, k=k: state.drop_instance(c, k),
-                        )
 
             if best_move is None:
                 if sp is not None:

@@ -51,6 +51,7 @@ default); ``engine="reference"`` runs the original path. Golden tests in
 
 from __future__ import annotations
 
+import dataclasses
 from fractions import Fraction
 
 import numpy as np
@@ -91,6 +92,67 @@ def _edited_rows(base: np.ndarray, edits: np.ndarray) -> np.ndarray:
     tm[rows, edits[0]] = edits[1]
     tm[rows, edits[2]] = edits[3]
     return tm
+
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class _CountEdits:
+    """Candidate rows given as count edits of k base rows, which
+    ``ScheduleState.score_task_machine_batch`` scores without building
+    them where it can: row i of ``rows`` (k, T) holds ``counts[i]`` (n,)
+    instances per component, and its candidates change the count of
+    ``comps[i]`` by one. A growth step appends one more instance at the end
+    of the component's block, on each of ``n_machines`` machines (k·m rows
+    of T + 1 tasks, chain by chain); a ``drop`` removes each of its
+    instances in turn (rows of T - 1 tasks). ``shared``: a single chain,
+    whose m rows resolve as one shared count vector."""
+
+    rows: np.ndarray
+    counts: np.ndarray
+    comps: np.ndarray
+    n_machines: int
+    drop: bool = False
+    shared: bool = False
+
+    def __post_init__(self):
+        for name in ("rows", "counts", "comps"):
+            object.__setattr__(self, name, np.asarray(getattr(self, name), dtype=np.int64))
+
+    @property
+    def new_counts(self) -> np.ndarray:
+        """(k, n) instance counts of each row's candidates."""
+        new = self.counts.copy()
+        new[np.arange(self.comps.size), self.comps] += -1 if self.drop else 1
+        return new
+
+    @property
+    def shape(self) -> tuple[int, int]:
+        """(B, T') of the candidate rows."""
+        k, n_tasks = self.rows.shape
+        if self.drop:
+            return int(self.counts[np.arange(k), self.comps].sum()), n_tasks - 1
+        return k * self.n_machines, n_tasks + 1
+
+    def materialise(self) -> tuple[np.ndarray, np.ndarray]:
+        """The (B, T') candidate rows, in order, and their counts: (B, n),
+        or the one (n,) vector where ``shared``."""
+        k, n_tasks = self.rows.shape
+        m, new = self.n_machines, self.new_counts
+        ends = np.cumsum(self.counts, axis=1)[np.arange(k), self.comps]
+        if self.drop:
+            sizes = self.counts[np.arange(k), self.comps]
+            task = np.concatenate([np.arange(e - s, e) for e, s in zip(ends, sizes)])
+            row = np.repeat(np.arange(k), sizes)
+            cols = np.arange(n_tasks - 1)
+            tm = self.rows[row[:, None], cols[None, :] + (cols[None, :] >= task[:, None])]
+            return tm, np.repeat(new, sizes, axis=0)
+        # Insert one column at the end of each row's grown block: source
+        # column j-1 right of it, j left of it; the insert column itself is
+        # overwritten with the machine index.
+        cols = np.arange(n_tasks + 1)
+        src = np.clip(cols[None, :] - (cols[None, :] > ends[:, None]), 0, max(n_tasks - 1, 0))
+        tm = np.repeat(np.take_along_axis(self.rows, src, axis=1), m, axis=0)
+        tm[np.arange(k * m), np.repeat(ends, m)] = np.tile(np.arange(m), k)
+        return tm, new[0] if self.shared else np.repeat(new, m, axis=0)
 
 
 class ScheduleState:
@@ -557,6 +619,11 @@ class ScheduleState:
             machine-count gated on CPU — skew rows dispatch under the
             ``"skew"`` regime; the jitted kernel is skew-agnostic).
 
+        ``task_machine`` may also be growth steps or drops given as count
+        edits of base rows (``score_grow_steps``, ``score_drops``; ``n_instances``
+        then unused), which resolve as the rows they stand for and, on a
+        device sweep without a skew model, score without being built.
+
         Each call is one ``refine.sweep`` span on the active recorder and
         adds its B rows to ``rows_scored`` and the ``refine.rows`` counter.
         """
@@ -641,6 +708,118 @@ class ScheduleState:
             trace.count("refine.rows", rows)
         return edits, thpt
 
+    def score_grow_steps(
+        self,
+        rows: np.ndarray,
+        counts: np.ndarray,
+        comps: np.ndarray,
+        backend: str,
+    ) -> np.ndarray:
+        """Closed-form throughput of the next greedy step of k growth
+        chains, on every machine.
+
+        Row i of ``rows`` (k, T) holds ``counts[i]`` (n,) instances per
+        component; candidate [i, v] appends one more instance of
+        ``comps[i]`` at the end of its block, on machine v, and every
+        instance of that component takes the new even split. Returns the
+        (k, m) grid. A single chain — a (T,) row, (n,) counts and one
+        component — returns (m,) and resolves as m rows with shared counts.
+
+        One ``score_task_machine_batch`` call of the k·m rows, given as
+        count edits of the base rows: a device sweep without a skew model
+        ships only the base rows and the tables (``msr_count_edits``).
+        """
+        single = np.ndim(rows) == 1
+        m = self.cluster.n_machines
+        edits = _CountEdits(
+            np.atleast_2d(rows), np.atleast_2d(counts), np.atleast_1d(comps), m,
+            shared=single,
+        )
+        thpt = self.score_task_machine_batch(edits, backend=backend)[1].reshape(-1, m)
+        return thpt[0] if single else thpt
+
+    def score_drops(
+        self, base: np.ndarray, counts: np.ndarray, backend: str
+    ) -> np.ndarray:
+        """Closed-form throughput of the (T,) ``base`` row under ``counts``
+        without task p, for every task p of a component with at least two
+        instances (the others, which are no candidate, hold NaN); the
+        component's remaining instances take the new even split.
+
+        One ``score_task_machine_batch`` call of the candidate rows with
+        per-row counts, given as count edits of the base row (once per
+        droppable component), as ``score_grow_steps`` does; none when no
+        component can drop an instance.
+        """
+        counts = np.asarray(counts, dtype=np.int64)
+        thpt = np.full(np.shape(base)[0], np.nan)
+        comps = np.flatnonzero(counts >= 2)
+        if comps.size:
+            k = comps.size
+            edits = _CountEdits(
+                np.tile(base, (k, 1)), np.tile(counts, (k, 1)), comps,
+                self.cluster.n_machines, drop=True,
+            )
+            offsets = np.concatenate([[0], np.cumsum(counts)])
+            tasks = np.concatenate([np.arange(offsets[c], offsets[c + 1]) for c in comps])
+            thpt[tasks] = self.score_task_machine_batch(edits, backend=backend)[1]
+        return thpt
+
+    def _score_count_edits(
+        self, edits: "_CountEdits", backend: str
+    ) -> tuple[np.ndarray, np.ndarray]:
+        """Closed form of the rows ``edits`` stands for, resolved as those
+        rows. A device sweep without a skew model scores them as count edits
+        on ``sim_jax``'s ``msr_count_edits`` (``msr_count_edits_resources``
+        on a cluster with network or memory resources, which also bumps
+        ``sweep.net_rows``) and bumps ``sweep.edit_rows`` by its candidates.
+        Otherwise — a NumPy sweep, or a skew model, whose per-instance key
+        shares do not rescale evenly — the rows are built and scored as
+        such, NumPy scores bit-identical to materialised rows'."""
+        n_rows, n_tasks = edits.shape
+        regime = "shared" if edits.shared else "per_row"
+        resolved = self._resolve_rows(backend, n_rows, n_tasks, regime)
+        if resolved != "jax" or self.skew is not None:
+            with trace.span("refine.build", "refine"):
+                tm, n_inst = edits.materialise()
+            comp, unit_ir, _ = self._task_maps(n_inst, *tm.shape)
+            return self._score_rows(tm, comp, unit_ir, resolved)
+        from repro.core.sim_jax import count_edit_scores_jax
+
+        resources = self._device_resources()
+        trace.count("sweep.edit_rows", n_rows)
+        if resources is not None:
+            trace.count("sweep.net_rows", n_rows)
+        rates, thpt = count_edit_scores_jax(
+            edits.rows, edits.counts, self.cir_unit[None, :] / edits.new_counts,
+            edits.comps, self.e_cm, self.met_cm, self.cluster.capacity,
+            resources, drop=edits.drop,
+        )
+        if not edits.drop:
+            return rates.reshape(-1), thpt.reshape(-1)
+        # Row i's candidates: the tasks of its component, in task order.
+        k = edits.rows.shape[0]
+        ends = np.cumsum(edits.counts, axis=1)[np.arange(k), edits.comps]
+        sizes = edits.counts[np.arange(k), edits.comps]
+        row = np.repeat(np.arange(k), sizes)
+        task = np.concatenate([np.arange(e - s, e) for e, s in zip(ends, sizes)])
+        return rates[row, task], thpt[row, task]
+
+    def _resolve_rows(
+        self, backend: str, rows: int, n_tasks: int, regime: str
+    ) -> str:
+        """The backend of a sweep of ``rows`` candidate rows of ``n_tasks``
+        tasks, under the skew regime where the state has a skew model."""
+        from repro.core.simulator import resolve_closed_form_backend
+
+        return resolve_closed_form_backend(
+            backend,
+            rows * n_tasks,
+            regime="skew" if self.skew is not None else regime,
+            n_machines=self.cluster.n_machines,
+            site="score_task_machine_batch",
+        )
+
     def _score_moves(
         self,
         base: np.ndarray,
@@ -690,10 +869,12 @@ class ScheduleState:
 
     def _score_batch(
         self,
-        task_machine: np.ndarray,
+        task_machine: "np.ndarray | _CountEdits",
         n_instances: np.ndarray | None,
         backend: str,
     ) -> tuple[np.ndarray, np.ndarray]:
+        if isinstance(task_machine, _CountEdits):
+            return self._score_count_edits(task_machine, backend)
         n_inst = self.n_instances if n_instances is None else np.asarray(
             n_instances, dtype=np.int64
         )
@@ -701,15 +882,7 @@ class ScheduleState:
         if task_machine.ndim != 2:
             raise ValueError("task_machine must be (B, sum(n_instances))")
         comp, unit_ir, regime = self._task_maps(n_inst, *task_machine.shape)
-        from repro.core.simulator import resolve_closed_form_backend
-
-        resolved = resolve_closed_form_backend(
-            backend,
-            task_machine.size,
-            regime=regime,
-            n_machines=self.cluster.n_machines,
-            site="score_task_machine_batch",
-        )
+        resolved = self._resolve_rows(backend, *task_machine.shape, regime)
         return self._score_rows(task_machine, comp, unit_ir, resolved)
 
     def _task_maps(

@@ -32,6 +32,7 @@ __all__ = [
     "max_stable_rate_batch_jax",
     "closed_form_rates_jax",
     "relocate_swap_scores_jax",
+    "count_edit_scores_jax",
     "device_resources",
     "network_tables",
 ]
@@ -213,9 +214,12 @@ def simulate_batch_jax(
 # ----------------------------------------------------- closed-form scoring
 
 
-@functools.lru_cache(maxsize=8)
+@functools.lru_cache(maxsize=None)
 def _msr_kernel(
-    per_row: bool = False, with_resources: bool = False, edits: bool = False
+    per_row: bool = False,
+    with_resources: bool = False,
+    edits: bool = False,
+    count_edits: bool = False,
 ):
     """Jitted closed-form max-stable-rate scorer (paper eq. 5 linearity).
 
@@ -290,11 +294,30 @@ def _msr_kernel(
     terms each count as colocated. Memory changes on the two touched
     machines only.
 
+    ``count_edits=True`` selects ``msr_count_edits``, the count-edit
+    path: refine's growth steps (ADD / GROW / PAIRGROW) and DROP
+    candidates, which change one component's instance count by one. It
+    takes k (k, T) base rows, their (k, n) instance counts, the (k, n)
+    per-component unit rates of their candidates and the (k,) component
+    each row's candidates grow or shrink. It computes each row's totals
+    rescaled to the candidates' counts with the one-hot contraction, laid
+    out (k·m, T) (a fresh sum at the new rates, not a correction of the old
+    one), then patches
+    the one machine a candidate touches, as ``msr_edits`` does: with the
+    static ``drop=False`` a (k, m) grid, [i, v] one more task of the
+    component on machine v; with ``drop=True`` a (k, T) grid, [i, p] row i
+    without task p. ``count_edits=True, with_resources=True`` selects
+    ``msr_count_edits_resources``: every candidate is scored on all m
+    machines, a (k, m, m) or (k, T, m) grid, with the "+v" or "-u" half of
+    ``msr_edits_resources``' cut-traffic term at the rescaled rates. Both
+    return the rate and the throughput of each cell.
+
     Each variant is named by what it computes — ``msr_shared``,
     ``msr_per_row``, ``msr_resources_shared``, ``msr_resources_per_row``,
-    ``msr_edits``, ``msr_edits_resources`` — as its jitted function (the
-    module ``jit_<name>`` in a profiler trace) and as a
-    ``jax.named_scope`` around its body.
+    ``msr_edits``, ``msr_edits_resources``, ``msr_count_edits``,
+    ``msr_count_edits_resources`` — as its jitted function (the module
+    ``jit_<name>`` in a profiler trace) and as a ``jax.named_scope`` around
+    its body.
     """
     import jax
     import jax.numpy as jnp
@@ -315,25 +338,26 @@ def _msr_kernel(
         met_w = jnp.sum(jnp.where(onehot, met[:, None, :], 0.0), axis=-1)
         return onehot, var_w, met_w
 
+    def _limits(head, var):
+        """Each machine's rate limit: head room over the variable load."""
+        return jnp.where(var > 0.0, head / jnp.maximum(var, 1e-300), jnp.inf)
+
     def _finish(var_w, met_w, capacity, unit_ir, infeasible_extra=None):
         cap_b = capacity if capacity.ndim == 2 else capacity[None, :]
         head = cap_b - met_w
         infeasible = jnp.any(head < 0.0, axis=1)
         if infeasible_extra is not None:
             infeasible = infeasible | infeasible_extra
-        limits = jnp.where(var_w > 0.0, head / jnp.maximum(var_w, 1e-300), jnp.inf)
+        limits = _limits(head, var_w)
         rates = jnp.clip(jnp.min(limits, axis=1), 0.0, None)
         rates = jnp.where(infeasible, 0.0, rates)
         thpt = rates * (unit_ir.sum(axis=1) if per_row else unit_ir.sum())
         return rates, thpt
 
     def _net_masses(onehot, comp, unit_ir, distance, adjacency, alpha, cir_unit):
-        """Cut-traffic masses at unit rate, each (B, n, m): what the
-        instances of component c on machine w send (``send``) and what
-        share of c's input they receive (``recv``), the neighbour masses
-        ``r_out`` (c's children's ``recv``) and ``s_in`` (c's parents'
-        ``send``), and their distance products ``(D x)[w] = sum_v D[w, v]
-        x[v]``. Components count from the row's least ``comp``, so rows of
+        """``_net_terms`` of rows given by their (B, m, T) one-hot: each
+        component's unit-rate mass per machine, by the same contraction.
+        Components count from the row's least ``comp``, so rows of
         different topologies index their own (B, n, n) / (B, n) tables."""
         cmap = comp if comp.ndim == 2 else comp[None, :]
         u = unit_ir if unit_ir.ndim == 2 else unit_ir[None, :]
@@ -349,6 +373,15 @@ def _msr_kernel(
             ],
             axis=1,
         )
+        return _net_terms(mass, distance, adjacency, alpha, cir_unit)
+
+    def _net_terms(mass, distance, adjacency, alpha, cir_unit):
+        """Cut-traffic terms at unit rate of (B, n, m) masses, each (B, n,
+        m): what the instances of component c on machine w send (``send``)
+        and what share of c's input they receive (``recv``), the neighbour
+        masses ``r_out`` (c's children's ``recv``) and ``s_in`` (c's
+        parents' ``send``), and their distance products ``(D x)[w] =
+        sum_v D[w, v] x[v]``."""
         adj = adjacency if adjacency.ndim == 3 else adjacency[None]
         al = (alpha if alpha.ndim == 2 else alpha[None])[:, :, None]
         cir = (cir_unit if cir_unit.ndim == 2 else cir_unit[None])[:, :, None]
@@ -358,7 +391,11 @@ def _msr_kernel(
         s_in = jnp.sum(adj[:, :, :, None] * send[:, :, None, :], axis=1)
 
         def dist(x):
-            return jnp.sum(x[:, :, None, :] * distance[None, None, :, :], axis=-1)
+            # Laid out (B·n, m, m): over the 4-D (B, n, m, m) broadcast the
+            # TPU compiler takes about ten times longer.
+            b, n, m = x.shape
+            rows = x.reshape(b * n, 1, m) * distance[None]
+            return jnp.sum(rows, axis=-1).reshape(b, n, m)
 
         return send, recv, r_out, s_in, dist(r_out), dist(s_in)
 
@@ -372,10 +409,12 @@ def _msr_kernel(
         )
         return penalty * jnp.sum(send * d_r_out + recv * d_s_in, axis=1)
 
-    if edits:
-        if per_row:
-            raise ValueError("the edit kernel takes shared maps")
-        name = "msr_edits_resources" if with_resources else "msr_edits"
+    if edits or count_edits:
+        if per_row or (edits and count_edits):
+            raise ValueError("an edit kernel takes its own maps")
+        name = ("msr_count_edits" if count_edits else "msr_edits") + (
+            "_resources" if with_resources else ""
+        )
     else:
         name = ("msr_resources_" if with_resources else "msr_") + (
             "per_row" if per_row else "shared"
@@ -387,6 +426,20 @@ def _msr_kernel(
                 task_machine, comp, unit_ir, e_cm, met_cm, capacity
             )
             return _finish(var_w, met_w, capacity, unit_ir)
+
+    def _patched_rates(
+        var_w, met_w, mem_w, net_w, capacity, mem_capacity, dvar, dmet, dmem, dnet
+    ):
+        """Rates of a grid of candidates given every machine's change to the
+        base's totals (last axis): each total is the base's plus its change,
+        so a machine the candidate leaves alone keeps its sums exactly."""
+        head = capacity - (met_w + dmet)
+        den = (var_w + dvar) + (net_w + dnet)
+        infeasible = jnp.any(head < 0.0, axis=-1) | jnp.any(
+            mem_w + dmem > mem_capacity, axis=-1
+        )
+        rates = jnp.clip(jnp.min(_limits(head, den), axis=-1), 0.0, None)
+        return jnp.where(infeasible, 0.0, rates)
 
     def kernel_resources(
         task_machine, comp, unit_ir, e_cm, met_cm, capacity,
@@ -427,11 +480,7 @@ def _msr_kernel(
             # least limit among the others is that of the first of the three
             # best that it leaves alone.
             head = cap_m - met_m
-            key = jnp.where(
-                head < 0.0,
-                -jnp.inf,
-                jnp.where(var_m > 0.0, head / jnp.maximum(var_m, 1e-300), jnp.inf),
-            )
+            key = jnp.where(head < 0.0, -jnp.inf, _limits(head, var_m))
             idx = jnp.arange(key.shape[0], dtype=base.dtype)
             before = (key[:, None] < key[None, :]) | (
                 (key[:, None] == key[None, :]) & (idx[:, None] < idx[None, :])
@@ -526,18 +575,9 @@ def _msr_kernel(
             else:
                 net_w = jnp.zeros(m, dtype=unit_ir.dtype)
 
-            def score(dvar, dmet, dmem, dnet):
-                """Throughput of a grid of candidates, given every machine's
-                change (last axis): each total is the base's plus its change,
-                so a machine the move leaves alone keeps its sums exactly."""
-                head = capacity - (met_w + dmet)
-                den = (var_w + dvar) + (net_w + dnet)
-                infeasible = jnp.any(head < 0.0, axis=-1) | jnp.any(
-                    mem_w + dmem > mem_capacity, axis=-1
-                )
-                limits = jnp.where(den > 0.0, head / jnp.maximum(den, 1e-300), jnp.inf)
-                rates = jnp.clip(jnp.min(limits, axis=-1), 0.0, None)
-                return jnp.where(infeasible, 0.0, rates) * unit_ir.sum()
+            def score(*changes):
+                totals = (var_w, met_w, mem_w, net_w, capacity, mem_capacity)
+                return _patched_rates(*totals, *changes) * unit_ir.sum()
 
             ev = e_cm[comp] * unit_ir[:, None]                               # (T, m)
             mt = met_cm[comp]
@@ -597,6 +637,139 @@ def _msr_kernel(
             )
             return relocate, swap
 
+    def _machine_sums(base, m):
+        """Per-machine sums over each of k rows' tasks: a function from
+        (k, T) per-task values to (k, m) sums, by the one-hot contraction
+        laid out (k·m, T). On a short leading axis (k is at most n² chains)
+        the (k, m, T) layout takes the TPU compiler several times longer."""
+        k = base.shape[0]
+        onehot = jnp.repeat(base, m, axis=0) == jnp.tile(
+            jnp.arange(m, dtype=base.dtype), k
+        )[:, None]
+
+        def sums(x):
+            rows = jnp.repeat(x, m, axis=0)
+            return jnp.sum(jnp.where(onehot, rows, 0.0), axis=-1).reshape(k, m)
+
+        return sums
+
+    def _count_rows(base, counts, unit, grown):
+        """Per-task (k, T) component and unit-rate maps of each base row
+        under its candidates' counts, and the (k,) unit rate ``u`` of the
+        component whose count they change."""
+        ends = jnp.cumsum(counts, axis=1)
+        tasks = jnp.arange(base.shape[1], dtype=counts.dtype)
+        comp = jnp.sum(
+            tasks[None, :, None] >= ends[:, None, :], axis=-1, dtype=base.dtype
+        )
+        unit_ir = jnp.take_along_axis(unit, comp, axis=1)
+        u = jnp.take_along_axis(unit, grown[:, None], axis=1)[:, 0]
+        return comp, unit_ir, u
+
+    def kernel_count_edits(base, counts, unit, grown, e_cm, met_cm, capacity, drop):
+        with jax.named_scope(name):
+            comp, unit_ir, u = _count_rows(base, counts, unit, grown)
+            sums = _machine_sums(base, capacity.shape[0])
+            var_w = sums(e_cm[comp, base] * unit_ir)                         # (k, m)
+            met_w = sums(met_cm[comp, base])
+            head = capacity - met_w
+            limits = _limits(head, var_w)
+            over = head < 0.0
+            # What one task of the changed component adds to each machine.
+            ev = e_cm[grown] * u[:, None]                                    # (k, m)
+            mt = met_cm[grown]
+            if drop:
+                # Task p leaves its machine x: the (k, T) grid.
+                x = base
+                var_x = jnp.take_along_axis(var_w, x, axis=1) - jnp.take_along_axis(ev, x, axis=1)
+                met_x = jnp.take_along_axis(met_w, x, axis=1) - jnp.take_along_axis(mt, x, axis=1)
+                total = unit_ir.sum(axis=1) - u
+            else:
+                # One more task on machine x, every x: the (k, m) grid.
+                x = jnp.broadcast_to(jnp.arange(capacity.shape[0], dtype=base.dtype), var_w.shape)
+                var_x, met_x = var_w + ev, met_w + mt
+                total = unit_ir.sum(axis=1) + u
+            # The least limit among the machines a candidate leaves alone:
+            # the row's least, unless x alone holds it, then the next.
+            least = jnp.min(limits, axis=1, keepdims=True)
+            alone = jnp.sum(limits == least, axis=1, keepdims=True) == 1
+            runner_up = jnp.min(
+                jnp.where(limits == least, jnp.inf, limits), axis=1, keepdims=True
+            )
+            lim_x = jnp.take_along_axis(limits, x, axis=1)
+            rest = jnp.where((lim_x == least) & alone, runner_up, least)
+            n_over = jnp.sum(over, axis=1, keepdims=True, dtype=base.dtype)
+            rest_over = n_over - jnp.take_along_axis(over, x, axis=1).astype(base.dtype) > 0
+            head_x = capacity[x] - met_x
+            rates = jnp.clip(jnp.minimum(_limits(head_x, var_x), rest), 0.0, None)
+            rates = jnp.where(rest_over | (head_x < 0.0), 0.0, rates)
+            return rates, rates * total[:, None]
+
+    def kernel_count_edits_resources(
+        base, counts, unit, grown, e_cm, met_cm, capacity,
+        mem, mem_capacity, distance, adjacency, alpha, cir_unit, net_penalty, drop,
+    ):
+        with jax.named_scope(name):
+            comp, unit_ir, u = _count_rows(base, counts, unit, grown)
+            k, m = base.shape[0], capacity.shape[0]
+            sums = _machine_sums(base, m)
+            var_w = sums(e_cm[comp, base] * unit_ir)                         # (k, m)
+            met_w = sums(met_cm[comp, base])
+            mem_w = sums(mem[comp])
+            dnet = jnp.zeros((), dtype=unit_ir.dtype)
+            net_w = jnp.zeros((k, m), dtype=unit_ir.dtype)
+            if adjacency.shape[-1]:
+                mass = jnp.stack(
+                    [
+                        sums(jnp.where(comp == c, unit_ir, 0.0))
+                        for c in range(adjacency.shape[-1])
+                    ],
+                    axis=1,
+                )
+                send, recv, r_out, s_in, d_r_out, d_s_in = _net_terms(
+                    mass, distance, adjacency, alpha, cir_unit
+                )
+                net_w = net_penalty * jnp.sum(send * d_r_out + recv * d_s_in, axis=1)
+                # A task's send rate and receive share at the rescaled rate,
+                # and what it adds: K (distance-weighted), L (on its machine).
+                rows = jnp.arange(k)
+                o = (alpha[grown] * u)[:, None]
+                cir_c = cir_unit[grown]
+                r = jnp.where(cir_c > 0.0, u / jnp.where(cir_c > 0.0, cir_c, 1.0), 0.0)[:, None]
+                K = r * s_in[rows, grown] + o * r_out[rows, grown]          # (k, m)
+                L = o * d_r_out[rows, grown] + r * d_s_in[rows, grown]
+                Dt = distance.T                                              # [v, w] = D[w, v]
+            ev = e_cm[grown] * u[:, None]                                    # (k, m)
+            mt = met_cm[grown]
+            mem_c = mem[grown][:, None, None]
+            if drop:
+                # Task p leaves its machine x = base[i, p]: the (k, T, w) grid.
+                on = base[:, :, None] == jnp.arange(m, dtype=base.dtype)     # (k, T, m)
+                dvar = -jnp.where(on, jnp.take_along_axis(ev, base, axis=1)[..., None], 0.0)
+                dmet = -jnp.where(on, jnp.take_along_axis(mt, base, axis=1)[..., None], 0.0)
+                dmem = -jnp.where(on, mem_c, 0.0)
+                if adjacency.shape[-1]:
+                    dnet = -(net_penalty * (Dt[base] * K[:, None, :] + on * L[:, None, :]))
+                total = unit_ir.sum(axis=1) - u
+            else:
+                # One more task on machine v, every v: the (k, v, w) grid.
+                on = jnp.eye(m, dtype=bool)[None]                           # (1, m, m)
+                dvar = jnp.where(on, ev[:, :, None], 0.0)
+                dmet = jnp.where(on, mt[:, :, None], 0.0)
+                dmem = jnp.where(on, mem_c, 0.0)
+                if adjacency.shape[-1]:
+                    dnet = net_penalty * (Dt[None] * K[:, None, :] + on * L[:, None, :])
+                total = unit_ir.sum(axis=1) + u
+            totals = (x[:, None, :] for x in (var_w, met_w, mem_w, net_w))
+            rates = _patched_rates(
+                *totals, capacity, mem_capacity, dvar, dmet, dmem, dnet
+            )
+            return rates, rates * total[:, None]
+
+    if count_edits:
+        fn = kernel_count_edits_resources if with_resources else kernel_count_edits
+        fn.__name__ = fn.__qualname__ = name
+        return jax.jit(fn, static_argnames="drop")
     if edits:
         fn = kernel_edits_resources if with_resources else kernel_edits
     else:
@@ -761,6 +934,51 @@ def relocate_swap_scores_jax(
     return _device_sweep(
         _msr_kernel(edits=True, with_resources=resources is not None), operands
     )
+
+
+def count_edit_scores_jax(
+    base: np.ndarray,
+    counts: np.ndarray,
+    unit: np.ndarray,
+    grown: np.ndarray,
+    e_cm: np.ndarray,
+    met_cm: np.ndarray,
+    capacity: np.ndarray,
+    resources: list | None = None,
+    drop: bool = False,
+) -> tuple[np.ndarray, np.ndarray]:
+    """Closed-form (rate, throughput) of the count edits of k base rows, on
+    ``msr_count_edits`` (``msr_count_edits_resources`` with ``resources``).
+
+    Row i of ``base`` (k, T) holds ``counts[i]`` (n,) instances per
+    component, in task order. Its candidates change the count of component
+    ``grown[i]`` by one; ``unit`` (k, n) holds their per-component unit
+    input rates (``cir_unit`` over the changed counts), which every task of
+    the row takes. With ``drop=False`` returns two (k, m) grids whose [i, v]
+    entries score row i with one more task of ``grown[i]`` on machine v;
+    with ``drop=True`` two (k, T) grids whose [i, p] entries score row i
+    without task p (meaningful where p is a task of ``grown[i]``). Each
+    entry is what ``closed_form_rates_jax`` gives the materialised row, up
+    to the rounding of the patched machine sums. ``resources`` is
+    ``device_resources``' tail, or ``None`` on a cluster without resources.
+
+    Only the base rows, counts and tables ship (indices as int32), and only
+    the grids come back. Spans and the ``sweep.h2d_bytes`` counter are those
+    of ``closed_form_rates_jax``.
+    """
+    operands = [
+        np.asarray(base, dtype=np.int32),
+        np.asarray(counts, dtype=np.int32),
+        unit,
+        np.asarray(grown, dtype=np.int32),
+        e_cm,
+        met_cm,
+        capacity,
+    ]
+    if resources is not None:
+        operands += resources
+    kernel = _msr_kernel(count_edits=True, with_resources=resources is not None)
+    return _device_sweep(functools.partial(kernel, drop=drop), operands)
 
 
 def max_stable_rate_batch_jax(

@@ -123,6 +123,29 @@ def _edit_specs(sharding):
     ]
 
 
+@pytest.mark.parametrize("drop", [False, True], ids=["grow", "drop"])
+@pytest.mark.parametrize("with_resources", [False, True], ids=["scalar", "resources"])
+def test_count_edit_kernels_compile_f64(one_chip, with_resources, drop):
+    """``msr_count_edits`` (``_resources``): refine's growth steps and drops
+    of k base rows, scored in (emulated) float64."""
+    from repro.core.sim_jax import _msr_kernel
+
+    k = N_COMP * N_COMP
+    with jax.enable_x64(True):
+        specs = [
+            _spec(one_chip, (k, T), jnp.int32),
+            _spec(one_chip, (k, N_COMP), jnp.int32),
+            _spec(one_chip, (k, N_COMP), jnp.float64),
+            _spec(one_chip, (k,), jnp.int32),
+            *_edit_specs(one_chip)[4:],
+        ]
+        if with_resources:
+            specs += _resource_specs(one_chip)
+        kernel = _msr_kernel(count_edits=True, with_resources=with_resources)
+        compiled = kernel.lower(*specs, drop=drop).compile()
+    assert "f64" in compiled.as_text()
+
+
 @pytest.mark.parametrize("with_resources", [False, True],
                          ids=["scalar", "resources"])
 def test_pallas_scoring_compiles_f32(one_chip, with_resources):
