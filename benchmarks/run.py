@@ -16,8 +16,6 @@ Prints ``name,us_per_call,derived`` CSV rows.
          runtime (writes BENCH_multitenant.json)
   netaware network-aware vs distance-blind placement on rack-structured
          clusters (writes BENCH_netaware.json)
-  planner beyond-paper heterogeneous LM fleet planning
-  roofline dry-run roofline aggregation (requires dry-run artifacts)
 """
 
 from __future__ import annotations
@@ -30,10 +28,8 @@ from benchmarks import (
     bench_largescale,
     bench_multitenant,
     bench_netaware,
-    bench_planner,
     bench_prediction,
     bench_refine,
-    bench_roofline,
     bench_runtime,
     bench_sched_speed,
     bench_throughput,
@@ -55,8 +51,6 @@ def main() -> None:
     bench_runtime.main(json_path="BENCH_runtime.json")
     bench_multitenant.main(json_path="BENCH_multitenant.json")
     bench_netaware.main(json_path="BENCH_netaware.json")
-    bench_planner.main()
-    bench_roofline.main()
 
 
 if __name__ == "__main__":

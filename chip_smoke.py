@@ -24,8 +24,6 @@ cold then warm, and compared with its NumPy reference:
             at 240 windows: rel 1e-9.
   simulate  simulate_batch(backend="jax") at B=2048 on paper_cluster
             ((10, 10, 10)) vs backend="numpy": rel 1e-9.
-  pallas    the compiled Pallas scoring kernel in float32 at B=16384,
-            T=478, m=180 vs its float64 NumPy oracle: rel 1e-4.
 
 Every phase must send at least one sweep to the device, counted from the
 ``repro.obs`` dispatch log. Deviations are scale-relative:
@@ -50,41 +48,27 @@ ROOT = Path(__file__).resolve().parent
 sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
 
 SEED = 0
-DEVICE_BACKENDS = ("jax", "pallas")
 # Deployment sizes (machines per type, candidate batch); see the docstring.
 LARGE_CLUSTER = (20, 70, 90)
 TENANT_CLUSTER, N_TENANTS = (20, 30, 40), 100
 SIM_CLUSTER, SIM_B = (10, 10, 10), 2048
-PALLAS_B = 16384
 # Refine climb: instances moved off the Alg. 1+2 start, and the moves
 # compared (the full climb from there takes about 60).
 CLIMB_PERTURB, CLIMB_ROUNDS = 4, 6
+# Single-task relocations scored in the offline phase's relocate sweep.
+RELOCATE_B = 16384
 
 
-class _CompileCounter:
-    """Programs compiled and persistent-cache hits, read from
-    jax.monitoring. The backend-compile event also fires for a program
-    loaded from the persistent cache, so fresh compiles are events - hits."""
+def _compile_counter():
+    """The benchmark's ``CompileCounter`` (bench/harness/compiles.py),
+    loaded from its file: programs compiled and persistent-cache hits."""
+    import importlib.util
 
-    def __init__(self) -> None:
-        from jax import monitoring
-
-        self.compiles = 0
-        self.cache_hits = 0
-        monitoring.register_event_duration_secs_listener(self._on_duration)
-        monitoring.register_event_listener(self._on_event)
-
-    def _on_duration(self, event: str, duration: float, **kwargs) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compiles += 1
-
-    def _on_event(self, event: str, **kwargs) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def snapshot(self) -> tuple[int, int]:
-        """(fresh compiles, persistent-cache hits) so far."""
-        return self.compiles - self.cache_hits, self.cache_hits
+    path = ROOT / "bench" / "harness" / "compiles.py"
+    spec = importlib.util.spec_from_file_location("compiles", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module.CompileCounter()
 
 
 def rel_dev(got, ref) -> float:
@@ -157,7 +141,7 @@ def _refine_sweeps(state, n_machines: int) -> dict:
             drop_counts.append(np.tile(dropped, (nk, 1)))
             drop_sizes.append(nk)
     return {
-        "relocate": (_relocations(base, m, PALLAS_B), None, [PALLAS_B]),
+        "relocate": (_relocations(base, m, RELOCATE_B), None, [RELOCATE_B]),
         "grow": (
             np.concatenate(grow_rows), np.concatenate(grow_counts),
             [m] * n_inst.shape[0],
@@ -351,59 +335,15 @@ def _simulate():
     return prepare, run, check
 
 
-def _pallas():
-    from repro.core import cost_model, linear_topology, paper_cluster, schedule
-    from repro.kernels.sched_scoring.ops import closed_form_rates_sched
-
-    cluster = paper_cluster(LARGE_CLUSTER)
-
-    def prepare():
-        etg = schedule(linear_topology(), cluster, r0=1.0, rate_epsilon=1.0).etg
-        tm = _relocations(etg.task_machine(), cluster.n_machines, PALLAS_B)
-        comp = etg.task_component()
-        ttypes = etg.utg.component_types
-        operands = (
-            tm, comp, cost_model.instance_rates(etg, 1.0),
-            cluster.profile.e[ttypes][:, cluster.machine_types],
-            cluster.profile.met[ttypes][:, cluster.machine_types],
-            cluster.capacity,
-        )
-        return operands, closed_form_rates_sched(*operands, impl="ref")
-
-    def run(ref):
-        operands, _ = ref
-        # Outside jax.enable_x64: the kernel runs in float32.
-        return closed_form_rates_sched(*operands, impl="pallas")
-
-    def check(out, ref):
-        operands, want = ref
-        dev = max(rel_dev(out[0], want[0]), rel_dev(out[1], want[1]))
-        pick = int(np.argmax(out[1]))
-        best = int(np.argmax(want[1]))
-        # A different pick among near-ties is fine if its reference score
-        # is within the tolerance of the best.
-        near = want[1][best] - want[1][pick] <= 1e-4 * abs(want[1][best])
-        return dev, 1e-4, dev <= 1e-4 and near, {
-            "dtype": str(np.asarray(out[0]).dtype),
-            "batch": operands[0].shape[0],
-            "tasks": operands[0].shape[1],
-            "machines": cluster.n_machines,
-            "argmax_identical": pick == best,
-        }
-
-    return prepare, run, check
-
-
 PHASES = {
     "offline": _offline,
     "tenants": _tenants,
     "runtime": _runtime,
     "simulate": _simulate,
-    "pallas": _pallas,
 }
 
 
-def run_phase(name: str, counter: _CompileCounter) -> bool:
+def run_phase(name: str, counter) -> bool:
     from repro.obs import TraceRecorder
 
     prepare, run, check = PHASES[name]()
@@ -420,7 +360,7 @@ def run_phase(name: str, counter: _CompileCounter) -> bool:
             out = run(ref)
         wall = time.perf_counter() - t0
         c1, h1 = counter.snapshot()
-        sweeps = sum(d.backend in DEVICE_BACKENDS for d in rec.dispatch_log)
+        sweeps = sum(d.backend == "jax" for d in rec.dispatch_log)
         dev, tol, passed, extra = check(out, ref)
         ok = ok and passed and sweeps > 0
         fields += [
@@ -460,7 +400,7 @@ def main(argv: list[str]) -> int:
         f"cache_dir={cache_dir}",
         flush=True,
     )
-    counter = _CompileCounter()
+    counter = _compile_counter()
     ok = True
     for name in names:
         try:

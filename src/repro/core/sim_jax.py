@@ -792,9 +792,7 @@ def closed_form_rates_jax(
     ``comp`` / ``unit_ir`` may be (T,) shared maps or (B, T) per-row maps;
     each shape routes to its own cached kernel variant. ``capacity`` may be
     (m,) shared or (B, m) per-row. The sweep runs the XLA contraction in
-    float64 on every backend (emulated on TPU): the Pallas twin in
-    ``repro.kernels.sched_scoring`` compiles for the TPU only with 32-bit
-    operands, so it is not on this path.
+    float64 on every backend (emulated on TPU).
 
     ``resources`` is the operand tail ``device_resources`` builds for a
     cluster with network or memory resources (``None`` without them, which

@@ -63,9 +63,8 @@ _RECENT: collections.deque[dict[str, Any]] = collections.deque(maxlen=RECENT_CAP
 class DispatchDecision:
     """One backend resolution of a sweep: closed-form scoring
     (``resolve_closed_form_backend``; regimes shared / per_row / skew),
-    ``simulate_batch`` (regime ``simulate``), ``evaluate_policies_batch``
-    (regime ``policy_eval``) or an explicit call of the Pallas scoring
-    kernel (regime ``sched_scoring``)."""
+    ``simulate_batch`` (regime ``simulate``) or ``evaluate_policies_batch``
+    (regime ``policy_eval``)."""
 
     requested: str
     backend: str

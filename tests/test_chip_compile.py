@@ -2,9 +2,9 @@
 
 Each case lowers and compiles one kernel of the main path for a described
 (not attached) ``v5e:2x2`` topology, on one of its chips, so the chip's own
-compiler refuses here what it would refuse on the chip: 64-bit operands in
-a Pallas kernel, unsupported reductions, tiling or VMEM limits. Nothing
-runs; results and times come from ``chip_smoke.py`` on the chip.
+compiler refuses here what it would refuse on the chip: unsupported
+operand types or reductions, tiling or memory limits. Nothing runs;
+results and times come from ``chip_smoke.py`` on the chip.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU compiler library, so describing it
@@ -144,28 +144,6 @@ def test_count_edit_kernels_compile_f64(one_chip, with_resources, drop):
         kernel = _msr_kernel(count_edits=True, with_resources=with_resources)
         compiled = kernel.lower(*specs, drop=drop).compile()
     assert "f64" in compiled.as_text()
-
-
-@pytest.mark.parametrize("with_resources", [False, True],
-                         ids=["scalar", "resources"])
-def test_pallas_scoring_compiles_f32(one_chip, with_resources):
-    """The Pallas twin compiles for the chip with 32-bit operands, called
-    outside ``jax.enable_x64``, and lands as a Mosaic custom call."""
-    from repro.kernels.sched_scoring.kernel import (
-        sched_scoring_pallas,
-        sched_scoring_pallas_resources,
-    )
-
-    bt = _spec(one_chip, (B, T), jnp.float32)
-    tm = _spec(one_chip, (B, T), jnp.int32)
-    cap = _spec(one_chip, (M,), jnp.float32)
-    if with_resources:
-        compiled = sched_scoring_pallas_resources.lower(
-            tm, bt, bt, bt, cap, _spec(one_chip, (B, M), jnp.float32), cap,
-        ).compile()
-    else:
-        compiled = sched_scoring_pallas.lower(tm, bt, bt, cap).compile()
-    assert "tpu_custom_call" in compiled.as_text()
 
 
 def test_simulate_fixed_point_compiles_f64(one_chip):

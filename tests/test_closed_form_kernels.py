@@ -1,13 +1,13 @@
-"""Parity suite for the scatter-free closed-form scoring kernels (ISSUE 6).
+"""Parity suite for the scatter-free closed-form scoring kernels.
 
-Covers both new accumulation paths against the NumPy reference
-(``cost_model.closed_form_rates`` — sequential ``np.add.at``, the bit-exact
-oracle): the XLA one-hot contraction (``sim_jax._msr_kernel``) and the
-Pallas segmented-reduce kernel run in interpret mode
-(``kernels.sched_scoring``), across all three scoring regimes — shared
-(T,) maps, per-row (B, T) maps, and skew rows — plus the dispatch table's
-regime/machine-gate semantics. Runs in the fast tier: shapes are small and
-the Pallas kernel interprets on CPU.
+The XLA one-hot contraction (``sim_jax._msr_kernel``) against the NumPy
+reference (``cost_model.closed_form_rates`` — sequential ``np.add.at``, the
+bit-exact oracle), across all three scoring regimes — shared (T,) maps,
+per-row (B, T) maps, and skew rows — plus the dispatch table's
+regime/machine-gate semantics. Each resource row kernel
+(``msr_resources_shared``, ``msr_resources_per_row``) fed the neutral tail
+(no memory demand, unbounded memory, no network component) is bit-identical
+to its plain twin (``msr_shared``, ``msr_per_row``) in every regime.
 
 When hypothesis is installed (CI dev image), a property section fuzzes
 shapes/values; the deterministic seed sweep below keeps kernel coverage in
@@ -24,7 +24,6 @@ from repro.core import (
     max_stable_rate_batch,
     paper_cluster,
     schedule,
-    star_topology,
 )
 from repro.core.cost_model import closed_form_rates
 from repro.core.schedule_state import ScheduleState
@@ -32,8 +31,7 @@ from repro.core.schedule_state import ScheduleState
 jax = pytest.importorskip("jax")
 
 from repro.core.sim_jax import closed_form_rates_jax  # noqa: E402
-from repro.kernels.sched_scoring.ops import closed_form_rates_sched  # noqa: E402
-from repro.kernels.sched_scoring.ref import sched_scoring_ref  # noqa: E402
+from neutral_tables import neutral_tail  # noqa: E402
 
 try:
     from hypothesis import given, settings
@@ -64,13 +62,6 @@ def _random_problem(seed, B, T, m, n, infeasible_rows=0):
     met = met_cm[comp[None, :], tm]
     ref = closed_form_rates(tm, e, met, unit_ir, cap)
     return tm, comp, unit_ir, e_cm, met_cm, cap, ref
-
-
-def _sched_interpret(*args, **kwargs):
-    """Pallas kernel in interpret mode, in float64 like the NumPy oracle
-    (the kernel computes in JAX's default float dtype)."""
-    with jax.enable_x64(True):
-        return closed_form_rates_sched(*args, impl="interpret", **kwargs)
 
 
 def _exact_throughput(tm, e, met, unit_ir, cap):
@@ -118,7 +109,7 @@ SHAPES = [
     (17, 14, 3, 6),      # small-cluster refine sweep shape
     (64, 15, 6, 7),      # medium cluster
     (33, 54, 15, 7),     # large realistic cluster (4,5,6)
-    (9, 130, 16, 5),     # T above the Pallas task-block size (padding)
+    (9, 130, 16, 5),     # T above 128 lanes
 ]
 
 
@@ -132,14 +123,25 @@ def test_contraction_parity_shared(B, T, m, n, seed):
     _assert_parity(got, ref)
 
 
+def _assert_neutral_identity(tm, comp, unit_ir, e_cm, met_cm, cap):
+    """The resource row kernel on the neutral tail gives the plain row
+    kernel's rates and throughputs bit for bit."""
+    plain = closed_form_rates_jax(tm, comp, unit_ir, e_cm, met_cm, cap)
+    neutral = closed_form_rates_jax(
+        tm, comp, unit_ir, e_cm, met_cm, cap,
+        neutral_tail(e_cm.shape[0], cap.size),
+    )
+    for got, want in zip(neutral, plain):
+        assert np.array_equal(got, want)
+
+
 @pytest.mark.parametrize("B,T,m,n", SHAPES)
 @pytest.mark.parametrize("seed", [0, 1])
-def test_pallas_interpret_parity_shared(B, T, m, n, seed):
-    tm, comp, unit_ir, e_cm, met_cm, cap, ref = _random_problem(
+def test_resource_row_kernel_on_neutral_tables_is_the_plain_one(B, T, m, n, seed):
+    tm, comp, unit_ir, e_cm, met_cm, cap, _ = _random_problem(
         seed, B, T, m, n, infeasible_rows=min(B, 3)
     )
-    got = _sched_interpret(tm, comp, unit_ir, e_cm, met_cm, cap)
-    _assert_parity(got, ref)
+    _assert_neutral_identity(tm, comp, unit_ir, e_cm, met_cm, cap)
 
 
 @pytest.mark.parametrize("B,T,m,n", [(21, 14, 3, 6), (13, 54, 15, 7)])
@@ -154,18 +156,15 @@ def test_per_row_parity(B, T, m, n):
     _assert_parity(
         closed_form_rates_jax(tm, comp, unit_ir, e_cm, met_cm, cap), ref
     )
-    _assert_parity(
-        _sched_interpret(tm, comp, unit_ir, e_cm, met_cm, cap),
-        ref,
-    )
 
 
-def test_sched_scoring_ref_matches_core():
-    tm, comp, unit_ir, e_cm, met_cm, cap, ref = _random_problem(3, 11, 14, 3, 6)
-    e = e_cm[comp[None, :], tm]
-    ev = e * unit_ir[None, :]
-    met = met_cm[comp[None, :], tm]
-    assert np.array_equal(sched_scoring_ref(tm, ev, met, cap), ref[0])
+@pytest.mark.parametrize("B,T,m,n", [(21, 14, 3, 6), (13, 54, 15, 7)])
+def test_resource_per_row_kernel_on_neutral_tables_is_the_plain_one(B, T, m, n):
+    rng = np.random.default_rng(7)
+    tm, comp1, _, e_cm, met_cm, cap, _ = _random_problem(7, B, T, m, n)
+    comp = np.broadcast_to(comp1, (B, T)).copy()
+    unit_ir = rng.uniform(0.05, 1.5, size=(B, T))
+    _assert_neutral_identity(tm, comp, unit_ir, e_cm, met_cm, cap)
 
 
 # ------------------------------------------------------------- skew rows
@@ -222,19 +221,20 @@ def test_skew_per_row_jax_matches_numpy(skew_state):
     )
 
 
-def test_skew_pallas_interpret_matches_numpy(skew_state):
-    state, _, cluster, skew = skew_state
+@pytest.mark.parametrize("per_row", [False, True], ids=["shared", "per_row"])
+def test_resource_kernels_on_neutral_tables_score_skew_rows_as_plain(
+    skew_state, per_row
+):
+    state, _, cluster, _ = skew_state
     rng = np.random.default_rng(17)
-    T = int(state.n_instances.sum())
-    tm = rng.integers(0, cluster.n_machines, size=(16, T))
-    n = state.utg.n_components
-    comp = np.repeat(np.arange(n), state.n_instances)
-    unit_ir = skew.per_task_unit_ir(state.n_instances)
-    ref = state.score_task_machine_batch(tm, backend="numpy")
-    got = _sched_interpret(
+    B, T = 16, int(state.n_instances.sum())
+    tm = rng.integers(0, cluster.n_machines, size=(B, T))
+    n_inst = np.tile(state.n_instances, (B, 1)) if per_row else state.n_instances
+    comp, unit_ir, _ = state._task_maps(n_inst, B, T)
+    assert comp.ndim == (2 if per_row else 1)
+    _assert_neutral_identity(
         tm, comp, unit_ir, state.e_cm, state.met_cm, cluster.capacity
     )
-    _assert_parity(got, ref)
 
 
 # ------------------------------------------------- dispatch regime/gating
@@ -327,16 +327,12 @@ if HAS_HYPOTHESIS:
         T=st.integers(1, 80),
         m=st.integers(1, 20),
         n=st.integers(1, 8),
-        impl=st.sampled_from(["contraction", "interpret"]),
     )
-    def test_fuzz_parity_shared(seed, B, T, m, n, impl):
+    def test_fuzz_parity_shared(seed, B, T, m, n):
         tm, comp, unit_ir, e_cm, met_cm, cap, ref = _random_problem(
             seed, B, T, m, n, infeasible_rows=min(B, 2)
         )
-        if impl == "contraction":
-            got = closed_form_rates_jax(tm, comp, unit_ir, e_cm, met_cm, cap)
-        else:
-            got = _sched_interpret(tm, comp, unit_ir, e_cm, met_cm, cap)
+        got = closed_form_rates_jax(tm, comp, unit_ir, e_cm, met_cm, cap)
         exact = _exact_throughput(
             tm, e_cm[comp[None, :], tm], met_cm[comp[None, :], tm], unit_ir, cap
         )
@@ -360,11 +356,6 @@ if HAS_HYPOTHESIS:
         exact = _exact_throughput(tm, e, met, unit_ir, cap)
         _assert_parity(
             closed_form_rates_jax(tm, comp, unit_ir, e_cm, met_cm, cap),
-            ref,
-            exact,
-        )
-        _assert_parity(
-            _sched_interpret(tm, comp, unit_ir, e_cm, met_cm, cap),
             ref,
             exact,
         )

@@ -2,8 +2,9 @@
 
 ``sim_jax``'s ``msr_count_edits`` and ``msr_count_edits_resources`` kernels
 against the row kernels (``msr_per_row``, ``msr_resources_per_row``) and the
-NumPy rows on the same candidates materialised, then refine's routing
-through ``ScheduleState.score_grow_steps`` / ``score_drops``: device sweeps
+NumPy rows on the same candidates materialised, ``msr_count_edits_resources``
+fed neutral resource tables against ``msr_count_edits``, then refine's
+routing through ``ScheduleState.score_grow_steps`` / ``score_drops``: device sweeps
 ship the base rows and tables and count their candidates in
 ``sweep.edit_rows``; NumPy sweeps and skew rows keep the row path.
 """
@@ -23,8 +24,10 @@ from repro.core import (
 )
 from repro.core.refine import refine
 from repro.core.schedule_state import ScheduleState
-from repro.core.sim_jax import closed_form_rates_jax
+from repro.core.sim_jax import closed_form_rates_jax, count_edit_scores_jax
 from repro.obs import TraceRecorder
+
+from neutral_tables import assert_edit_parity, neutral_tail
 
 
 def _linear():
@@ -157,6 +160,56 @@ def test_drop_kernel_agrees_with_the_rows(fixture, seed):
         block = slice(offsets[c], offsets[c + 1])
         assert np.argmax(got[block]) == np.argmax(numpy_[block])
     assert np.any(got[droppable] > 0.0)
+
+
+def _neutral_pair(state, rows, counts, comps, drop):
+    """Throughput grids of ``msr_count_edits_resources`` on the neutral
+    tail and of ``msr_count_edits``, on the count edits of ``rows``."""
+    new = counts.copy()
+    new[np.arange(comps.size), comps] += -1 if drop else 1
+    args = (
+        rows, counts, state.cir_unit[None, :] / new, comps,
+        state.e_cm, state.met_cm, state.cluster.capacity,
+    )
+    tail = neutral_tail(state.utg.n_components, state.cluster.n_machines)
+    return (
+        count_edit_scores_jax(*args, tail, drop=drop)[1],
+        count_edit_scores_jax(*args, drop=drop)[1],
+    )
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("depth", [0, 1, 2], ids=["k=n", "k=n+C(n,2)", "k=n^2"])
+def test_resource_grow_kernel_on_neutral_tables_agrees_with_the_plain_one(seed, depth):
+    state = ScheduleState.from_etg(*_linear())
+    n = state.utg.n_components
+    k = (n, n + comb(n, 2), n * n)[depth]
+    rows, counts, comps = _chains(state, k, depth, np.random.default_rng(seed))
+    neutral, plain = _neutral_pair(state, rows, counts, comps, drop=False)
+    assert neutral.shape == plain.shape == (k, state.cluster.n_machines)
+    for got, want in zip(neutral, plain):
+        assert_edit_parity(got, want)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_resource_drop_kernel_on_neutral_tables_agrees_with_the_plain_one(seed):
+    state = ScheduleState.from_etg(*_linear())
+    rng = np.random.default_rng(seed)
+    base = state.task_machine()
+    a, b = rng.choice(base.size, size=(2, 3), replace=False)
+    base[a], base[b] = base[b], base[a]
+    counts = state.n_instances
+    comps = np.flatnonzero(counts >= 2)
+    k = comps.size
+    neutral, plain = _neutral_pair(
+        state, np.tile(base, (k, 1)), np.tile(counts, (k, 1)), comps, drop=True
+    )
+    assert neutral.shape == plain.shape == (k, base.size)
+    # Row i's candidates: the tasks of component comps[i].
+    task_comp = np.repeat(np.arange(counts.size), counts)
+    for i, c in enumerate(comps):
+        cells = task_comp == c
+        assert_edit_parity(neutral[i][cells], plain[i][cells])
 
 
 def _counters(rec):

@@ -1,10 +1,11 @@
 """RELOCATE+SWAP candidates scored as edits of one base row.
 
 ``sim_jax``'s ``msr_edits`` kernel against ``msr_shared`` on the same
-candidates materialised as rows, and refine's routing of its relocate+swap
-sweep through ``ScheduleState.score_relocate_swap``: device sweeps take the
-edit path and count it in ``sweep.edit_rows``; NumPy sweeps keep rows.
-Clusters with resources: ``tests/test_net_edit_scoring.py``.
+candidates materialised as rows, ``msr_edits_resources`` fed neutral
+resource tables against ``msr_edits``, and refine's routing of its
+relocate+swap sweep through ``ScheduleState.score_relocate_swap``: device
+sweeps take the edit path and count it in ``sweep.edit_rows``; NumPy sweeps
+keep rows. Clusters with resources: ``tests/test_net_edit_scoring.py``.
 """
 
 import numpy as np
@@ -15,6 +16,8 @@ from repro.core.refine import refine
 from repro.core.schedule_state import ScheduleState
 from repro.core.sim_jax import closed_form_rates_jax, relocate_swap_scores_jax
 from repro.obs import TraceRecorder
+
+from neutral_tables import assert_edit_parity, neutral_tail
 
 # Machine types of the kernel scenario: machines 1-3 share a type.
 MTYPE = np.array([0, 1, 1, 1, 2, 2])
@@ -101,6 +104,25 @@ def test_edit_kernel_agrees_with_the_row_kernel(seed, family, tight):
         assert to_1.any() and np.array_equal(edits[0][to_1], edits[0][to_2])
         np.testing.assert_array_equal(want[to_1], want[to_2])
         np.testing.assert_array_equal(got[to_1], got[to_2])
+
+
+@pytest.mark.parametrize("tight", [True, False], ids=["tight", "roomy"])
+@pytest.mark.parametrize("seed", [0, 1, 2, 3])
+@pytest.mark.parametrize("family", ["relocate", "swap"])
+def test_resource_edit_kernel_on_neutral_tables_agrees_with_the_plain_one(
+    seed, family, tight
+):
+    base, comp, unit_ir, e_cm, met_cm, cap = _scenario(seed, tight)
+    _, _, moves, pairs = _menu(base, cap.size)
+    args = (base, np.arange(base.size), comp, unit_ir, e_cm, met_cm, cap)
+    plain = relocate_swap_scores_jax(*args)
+    neutral = relocate_swap_scores_jax(*args, neutral_tail(e_cm.shape[0], cap.size))
+    cells, grid = (moves, 0) if family == "relocate" else (pairs, 1)
+    assert neutral[grid].shape == plain[grid].shape == cells.shape
+    assert_edit_parity(neutral[grid][cells], plain[grid][cells])
+    # Moves onto machine 0 with no spare fixed capacity are infeasible.
+    if tight and family == "relocate":
+        assert np.any(plain[0][cells] == 0.0)
 
 
 def test_edit_kernel_scores_a_block_of_moving_tasks():
