@@ -67,7 +67,7 @@ __all__ = ["RefineResult", "refine"]
 # bounds the (chunk, T) batch memory on large clusters without changing
 # results (rows are independent). Device sweeps score the edits without
 # building rows, with or without resources, so this does not chunk them
-# (``ScheduleState.score_relocate_swap``). Network-aware clusters tighten
+# (``ScheduleState.best_relocate_swap``). Network-aware clusters tighten
 # this further (see ``_effective_chunk``): the host cut-traffic term expands
 # every row into (n_components, m) scatter tensors plus distance matvecs,
 # so the naive cap would materialize the full edge×machine product on wide
@@ -627,7 +627,9 @@ def _refine_state(
     Per round, every move family is expressed as edits on the flattened
     (T,) task->machine row exported from ``ScheduleState`` and scored in
     vectorized ``max_stable_rate_batch`` sweeps — one sweep covers all
-    RELOCATE+SWAP candidates (``ScheduleState.score_relocate_swap``), four
+    RELOCATE+SWAP candidates (``ScheduleState.best_relocate_swap``, which
+    on the device selects the winner there and fetches it alone, four
+    float64s a sweep, not the candidates' scores), four
     depth-lockstep sweeps cover every growth chain (ADD/GROW/PAIRGROW;
     ``score_grow_steps``), and one more covers all DROP candidates
     (``score_drops``): ~6 sweeps per round. NumPy-scored candidates are
@@ -676,15 +678,15 @@ def _refine_state(
 
             # RELOCATE + SWAP share the template (counts unchanged): candidates
             # are 1-2 column edits on the base row, scored in one sweep (the
-            # rows are built only where NumPy scores them). Within the [relocate..., swap...] order, np.argmax
-            # is the reference's first strictly-greater winner.
-            edits, scores = state.score_relocate_swap(
+            # rows are built only where NumPy scores them). The winner is the
+            # first strict maximum in [relocate..., swap...] order, the
+            # reference's first strictly-greater winner; a device sweep picks
+            # it on the device and fetches it alone.
+            won = state.best_relocate_swap(
                 base_tm, backend=backend, row_chunk=_effective_chunk(cluster, n)
             )
-            if scores.size:
-                i = int(np.argmax(scores))
-                s = float(scores[i])
-                pa, wa, pb, wb = (int(x) for x in edits[:, i])
+            if won is not None:
+                (pa, wa, pb, wb), s = won
                 if pa == pb:
                     c = int(comp_of[pb])
                     k, src = pb - int(offsets[c]), int(base_tm[pb])

@@ -651,22 +651,24 @@ class ScheduleState:
         moving tasks (one sweep on the paper's clusters), each resolved like
         a ``score_task_machine_batch`` call of the rows it stands for (same
         elements, regime and machine count). A device sweep ships only the
-        base row to ``sim_jax``'s ``msr_edits`` kernel, which scores the
-        sweep's relocate and swap grids, and bumps the ``sweep.edit_rows``
-        counter by its candidates; a NumPy sweep builds the rows
-        ``row_chunk`` at a time and scores them with the reference core, so
-        its scores are bit-identical to ``score_task_machine_batch``'s.
-        On a cluster with network or memory resources a move changes the
-        cut traffic of machines it does not touch, so a device sweep scores
-        every candidate on every machine (``msr_edits_resources``, with the
-        cut traffic and the memory mask computed on the device), in sweeps
-        of ``_NET_EDIT_SWEEP_CELLS // ((m + T) * m)`` moving tasks, and also
-        bumps the ``sweep.net_rows`` counter.
+        base row to ``sim_jax``'s ``msr_edits`` kernel, fetches the sweep's
+        relocate and swap grids (8·A·(m + T) bytes for A moving tasks) and
+        bumps the ``sweep.edit_rows`` counter by its candidates; a NumPy
+        sweep builds the rows ``row_chunk`` at a time and scores them with
+        the reference core, so its scores are bit-identical to
+        ``score_task_machine_batch``'s. On a cluster with network or memory
+        resources a move changes the cut traffic of machines it does not
+        touch, so a device sweep scores every candidate on every machine
+        (``msr_edits_resources``, with the cut traffic and the memory mask
+        computed on the device), in sweeps of ``_NET_EDIT_SWEEP_CELLS // ((m
+        + T) * m)`` moving tasks, and also bumps the ``sweep.net_rows``
+        counter. Refine needs only the menu's winner and asks
+        ``best_relocate_swap``, which fetches no grid.
 
         Each sweep is one ``refine.sweep`` span and adds its candidates to
         ``rows_scored`` and the ``refine.rows`` counter.
         """
-        n_tasks, m = int(base.shape[0]), self.cluster.n_machines
+        m = self.cluster.n_machines
         comp = np.repeat(np.arange(self.utg.n_components), self.n_instances)
         moves = np.arange(m)[None, :] != base[:, None]              # (T, m)
         pairs = np.triu(
@@ -683,30 +685,113 @@ class ScheduleState:
         ])
         thpt = np.empty(edits.shape[1], dtype=np.float64)
         # Candidates of tasks [a0, a1) are one slice of each family.
-        reloc_end = np.cumsum(moves.sum(axis=1))
-        swap_end = reloc_pos.size + np.cumsum(pairs.sum(axis=1))
-        if self.cluster.has_resources:
-            block = _NET_EDIT_SWEEP_CELLS // ((m + n_tasks) * m)
-        else:
-            block = _EDIT_SWEEP_CELLS // (m + n_tasks)
-        block = max(1, block)
-        for a0 in range(0, n_tasks, block):
-            a1 = min(a0 + block, n_tasks)
+        reloc_at, swap_at = 0, reloc_pos.size
+        for a0, a1, n_reloc, n_swap in self._relocate_swap_sweeps(base):
             parts = [
-                slice(reloc_end[a0 - 1] if a0 else 0, reloc_end[a1 - 1]),
-                slice(swap_end[a0 - 1] if a0 else reloc_pos.size, swap_end[a1 - 1]),
+                slice(reloc_at, reloc_at + n_reloc),
+                slice(swap_at, swap_at + n_swap),
             ]
-            rows = sum(p.stop - p.start for p in parts)
-            if rows == 0:
-                continue
+            reloc_at, swap_at = parts[0].stop, parts[1].stop
             with trace.span("refine.sweep", "refine"):
                 self._score_moves(
                     base, edits, parts, (a0, a1), moves, pairs, thpt,
                     backend, row_chunk,
                 )
+            rows = n_reloc + n_swap
             self.rows_scored += rows
             trace.count("refine.rows", rows)
         return edits, thpt
+
+    def best_relocate_swap(
+        self, base: np.ndarray, backend: str, row_chunk: int
+    ) -> tuple[tuple[int, int, int, int], float] | None:
+        """The winner of ``score_relocate_swap``'s menu of the (T,) ``base``
+        row: ``(edit, throughput)``, with ``edit = (pos_a, val_a, pos_b,
+        val_b)`` as there, the first strict maximum in the menu's order
+        (``np.argmax`` over its scores); ``None`` for an empty menu.
+
+        Where every sweep of the menu resolves to JAX, each runs
+        ``score_relocate_swap``'s device program and fetches only its
+        relocate and swap winners (``sim_jax.relocate_swap_winners_jax``,
+        four float64s), and the host keeps the first maximum of all sweeps'
+        relocate winners, then all their swap winners. No candidate is
+        built on the host, and the candidates are counted, not listed.
+        Otherwise it takes ``np.argmax`` over ``score_relocate_swap``'s
+        scores. Either way the sweeps, their resolutions (site
+        ``score_relocate_swap``), spans and counters are
+        ``score_relocate_swap``'s.
+        """
+        n_tasks, m = int(base.shape[0]), self.cluster.n_machines
+        sweeps = self._relocate_swap_sweeps(base)
+        with trace.unrecorded():
+            on_device = all(
+                self._resolve_rows(
+                    backend, n_reloc + n_swap, n_tasks, "shared", "score_relocate_swap"
+                ) == "jax"
+                for _, _, n_reloc, n_swap in sweeps
+            )
+        if not on_device:
+            edits, thpt = self.score_relocate_swap(base, backend, row_chunk)
+            i = int(np.argmax(thpt))
+            return tuple(int(x) for x in edits[:, i]), float(thpt[i])
+        relocates, swaps = [], []
+        for a0, a1, n_reloc, n_swap in sweeps:
+            rows = n_reloc + n_swap
+            with trace.span("refine.sweep", "refine"):
+                reloc_i, reloc_s, swap_i, swap_s = self._move_winners(
+                    base, (a0, a1), rows, backend
+                )
+            if n_reloc:
+                a, w = divmod(int(reloc_i), m)
+                relocates.append(((a0 + a, w, a0 + a, w), reloc_s))
+            if n_swap:
+                a, b = divmod(int(swap_i), n_tasks)
+                a += a0
+                swaps.append(((a, int(base[b]), b, int(base[a])), swap_s))
+            self.rows_scored += rows
+            trace.count("refine.rows", rows)
+        menu = relocates + swaps
+        if not menu:
+            return None
+        i = int(np.argmax([score for _, score in menu]))
+        return menu[i][0], float(menu[i][1])
+
+    def _relocate_swap_sweeps(
+        self, base: np.ndarray
+    ) -> list[tuple[int, int, int, int]]:
+        """``(a0, a1, relocations, swaps)`` of each sweep of the RELOCATE
+        and SWAP menu of the (T,) ``base`` row that has a candidate: its
+        moving tasks [a0, a1) and their candidates of each family, counted
+        without being built. Each task has m - 1 relocations; task a's swaps
+        are the later tasks, less those of its component and those on its
+        machine, plus those of both (counted twice)."""
+        n_tasks, m = int(base.shape[0]), self.cluster.n_machines
+        comp = np.repeat(np.arange(self.utg.n_components), self.n_instances)
+
+        def later(key):
+            """Per task a: the tasks b > a with ``key[b] == key[a]``."""
+            order = np.argsort(key, kind="stable")
+            ends = np.searchsorted(key[order], key[order], side="right")
+            out = np.empty(n_tasks, dtype=np.int64)
+            out[order] = ends - np.arange(n_tasks) - 1
+            return out
+
+        swaps = (
+            (n_tasks - 1 - np.arange(n_tasks))
+            - later(comp) - later(base) + later(comp * m + base)
+        )
+        if self.cluster.has_resources:
+            block = _NET_EDIT_SWEEP_CELLS // ((m + n_tasks) * m)
+        else:
+            block = _EDIT_SWEEP_CELLS // (m + n_tasks)
+        block = max(1, block)
+        sweeps = []
+        for a0 in range(0, n_tasks, block):
+            a1 = min(a0 + block, n_tasks)
+            n_reloc, n_swap = (a1 - a0) * (m - 1), int(swaps[a0:a1].sum())
+            if n_reloc + n_swap:
+                sweeps.append((a0, a1, n_reloc, n_swap))
+        return sweeps
 
     def score_grow_steps(
         self,
@@ -806,7 +891,12 @@ class ScheduleState:
         return rates[row, task], thpt[row, task]
 
     def _resolve_rows(
-        self, backend: str, rows: int, n_tasks: int, regime: str
+        self,
+        backend: str,
+        rows: int,
+        n_tasks: int,
+        regime: str,
+        site: str = "score_task_machine_batch",
     ) -> str:
         """The backend of a sweep of ``rows`` candidate rows of ``n_tasks``
         tasks, under the skew regime where the state has a skew model."""
@@ -817,7 +907,7 @@ class ScheduleState:
             rows * n_tasks,
             regime="skew" if self.skew is not None else regime,
             n_machines=self.cluster.n_machines,
-            site="score_task_machine_batch",
+            site=site,
         )
 
     def _score_moves(
@@ -835,37 +925,55 @@ class ScheduleState:
         """One sweep of ``score_relocate_swap``: the candidates of moving
         tasks ``tasks``, which fill ``thpt[parts]``."""
         rows = sum(p.stop - p.start for p in parts)
-        comp, unit_ir, regime = self._task_maps(self.n_instances, rows, base.size)
-        from repro.core.simulator import resolve_closed_form_backend
-
-        resolved = resolve_closed_form_backend(
-            backend,
-            rows * base.size,
-            regime=regime,
-            n_machines=self.cluster.n_machines,
-            site="score_relocate_swap",
+        resolved = self._resolve_rows(
+            backend, rows, base.size, "shared", "score_relocate_swap"
         )
         if resolved == "jax":
             from repro.core.sim_jax import relocate_swap_scores_jax
 
-            resources = self._device_resources()
-            trace.count("sweep.edit_rows", rows)
-            if resources is not None:
-                trace.count("sweep.net_rows", rows)
-            a = slice(*tasks)
-            relocate, swap = relocate_swap_scores_jax(
-                base, np.arange(*tasks), comp, unit_ir,
-                self.e_cm, self.met_cm, self.cluster.capacity, resources,
+            relocate, swap = self._edit_sweep(
+                relocate_swap_scores_jax, base, tasks, rows
             )
+            a = slice(*tasks)
             thpt[parts[0]] = relocate[moves[a]]
             thpt[parts[1]] = swap[pairs[a]]
             return
+        comp, unit_ir, _ = self._task_maps(self.n_instances, rows, base.size)
         for part in parts:
             for start in range(part.start, part.stop, row_chunk):
                 chunk = slice(start, min(start + row_chunk, part.stop))
                 with trace.span("refine.build", "refine"):
                     tm = _edited_rows(base, edits[:, chunk])
                 thpt[chunk] = self._score_rows(tm, comp, unit_ir, "numpy")[1]
+
+    def _move_winners(
+        self, base: np.ndarray, tasks: tuple[int, int], rows: int, backend: str
+    ) -> np.ndarray:
+        """One device sweep of ``best_relocate_swap``: the winners of moving
+        tasks ``tasks`` (``rows`` candidates), from ``sim_jax``'s
+        ``relocate_swap_winners_jax``. It records the sweep's resolution,
+        which ``best_relocate_swap`` found to be JAX, as ``_score_moves``
+        does."""
+        from repro.core.sim_jax import relocate_swap_winners_jax
+
+        self._resolve_rows(backend, rows, base.size, "shared", "score_relocate_swap")
+        return self._edit_sweep(relocate_swap_winners_jax, base, tasks, rows)
+
+    def _edit_sweep(self, score, base: np.ndarray, tasks: tuple[int, int], rows: int):
+        """``score`` (``sim_jax.relocate_swap_scores_jax`` or
+        ``relocate_swap_winners_jax``) of moving tasks ``tasks`` of the base
+        row, with the state's maps and tables; bumps ``sweep.edit_rows``
+        (and, on a cluster with resources, ``sweep.net_rows``) by the
+        sweep's ``rows`` candidates."""
+        comp, unit_ir, _ = self._task_maps(self.n_instances, rows, base.size)
+        resources = self._device_resources()
+        trace.count("sweep.edit_rows", rows)
+        if resources is not None:
+            trace.count("sweep.net_rows", rows)
+        return score(
+            base, np.arange(*tasks), comp, unit_ir,
+            self.e_cm, self.met_cm, self.cluster.capacity, resources,
+        )
 
     def _score_batch(
         self,

@@ -32,6 +32,7 @@ __all__ = [
     "max_stable_rate_batch_jax",
     "closed_form_rates_jax",
     "relocate_swap_scores_jax",
+    "relocate_swap_winners_jax",
     "count_edit_scores_jax",
     "device_resources",
     "network_tables",
@@ -277,8 +278,16 @@ def _msr_kernel(
     per candidate against the row kernel's O(T·m), with nothing per
     candidate shipped: on a TPU a gather indexed per candidate costs about
     its table's size per index, so the candidates are dense grids rather
-    than per-row lookups. Refine uses it for every RELOCATE+SWAP sweep that
-    resolves to JAX (``ScheduleState.score_relocate_swap``).
+    than per-row lookups. The same program also selects each grid's winner
+    on the device: non-candidate cells (w already a's machine; b not after
+    a, of a's component or on a's machine) read -inf, and the first maximum
+    in row-major order, the host menu's, gives four float64s (each family's
+    index and throughput). A caller fetches the grids
+    (``relocate_swap_scores_jax``) or the winners
+    (``relocate_swap_winners_jax``), never both; both run one compiled
+    program. Refine fetches the winners of every RELOCATE+SWAP sweep that
+    resolves to JAX (``ScheduleState.best_relocate_swap``). The winners
+    follow both edit kernels' grids, with or without resources.
 
     ``edits=True, with_resources=True`` selects ``msr_edits_resources``,
     the edit path of a cluster with network or memory resources. There a
@@ -463,6 +472,24 @@ def _msr_kernel(
                 var_w, met_w, capacity, unit_ir, infeasible_extra=over_mem
             )
 
+    def _winners(relocate, swap, base, rows, comp):
+        """Each grid's first maximum over its candidates, in row-major
+        order (the host menu's): ``[relocate index, relocate throughput,
+        swap index, swap throughput]``, the indices flat in their grids.
+        Relocations leave a's machine; swaps pair a with a later task b of
+        another component on another machine. A grid with no candidate
+        reads index 0 and -inf."""
+        s_a, c_a = base[rows][:, None], comp[rows][:, None]
+        moves = jnp.arange(relocate.shape[1], dtype=base.dtype)[None, :] != s_a
+        b = jnp.arange(base.shape[0], dtype=base.dtype)[None, :]
+        pairs = (b > rows[:, None]) & (comp[None, :] != c_a) & (base[None, :] != s_a)
+        out = []
+        for grid, cells in ((relocate, moves), (swap, pairs)):
+            flat = jnp.where(cells, grid, -jnp.inf).reshape(-1)
+            i = jnp.argmax(flat)
+            out += [i.astype(grid.dtype), flat[i]]
+        return jnp.stack(out)
+
     def kernel_edits(base, rows, comp, unit_ir, e_cm, met_cm, capacity):
         with jax.named_scope(name):
             _, var_w, met_w = _accumulate(
@@ -541,7 +568,7 @@ def _msr_kernel(
                 met_m[s_b] + (mt[rows].T[base].T - mt_home[None, :]),
                 cap_m[s_b],
             )
-            return relocate, swap
+            return relocate, swap, _winners(relocate, swap, base, rows, comp)
 
     def kernel_edits_resources(
         base, rows, comp, unit_ir, e_cm, met_cm, capacity,
@@ -635,7 +662,7 @@ def _msr_kernel(
                 jnp.where(on_y, mem_ab, 0.0) - jnp.where(on_x, mem_ab, 0.0),
                 dnet,
             )
-            return relocate, swap
+            return relocate, swap, _winners(relocate, swap, base, rows, comp)
 
     def _machine_sums(base, m):
         """Per-machine sums over each of k rows' tasks: a function from
@@ -872,12 +899,16 @@ def network_tables(cluster: Cluster, adjacency, alpha, cir_unit) -> list:
     return [cluster.distance, adjacency, alpha, cir_unit, np.float64(cluster.net_penalty)]
 
 
-def _device_sweep(kernel, operands: list) -> tuple[np.ndarray, ...]:
+def _device_sweep(
+    kernel, operands: list, keep: slice = slice(None)
+) -> tuple[np.ndarray, ...]:
     """Run a jitted scorer on host operands in float64: ``sweep.put`` (the
     operands' ``jax.device_put``, whose ``nbytes`` add to the
     ``sweep.h2d_bytes`` counter), ``sweep.run`` (the call, which only
-    enqueues) and ``sweep.fetch`` (the ``np.asarray`` of its results, where
-    the host waits for the device)."""
+    enqueues) and ``sweep.fetch`` (the ``np.asarray`` of the results that
+    ``keep`` selects, where the host waits for the device; their ``nbytes``
+    add to the ``sweep.d2h_bytes`` counter). Results left out are never
+    read back."""
     import jax
 
     with jax.enable_x64(True):
@@ -887,7 +918,9 @@ def _device_sweep(kernel, operands: list) -> tuple[np.ndarray, ...]:
         with trace.span("sweep.run", "sweep"):
             out = kernel(*on_device)
     with trace.span("sweep.fetch", "sweep"):
-        return tuple(np.asarray(x) for x in out)
+        fetched = tuple(np.asarray(x) for x in out[keep])
+    trace.count("sweep.d2h_bytes", sum(int(x.nbytes) for x in fetched))
+    return fetched
 
 
 def relocate_swap_scores_jax(
@@ -915,9 +948,47 @@ def relocate_swap_scores_jax(
     rounding of the patched machine sums.
 
     Only the base row and the tables ship (indices as int32), and only the
-    grids come back. Spans and the ``sweep.h2d_bytes`` counter are those of
-    ``closed_form_rates_jax``.
+    two grids come back (A·(m + T) float64s), not the kernel's winners.
+    Spans and the ``sweep.h2d_bytes`` / ``sweep.d2h_bytes`` counters are
+    those of ``closed_form_rates_jax``.
     """
+    return _relocate_swap_sweep(
+        base, rows, comp, unit_ir, e_cm, met_cm, capacity, resources, slice(2)
+    )
+
+
+def relocate_swap_winners_jax(
+    base: np.ndarray,
+    rows: np.ndarray,
+    comp: np.ndarray,
+    unit_ir: np.ndarray,
+    e_cm: np.ndarray,
+    met_cm: np.ndarray,
+    capacity: np.ndarray,
+    resources: list | None = None,
+) -> np.ndarray:
+    """The winners of ``relocate_swap_scores_jax``'s grids, selected on the
+    device by the same program: ``[relocate index, relocate throughput,
+    swap index, swap throughput]``, each index flat in its grid ((A, m) and
+    (A, T), row-major) and each the first strict maximum over the grid's
+    candidates (w not a's machine; b after a, of another component, on
+    another machine), which is ``np.argmax`` over the candidates in the
+    host menu's order. A grid with no candidate reads index 0 and -inf.
+
+    Only these four float64s come back (32 bytes, against the grids'
+    8·A·(m + T)); operands, spans and counters are
+    ``relocate_swap_scores_jax``'s.
+    """
+    return _relocate_swap_sweep(
+        base, rows, comp, unit_ir, e_cm, met_cm, capacity, resources, slice(2, 3)
+    )[0]
+
+
+def _relocate_swap_sweep(
+    base, rows, comp, unit_ir, e_cm, met_cm, capacity, resources, keep: slice
+) -> tuple[np.ndarray, ...]:
+    """One ``msr_edits`` (``msr_edits_resources``) sweep, fetching the
+    outputs ``keep`` selects of (relocate grid, swap grid, winners)."""
     operands = [
         np.asarray(base, dtype=np.int32),
         np.asarray(rows, dtype=np.int32),
@@ -929,9 +1000,8 @@ def relocate_swap_scores_jax(
     ]
     if resources is not None:
         operands += resources
-    return _device_sweep(
-        _msr_kernel(edits=True, with_resources=resources is not None), operands
-    )
+    kernel = _msr_kernel(edits=True, with_resources=resources is not None)
+    return _device_sweep(kernel, operands, keep)
 
 
 def count_edit_scores_jax(

@@ -52,6 +52,7 @@ __all__ = [
     "recent",
     "record_dispatch",
     "span",
+    "unrecorded",
 ]
 
 # Summaries of the newest outermost spans that :func:`recent` returns.
@@ -349,7 +350,7 @@ class TraceRecorder:
 
 
 @contextlib.contextmanager
-def _activate(rec: TraceRecorder) -> Iterator[TraceRecorder]:
+def _activate(rec: TraceRecorder | None) -> Iterator[TraceRecorder | None]:
     global _ACTIVE
     prev = _ACTIVE
     _ACTIVE = rec
@@ -396,6 +397,13 @@ def count(name: str, amount: float) -> None:
     if rec is None:
         return
     rec.metrics.counter(name).add(amount)
+
+
+def unrecorded() -> contextlib.AbstractContextManager[None]:
+    """No recorder is active inside: dispatch decisions, spans and counters
+    made there reach none. For a resolution made only to look ahead, which
+    the sweep that follows makes again on the record."""
+    return _activate(None)
 
 
 def recent() -> list[dict[str, Any]]:

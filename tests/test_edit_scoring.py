@@ -2,11 +2,15 @@
 
 ``sim_jax``'s ``msr_edits`` kernel against ``msr_shared`` on the same
 candidates materialised as rows, ``msr_edits_resources`` fed neutral
-resource tables against ``msr_edits``, and refine's routing of its
-relocate+swap sweep through ``ScheduleState.score_relocate_swap``: device
-sweeps take the edit path and count it in ``sweep.edit_rows``; NumPy sweeps
-keep rows. Clusters with resources: ``tests/test_net_edit_scoring.py``.
+resource tables against ``msr_edits``, the winners the kernel selects
+against ``np.argmax`` over its grids, and refine's routing of its
+relocate+swap sweep through ``ScheduleState.best_relocate_swap``: device
+sweeps take the edit path, count it in ``sweep.edit_rows`` and fetch only
+the winners; NumPy sweeps keep rows. Clusters with resources:
+``tests/test_net_edit_scoring.py``.
 """
+
+import dataclasses
 
 import numpy as np
 import pytest
@@ -14,7 +18,11 @@ import pytest
 from repro.core import linear_topology, paper_cluster, schedule
 from repro.core.refine import refine
 from repro.core.schedule_state import ScheduleState
-from repro.core.sim_jax import closed_form_rates_jax, relocate_swap_scores_jax
+from repro.core.sim_jax import (
+    closed_form_rates_jax,
+    relocate_swap_scores_jax,
+    relocate_swap_winners_jax,
+)
 from repro.obs import TraceRecorder
 
 from neutral_tables import assert_edit_parity, neutral_tail
@@ -136,6 +144,36 @@ def test_edit_kernel_scores_a_block_of_moving_tasks():
         np.testing.assert_array_equal(b, f[rows])
 
 
+def first_maxima(grids, base, moving, comp):
+    """``np.argmax`` over each grid's cells that are candidates of the host
+    menu, row-major: ``[relocate index, its score, swap index, its
+    score]``, what ``relocate_swap_winners_jax`` must return."""
+    moves = np.arange(grids[0].shape[1])[None, :] != base[:, None]
+    pairs = np.triu((comp[:, None] != comp[None, :]) & (base[:, None] != base[None, :]), k=1)
+    out = []
+    for grid, cells in zip(grids, (moves[moving], pairs[moving])):
+        flat = np.where(cells, grid, -np.inf).ravel()
+        i = int(np.argmax(flat))
+        out += [float(i), flat[i]]
+    return out
+
+
+@pytest.mark.parametrize("tight", [True, False], ids=["tight", "roomy"])
+@pytest.mark.parametrize("seed", [0, 1, 2, 3])
+@pytest.mark.parametrize("menu", ["all", "block", "no_swap"])
+def test_edit_kernel_winners_are_the_first_maxima_of_its_grids(seed, tight, menu):
+    base, comp, unit_ir, e_cm, met_cm, cap = _scenario(seed, tight)
+    moving = np.arange(2, 5) if menu == "block" else np.arange(base.size)
+    if menu == "no_swap":
+        base = np.zeros_like(base)
+    args = (base, moving, comp, unit_ir, e_cm, met_cm, cap)
+    got = relocate_swap_winners_jax(*args)
+    want = first_maxima(relocate_swap_scores_jax(*args), base, moving, comp)
+    assert got.dtype == np.float64 and got.tolist() == want
+    # A grid without a candidate reads index 0 and -inf.
+    assert (got[3] == -np.inf) == (menu == "no_swap")
+
+
 def test_edit_kernel_ships_the_base_row_and_tables():
     base, comp, unit_ir, e_cm, met_cm, cap = _scenario(0)
     rec = TraceRecorder()
@@ -148,6 +186,8 @@ def test_edit_kernel_ships_the_base_row_and_tables():
     f64_bytes = unit_ir.nbytes + e_cm.nbytes + met_cm.nbytes + cap.nbytes
     assert counters["sweep.h2d_bytes"] == int32_bytes + f64_bytes
     assert [r["name"] for r in rec.records] == ["sweep.put", "sweep.run", "sweep.fetch"]
+    # The grids come back, 8 bytes a cell.
+    assert counters["sweep.d2h_bytes"] == 8 * base.size * (cap.size + base.size)
 
 
 def test_edit_kernel_carries_a_stable_name():
@@ -176,23 +216,38 @@ def browned_out():
 
 
 def _relocate_swap_rows(monkeypatch):
-    """Candidates of every ``score_relocate_swap`` call, each checked
-    against the relocate+swap menu of the base row it was handed."""
+    """Candidates of every ``best_relocate_swap`` call, each checked
+    against the relocate+swap menu of the base row it was handed, as is the
+    winning edit."""
     seen = []
-    score = ScheduleState.score_relocate_swap
+    best = ScheduleState.best_relocate_swap
 
     def logged(self, base, *args, **kw):
-        edits, thpt = score(self, base, *args, **kw)
+        before = self.rows_scored
+        won = best(self, base, *args, **kw)
+        scored = self.rows_scored - before
         comp = np.repeat(np.arange(self.utg.n_components), self.n_instances)
         a, b = np.triu_indices(base.size, 1)
         swaps = np.count_nonzero((comp[a] != comp[b]) & (base[a] != base[b]))
-        assert thpt.size == base.size * (self.cluster.n_machines - 1) + swaps
-        assert edits.shape == (4, thpt.size)
-        seen.append(thpt.size)
-        return edits, thpt
+        assert scored == base.size * (self.cluster.n_machines - 1) + swaps
+        assert _in_menu(won[0], base, comp, self.cluster.n_machines)
+        seen.append(scored)
+        return won
 
-    monkeypatch.setattr(ScheduleState, "score_relocate_swap", logged)
+    monkeypatch.setattr(ScheduleState, "best_relocate_swap", logged)
     return seen
+
+
+def _in_menu(edit, base, comp, m):
+    """Whether ``(pos_a, val_a, pos_b, val_b)`` is a candidate of the
+    relocate+swap menu of ``base``."""
+    pa, wa, pb, wb = edit
+    if pa == pb:
+        return wa == wb and 0 <= wa < m and wa != base[pa]
+    return (
+        pa < pb and comp[pa] != comp[pb] and base[pa] != base[pb]
+        and (wa, wb) == (base[pb], base[pa])
+    )
 
 
 def _counters(rec):
@@ -279,3 +334,62 @@ def test_network_clusters_keep_rows_on_the_device(monkeypatch, browned_out):
     assert counters["refine.rows"] == counters["sweep.net_rows"] == res.candidates
     edit_sweeps = [d for d in rec.dispatch_log if d.site == "score_relocate_swap"]
     assert len(edit_sweeps) == len(seen)
+
+
+@pytest.mark.parametrize("cells", [1 << 22, 64])
+def test_device_winner_is_the_first_maximum_of_the_menu(monkeypatch, browned_out, cells):
+    """``best_relocate_swap`` on the device against ``np.argmax`` over
+    ``score_relocate_swap``'s device scores: the same edit and the same
+    float, in one sweep or in several, with no candidate built and 32 bytes
+    fetched a sweep. The menu's maximum is an exact tie of relocations and
+    swaps, and a tied swap moves an earlier task than the first tied
+    relocation (another sweep, when there are several): the first in menu
+    order, a relocation, wins."""
+    from repro.core import schedule_state
+
+    monkeypatch.setattr(schedule_state, "_EDIT_SWEEP_CELLS", cells)
+    etg, cluster = browned_out
+    state = ScheduleState.from_etg(etg, cluster)
+    base = state.task_machine()
+    edits, thpt = state.score_relocate_swap(base, "jax", 16_384)
+    i = int(np.argmax(thpt))
+    tied = np.flatnonzero(thpt == thpt[i])
+    swaps = tied[edits[0, tied] != edits[2, tied]]
+    assert edits[0, i] == edits[2, i] and swaps.size and edits[0, swaps].min() < edits[0, i]
+    rec = TraceRecorder()
+    with monkeypatch.context() as mp, rec.activate():
+        mp.setattr(schedule_state, "_edited_rows", lambda *a: pytest.fail("rows built"))
+        won = state.best_relocate_swap(base, "jax", 16_384)
+    assert won == (tuple(int(x) for x in edits[:, i]), float(thpt[i]))
+    sweeps = [d for d in rec.dispatch_log if d.site == "score_relocate_swap"]
+    assert len(sweeps) == (1 if cells > 64 else 9)
+    assert all(d.backend == "jax" and d.regime == "shared" for d in sweeps)
+    counters = _counters(rec)
+    assert counters["sweep.d2h_bytes"] == 32 * len(sweeps)
+    assert counters["sweep.edit_rows"] == counters["refine.rows"] == thpt.size
+    on_host = state.best_relocate_swap(base, "numpy", 16_384)
+    assert on_host[0] == won[0] and on_host[1] == pytest.approx(won[1], rel=1e-12)
+
+
+def test_device_winner_of_a_menu_without_swaps(browned_out):
+    etg, cluster = browned_out
+    one = dataclasses.replace(etg, assignment=[np.zeros_like(a) for a in etg.assignment])
+    state = ScheduleState.from_etg(one, cluster)
+    base = state.task_machine()
+    edits, thpt = state.score_relocate_swap(base, "jax", 16_384)
+    assert thpt.size == base.size * (cluster.n_machines - 1)
+    i = int(np.argmax(thpt))
+    won = state.best_relocate_swap(base, "jax", 16_384)
+    assert won == (tuple(int(x) for x in edits[:, i]), float(thpt[i]))
+
+
+def test_refine_makes_the_same_moves_in_several_device_sweeps(monkeypatch, browned_out):
+    from repro.core import schedule_state
+
+    monkeypatch.setattr(schedule_state, "_EDIT_SWEEP_CELLS", 64)
+    etg, cluster = browned_out
+    res = refine(etg, cluster, max_rounds=4, backend="jax")
+    same = refine(etg, cluster, max_rounds=4, backend="numpy")
+    assert res.moves and res.moves == same.moves
+    assert res.throughput == pytest.approx(same.throughput, rel=1e-12)
+    assert res.candidates == same.candidates

@@ -23,8 +23,11 @@ from repro.core.sim_jax import (
     closed_form_rates_jax,
     edge_counts,
     relocate_swap_scores_jax,
+    relocate_swap_winners_jax,
 )
 from repro.obs import TraceRecorder
+
+from test_edit_scoring import first_maxima
 
 # Machine types and racks of the kernel scenario: machines 1 and 2 are twins
 # (one type, one rack), and three racks make moves within and across racks.
@@ -163,6 +166,23 @@ def test_resource_edit_kernel_scores_a_block_of_moving_tasks():
         np.testing.assert_array_equal(b, f[rows])
 
 
+@pytest.mark.parametrize("tight", [True, False], ids=["tight", "roomy"])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("menu", ["all", "block", "no_swap"])
+def test_resource_edit_kernel_winners_are_the_first_maxima_of_its_grids(
+    seed, tight, menu
+):
+    base, comp, unit_ir, e_cm, met_cm, cap, res = _scenario(seed, tight)
+    moving = np.arange(2, 5) if menu == "block" else np.arange(base.size)
+    if menu == "no_swap":
+        base = np.zeros_like(base)
+    args = (base, moving, comp, unit_ir, e_cm, met_cm, cap, res)
+    got = relocate_swap_winners_jax(*args)
+    want = first_maxima(relocate_swap_scores_jax(*args), base, moving, comp)
+    assert got.dtype == np.float64 and got.tolist() == want
+    assert (got[3] == -np.inf) == (menu == "no_swap")
+
+
 def test_resource_edit_kernel_carries_a_stable_name():
     import jax
 
@@ -289,6 +309,48 @@ def test_device_and_numpy_sweeps_score_one_resource_menu(monkeypatch, racks, cel
     assert len(sweeps) == 2 * -(-base.size // block) == (2 if cells > 512 else 18)
     counters = _counters(rec)
     assert counters["sweep.net_rows"] == counters["sweep.edit_rows"] == numpy_.size
+
+
+@pytest.mark.parametrize("cells", [1 << 26, 512])
+def test_device_winner_is_the_first_maximum_of_the_resource_menu(
+    monkeypatch, racks, cells
+):
+    """``best_relocate_swap`` on the device against ``np.argmax`` over
+    ``score_relocate_swap``'s device scores, in one sweep or in several:
+    the same edit and the same float, 32 bytes fetched a sweep. The menu's
+    maximum is an exact tie of swaps of one task: the first wins."""
+    from repro.core import schedule_state
+
+    monkeypatch.setattr(schedule_state, "_NET_EDIT_SWEEP_CELLS", cells)
+    etg, cluster = racks
+    state = ScheduleState.from_etg(etg, cluster)
+    base = state.task_machine()
+    edits, thpt = state.score_relocate_swap(base, "jax", 1024)
+    i = int(np.argmax(thpt))
+    assert np.count_nonzero(thpt == thpt[i]) > 1
+    rec = TraceRecorder()
+    with rec.activate():
+        won = state.best_relocate_swap(base, "jax", 1024)
+    assert won == (tuple(int(x) for x in edits[:, i]), float(thpt[i]))
+    sweeps = [d for d in rec.dispatch_log if d.site == "score_relocate_swap"]
+    assert len(sweeps) == (1 if cells > 512 else 9)
+    counters = _counters(rec)
+    assert counters["sweep.d2h_bytes"] == 32 * len(sweeps)
+    assert counters["sweep.net_rows"] == counters["sweep.edit_rows"] == thpt.size
+    on_host = state.best_relocate_swap(base, "numpy", 1024)
+    assert on_host[0] == won[0] and on_host[1] == pytest.approx(won[1], rel=1e-12)
+
+
+def test_refine_makes_the_same_moves_in_several_device_sweeps(monkeypatch, racks):
+    from repro.core import schedule_state
+
+    monkeypatch.setattr(schedule_state, "_NET_EDIT_SWEEP_CELLS", 512)
+    etg, cluster = racks
+    res = refine(etg, cluster, max_rounds=4, backend="jax")
+    same = refine(etg, cluster, max_rounds=4, backend="numpy")
+    assert res.moves and res.moves == same.moves
+    assert res.throughput == pytest.approx(same.throughput, rel=1e-12)
+    assert res.candidates == same.candidates
 
 
 def test_net_rows_and_host_spans_follow_the_backend(racks):

@@ -79,6 +79,7 @@ def test_candidates_equal_the_rows_of_every_sweep(monkeypatch, browned_out, back
     shapes = []
     score_rows = ScheduleState._score_batch
     score_moves = ScheduleState._score_moves
+    move_winners = ScheduleState._move_winners
 
     def logged_rows(self, task_machine, n_instances, backend):
         shapes.append(np.shape(task_machine))
@@ -88,8 +89,13 @@ def test_candidates_equal_the_rows_of_every_sweep(monkeypatch, browned_out, back
         shapes.append((sum(p.stop - p.start for p in parts), base.shape[0]))
         return score_moves(self, base, edits, parts, *rest)
 
+    def logged_winners(self, base, tasks, rows, backend):
+        shapes.append((rows, base.shape[0]))
+        return move_winners(self, base, tasks, rows, backend)
+
     monkeypatch.setattr(ScheduleState, "_score_batch", logged_rows)
     monkeypatch.setattr(ScheduleState, "_score_moves", logged_moves)
+    monkeypatch.setattr(ScheduleState, "_move_winners", logged_winners)
     rec = TraceRecorder()
     res = refine(etg, cluster, max_rounds=4, backend=backend, recorder=rec)
     assert res.candidates == sum(b for b, _ in shapes) > 0
