@@ -80,7 +80,9 @@ _EDIT_SWEEP_CELLS = 1 << 22
 # The same budget on a cluster with network or memory resources, counted in
 # (candidate x machine) cells: every candidate is scored on all m machines,
 # an (A, m, m) relocate grid and an (A, T, m) swap grid. At 2**26 the
-# paper's large scenario is still one sweep per round (56.6M cells).
+# paper's large scenario is still one sweep per round (56.6M cells); the
+# rack-aware deployment of the same cluster (1,434 tasks) is 7 sweeps of 230
+# moving tasks (416.6M cells).
 _NET_EDIT_SWEEP_CELLS = 1 << 26
 
 
@@ -666,7 +668,9 @@ class ScheduleState:
         ``best_relocate_swap``, which fetches no grid.
 
         Each sweep is one ``refine.sweep`` span and adds its candidates to
-        ``rows_scored`` and the ``refine.rows`` counter.
+        ``rows_scored`` and the ``refine.rows`` counter; each device sweep
+        also bumps ``sweep.rs_sweeps``. A menu with a candidate bumps
+        ``refine.rs_menus`` once.
         """
         m = self.cluster.n_machines
         comp = np.repeat(np.arange(self.utg.n_components), self.n_instances)
@@ -684,9 +688,12 @@ class ScheduleState:
             np.concatenate([reloc_w, base[swap_a]]),
         ])
         thpt = np.empty(edits.shape[1], dtype=np.float64)
+        sweeps = self._relocate_swap_sweeps(base)
+        if sweeps:
+            trace.count("refine.rs_menus", 1)
         # Candidates of tasks [a0, a1) are one slice of each family.
         reloc_at, swap_at = 0, reloc_pos.size
-        for a0, a1, n_reloc, n_swap in self._relocate_swap_sweeps(base):
+        for a0, a1, n_reloc, n_swap in sweeps:
             parts = [
                 slice(reloc_at, reloc_at + n_reloc),
                 slice(swap_at, swap_at + n_swap),
@@ -719,7 +726,8 @@ class ScheduleState:
         Otherwise it takes ``np.argmax`` over ``score_relocate_swap``'s
         scores. Either way the sweeps, their resolutions (site
         ``score_relocate_swap``), spans and counters are
-        ``score_relocate_swap``'s.
+        ``score_relocate_swap``'s: ``sweep.rs_sweeps`` counts each device
+        sweep, ``refine.rs_menus`` the menu.
         """
         n_tasks, m = int(base.shape[0]), self.cluster.n_machines
         sweeps = self._relocate_swap_sweeps(base)
@@ -734,6 +742,8 @@ class ScheduleState:
             edits, thpt = self.score_relocate_swap(base, backend, row_chunk)
             i = int(np.argmax(thpt))
             return tuple(int(x) for x in edits[:, i]), float(thpt[i])
+        if sweeps:
+            trace.count("refine.rs_menus", 1)
         relocates, swaps = [], []
         for a0, a1, n_reloc, n_swap in sweeps:
             rows = n_reloc + n_swap
@@ -962,11 +972,12 @@ class ScheduleState:
     def _edit_sweep(self, score, base: np.ndarray, tasks: tuple[int, int], rows: int):
         """``score`` (``sim_jax.relocate_swap_scores_jax`` or
         ``relocate_swap_winners_jax``) of moving tasks ``tasks`` of the base
-        row, with the state's maps and tables; bumps ``sweep.edit_rows``
-        (and, on a cluster with resources, ``sweep.net_rows``) by the
-        sweep's ``rows`` candidates."""
+        row, with the state's maps and tables; bumps ``sweep.rs_sweeps`` by
+        one and ``sweep.edit_rows`` (and, on a cluster with resources,
+        ``sweep.net_rows``) by the sweep's ``rows`` candidates."""
         comp, unit_ir, _ = self._task_maps(self.n_instances, rows, base.size)
         resources = self._device_resources()
+        trace.count("sweep.rs_sweeps", 1)
         trace.count("sweep.edit_rows", rows)
         if resources is not None:
             trace.count("sweep.net_rows", rows)

@@ -832,7 +832,8 @@ def closed_form_rates_jax(
     On the active recorder the sweep is three spans — ``sweep.put`` (the
     operands' ``jax.device_put``), ``sweep.run`` (the jitted call) and
     ``sweep.fetch`` (the ``np.asarray`` of rates and throughput) — and the
-    operands' ``nbytes`` add to the ``sweep.h2d_bytes`` counter.
+    operands' ``nbytes`` add to the ``sweep.h2d_bytes`` counter (those of
+    the tables, from ``e_cm`` on, also to ``sweep.table_h2d_bytes``).
 
     Refine's RELOCATE+SWAP rows are all edits of one base row; where they
     would resolve to this function they go to ``relocate_swap_scores_jax``
@@ -845,7 +846,7 @@ def closed_form_rates_jax(
     kernel = _msr_kernel(
         per_row=comp.ndim == 2, with_resources=resources is not None
     )
-    return _device_sweep(kernel, operands)
+    return _device_sweep(kernel, operands, 3)
 
 
 def edge_counts(n_components: int, edges) -> np.ndarray:
@@ -900,21 +901,26 @@ def network_tables(cluster: Cluster, adjacency, alpha, cir_unit) -> list:
 
 
 def _device_sweep(
-    kernel, operands: list, keep: slice = slice(None)
+    kernel, operands: list, tables: int, keep: slice = slice(None)
 ) -> tuple[np.ndarray, ...]:
     """Run a jitted scorer on host operands in float64: ``sweep.put`` (the
     operands' ``jax.device_put``, whose ``nbytes`` add to the
-    ``sweep.h2d_bytes`` counter), ``sweep.run`` (the call, which only
-    enqueues) and ``sweep.fetch`` (the ``np.asarray`` of the results that
-    ``keep`` selects, where the host waits for the device; their ``nbytes``
-    add to the ``sweep.d2h_bytes`` counter). Results left out are never
-    read back."""
+    ``sweep.h2d_bytes`` counter, and those of ``operands[tables:]``, the
+    per-cluster tables ``e_cm``, ``met_cm``, ``capacity`` and
+    ``device_resources``' tail, also to ``sweep.table_h2d_bytes``),
+    ``sweep.run`` (the call, which only enqueues) and ``sweep.fetch`` (the
+    ``np.asarray`` of the results that ``keep`` selects, where the host
+    waits for the device; their ``nbytes`` add to the ``sweep.d2h_bytes``
+    counter). Results left out are never read back."""
     import jax
 
     with jax.enable_x64(True):
         with trace.span("sweep.put", "sweep"):
             on_device = jax.device_put(operands)
         trace.count("sweep.h2d_bytes", sum(int(x.nbytes) for x in operands))
+        trace.count(
+            "sweep.table_h2d_bytes", sum(int(x.nbytes) for x in operands[tables:])
+        )
         with trace.span("sweep.run", "sweep"):
             out = kernel(*on_device)
     with trace.span("sweep.fetch", "sweep"):
@@ -1001,7 +1007,7 @@ def _relocate_swap_sweep(
     if resources is not None:
         operands += resources
     kernel = _msr_kernel(edits=True, with_resources=resources is not None)
-    return _device_sweep(kernel, operands, keep)
+    return _device_sweep(kernel, operands, 4, keep)
 
 
 def count_edit_scores_jax(
@@ -1046,7 +1052,7 @@ def count_edit_scores_jax(
     if resources is not None:
         operands += resources
     kernel = _msr_kernel(count_edits=True, with_resources=resources is not None)
-    return _device_sweep(functools.partial(kernel, drop=drop), operands)
+    return _device_sweep(functools.partial(kernel, drop=drop), operands, 4)
 
 
 def max_stable_rate_batch_jax(
